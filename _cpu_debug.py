@@ -1,5 +1,5 @@
 """Ad-hoc debug helper: import FIRST to pin jax to a virtual CPU mesh
-(same workaround as tests/conftest.py). Not part of the package."""
+(same pin as tests/conftest.py). Not part of the package."""
 import os
 
 flags = os.environ.get("XLA_FLAGS", "")
@@ -11,11 +11,3 @@ os.environ["JAX_PLATFORMS"] = "cpu"
 import jax
 
 jax.config.update("jax_platforms", "cpu")
-try:
-    from jax._src import xla_bridge as _xb
-
-    for _extra in list(_xb._backend_factories):
-        if _extra != "cpu":
-            _xb._backend_factories.pop(_extra, None)
-except Exception:
-    pass

@@ -36,8 +36,8 @@ __all__ = ["analyze_program", "analyze_engine",
 #: primitives that call back into the host / move data across the
 #: host-device boundary from inside a compiled body
 CALLBACK_PRIMITIVES = frozenset({
-    "pure_callback", "io_callback", "debug_callback", "callback",
-    "outside_call", "infeed", "outfeed",
+    "pure_callback", "io_callback", "debug_callback", "debug_print",
+    "callback", "outside_call", "infeed", "outfeed",
 })
 
 _FLOATS = ("bfloat16", "float16", "float32", "float64")

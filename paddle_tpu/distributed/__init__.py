@@ -71,7 +71,8 @@ def _psum_all_devices(arr, op="sum"):
         return arr
     import jax.numpy as jnp
     from jax.sharding import Mesh, PartitionSpec as P
-    from jax.experimental.shard_map import shard_map
+
+    from ..parallel.mesh import shard_map
 
     devs = np.array(jax.devices())
     mesh = Mesh(devs, ("x",))
@@ -129,7 +130,11 @@ def wait(tensor, group=None, use_calc_stream=True):
 
 
 def spawn(func, args=(), nprocs=-1, join=True, daemon=False, **options):
-    """paddle.distributed.spawn parity: fork worker processes."""
+    """paddle.distributed.spawn parity: start worker processes (spawn
+    context, a fresh interpreter each). This function touches no jax,
+    but a chip belongs to one process at a time: call it from a parent
+    that has not touched jax either, or the workers cannot have the
+    chip."""
     import multiprocessing as mp
 
     if nprocs == -1:

@@ -59,8 +59,13 @@ def get_worker_info():
 def _worker_loop(dataset, index_queue, result_queue, collate_fn, wid,
                  num_workers, seed, worker_init_fn, iterable, drop_last):
     global _worker_info
+    import jax
     import numpy as np
 
+    # a worker is host-side numpy work. It must never start an
+    # accelerator backend: a chip belongs to one process at a time, and
+    # that process is the parent
+    jax.config.update("jax_platforms", "cpu")
     np.random.seed((seed + wid) % (2**32))
     _worker_info = WorkerInfo(wid, num_workers, dataset, seed + wid)
     if worker_init_fn is not None:

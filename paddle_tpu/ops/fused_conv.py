@@ -59,15 +59,15 @@ import functools
 
 import numpy as np
 
-from .attention import _import_pallas, _z
+from .attention import _z
 
 
 @functools.lru_cache(maxsize=None)
 def _fwd_call(B, Ci, Co, HW, relu, has_norm, dtype_str, interpret):
     import jax
     import jax.numpy as jnp
+    from jax.experimental import pallas as pl
 
-    pl = _import_pallas()
     dtype = jnp.dtype(dtype_str)
 
     def kernel(x_ref, sc_ref, sh_ref, w_ref, z_ref, s_ref, ss_ref):
@@ -123,8 +123,8 @@ def _fwd_call(B, Ci, Co, HW, relu, has_norm, dtype_str, interpret):
 def _bwd_call(B, Ci, Co, HW, relu, has_norm, dtype_str, interpret):
     import jax
     import jax.numpy as jnp
+    from jax.experimental import pallas as pl
 
-    pl = _import_pallas()
     dtype = jnp.dtype(dtype_str)
 
     def kernel(x_ref, sc_ref, sh_ref, w_ref, z_ref, dz_ref, ds_ref,

@@ -147,15 +147,9 @@ def mesh_axis_size(name: str) -> int:
 
 
 def shard_map(f, *, mesh, in_specs, out_specs):
-    """Version-portable shard_map (jax>=0.8 moved it to jax.shard_map and
-    renamed check_rep; our per-device bodies use untracked collectives so
-    vma/rep checking is off)."""
+    """`jax.shard_map` with vma checking off: our per-device bodies use
+    untracked collectives."""
     import jax
 
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-    from jax.experimental.shard_map import shard_map as _sm
-
-    return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               check_rep=False)
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)

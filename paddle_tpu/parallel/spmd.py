@@ -50,17 +50,6 @@ class SpmdTrainer:
         self._step_fn = None
         self._eval_fn = None
 
-        # finalize the flash-attention probe EAGERLY, before any trace:
-        # the first in-trace consult can only compile-check the kernel
-        # (provisional verdict); consulting here, in a clean trace
-        # state, also EXECUTES the tiny probe and rejects a kernel that
-        # compiles but emits non-finite values — otherwise that verdict
-        # would be baked into the compiled train step (advisor r4)
-        from paddle_tpu.ops import attention as _attn
-
-        if _attn._on_tpu():
-            _attn._flash_usable()
-
         params = self.fm.params()
         buffers = self.fm.buffers()
         self.param_specs = infer_param_specs(params, rules)
@@ -149,8 +138,14 @@ class SpmdTrainer:
         import jax
         import jax.numpy as jnp
 
+        from ..ops import attention as _attn
+
         accum = self.grad_accum
 
+        # on a mesh of several devices XLA partitions this program, and
+        # it cannot partition a Pallas kernel: attention then takes its
+        # XLA composition
+        @_attn.partitioned_trace(self.mesh.mesh.size > 1)
         def step(params, opt_state, buffers, rng, inputs, labels):
             grad_fn = jax.value_and_grad(self._forward_loss, has_aux=True)
 

@@ -78,30 +78,34 @@ class DeviceSpec:
 #: (flops, dt). ~one AVX2 core: 8 lanes x 2 FMA ports x 2 flops @ 3GHz.
 CPU_SPEC = DeviceSpec("cpu", 96e9, 40e9, 16 * 2**30)
 
-#: per-chip published peaks (bf16 matmul flops, HBM bandwidth, HBM)
+#: per-chip published peaks (bf16 matmul flops, HBM bandwidth, HBM),
+#: keyed by the `device_kind` string jax reports for the chip.
+#: "TPU v5 lite" is what a v5e reports; its peaks are Google Cloud's
+#: "TPU v5e" system-architecture page: 197 TFLOP/s bf16, 819 GB/s,
+#: 16 GiB HBM per chip.
 DEVICE_SPECS = {
     "cpu": CPU_SPEC,
     "TPU v2": DeviceSpec("TPU v2", 22.5e12, 700e9, 8 * 2**30),
     "TPU v3": DeviceSpec("TPU v3", 61.5e12, 900e9, 16 * 2**30),
     "TPU v4": DeviceSpec("TPU v4", 137.5e12, 1228e9, 32 * 2**30),
-    "TPU v5e": DeviceSpec("TPU v5e", 98.5e12, 819e9, 16 * 2**30),
+    "TPU v5 lite": DeviceSpec("TPU v5 lite", 197e12, 819e9, 16 * 2**30),
     "TPU v5p": DeviceSpec("TPU v5p", 229.5e12, 2765e9, 95 * 2**30),
 }
 
 
-def detect_spec(default=CPU_SPEC):
-    """Spec for jax's default device by `device_kind` (prefix match, so
-    "TPU v4 lite" variants resolve); `default` when unknown."""
-    try:
-        import jax
+def detect_spec():
+    """Spec for jax's default device by its `device_kind`. A device the
+    table does not know is an error: a utilisation gauge against the
+    wrong peak is worse than none."""
+    import jax
 
-        kind = jax.devices()[0].device_kind
-    except Exception:
-        return default
-    for name, spec in DEVICE_SPECS.items():
-        if kind.lower().startswith(name.lower()):
-            return spec
-    return default
+    kind = jax.devices()[0].device_kind
+    if kind in DEVICE_SPECS:
+        return DEVICE_SPECS[kind]
+    raise LookupError(
+        f"no peak-rate row for device kind {kind!r} in "
+        f"profiler.costs.DEVICE_SPECS ({sorted(DEVICE_SPECS)}); add one "
+        f"with its published peaks and their source")
 
 
 def mfu(flops, dt_s, spec):

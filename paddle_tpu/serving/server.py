@@ -9,8 +9,10 @@ iteration through the request's `stream_cb`; the final result is a
 Lifecycle: `shutdown(drain=True)` closes admission and lets everything
 already accepted run to completion (graceful drain); `drain=False`
 aborts in-flight work at the next iteration boundary, delivering
-partial tokens with finish_reason "shutdown". Works under
-JAX_PLATFORMS=cpu — nothing here assumes an accelerator."""
+partial tokens with finish_reason "shutdown". The server is host code
+only: it runs wherever the engine's backend does — the tests drive it
+on the CPU backend, `chip_smoke.py` on one TPU chip, where this process
+is the only one that may hold the chip."""
 from __future__ import annotations
 
 import threading

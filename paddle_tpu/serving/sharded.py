@@ -342,6 +342,9 @@ class ShardedServingEngine(ServingEngine):
         key = ("prefill", Pb)
         neg = float(NEG)
 
+        from ..ops import attention as A
+
+        @A.partitioned_trace()   # weights shard over the prefill slice
         def prefill_fn(params, buffers, prompt, length, memory, *ad):
             self.trace_counts[key] += 1  # one per trace = one compile
             kpos = jnp.arange(L, dtype=jnp.int32)

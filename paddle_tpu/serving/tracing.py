@@ -8,7 +8,7 @@ and tests share. Instrumented call sites in scheduler/engine/server
 all guard with ``if _trace._SESSION is not None:`` — one module-global
 read when tracing is off.
 
-Span taxonomy (exported Chrome-trace names):
+Span catalog (exported Chrome-trace names):
 
   request         per-request root: submit() -> finish/fail
   queue           admission queue wait: submit -> slot pop (re-opened
@@ -53,7 +53,7 @@ import numpy as np
 from ..profiler import trace as _trace
 
 __all__ = [
-    "SPAN_TAXONOMY", "retrace_sentinel", "RetraceSentinel",
+    "SPAN_CATALOG", "retrace_sentinel", "RetraceSentinel",
     "RetraceError", "session_scope", "start_session", "end_session",
     "load_chrome_trace", "waterfalls", "waterfall_report",
 ]
@@ -68,7 +68,7 @@ end_session = _trace.end_session
 
 #: (span name, meaning) — the README "Observability" table and the
 #: report tool's legend both render from this
-SPAN_TAXONOMY = (
+SPAN_CATALOG = (
     ("request", "per-request root: submit -> finish/fail"),
     ("queue", "admission queue wait: submit -> slot pop"),
     ("join", "slot join: prefill / prefix attach / disagg dispatch"),

@@ -16,7 +16,8 @@ parses):
 
     <dir>/MANIFEST.json          {"version", "fingerprint", "entries":
                                   {digest: {"key", "crc32", "size"}}}
-    <dir>/entries/<digest>.bin   pickle((payload, in_tree, out_tree))
+    <dir>/entries/<digest>.bin   pickle((payload, in_tree, out_tree,
+                                         device_ids))
 
 Robustness contract (chaos-tested): a torn/corrupt entry (CRC
 mismatch), a version- or environment-mismatched manifest, or an
@@ -52,7 +53,7 @@ _PT_CACHE_LOAD = faults.point("tuning.cache_load")
 
 #: bump when the entry payload format changes: old caches read as
 #: stale (recompile + overwrite), never as garbage
-CACHE_SCHEMA = 1
+CACHE_SCHEMA = 2
 
 
 class CacheCorrupt(RuntimeError):
@@ -65,11 +66,8 @@ def env_fingerprint():
     import jax
     import jaxlib
 
-    try:
-        devs = jax.devices()
-        kind, n = devs[0].device_kind, len(devs)
-    except Exception:
-        kind, n = "unknown", 0
+    devs = jax.devices()
+    kind, n = devs[0].device_kind, len(devs)
     return {"schema": CACHE_SCHEMA,
             "jax": jax.__version__,
             "jaxlib": jaxlib.__version__,
@@ -182,10 +180,17 @@ class AotCompileCache:
                 raise CacheCorrupt(
                     f"entry {dg} failed its CRC/size check "
                     f"(torn write or bit rot)")
-            payload, in_tree, out_tree = pickle.loads(blob)
+            payload, in_tree, out_tree, device_ids = pickle.loads(blob)
+            import jax
             from jax.experimental import serialize_executable as se
 
-            out = se.deserialize_and_load(payload, in_tree, out_tree)
+            # load for the devices the program was compiled for: left
+            # to its default, jax binds the executable to EVERY local
+            # device and a one-device program then refuses its inputs
+            by_id = {d.id: d for d in jax.devices()}
+            out = se.deserialize_and_load(
+                payload, in_tree, out_tree,
+                execution_devices=[by_id[i] for i in device_ids])
             with self._lock:
                 self.stats["loaded"] += 1
             return out
@@ -213,7 +218,9 @@ class AotCompileCache:
             from jax.experimental import serialize_executable as se
 
             payload, in_tree, out_tree = se.serialize(compiled)
-            blob = pickle.dumps((payload, in_tree, out_tree))
+            device_ids = [d.id for d in
+                          compiled.runtime_executable().local_devices()]
+            blob = pickle.dumps((payload, in_tree, out_tree, device_ids))
         except Exception:
             return False
         dg = self._digest(key_str)

@@ -104,14 +104,11 @@ def key_str(parts):
 
 
 def current_device_kind():
-    """jax's device_kind for the default device ('cpu' fallback) —
-    the table's device tier."""
-    try:
-        import jax
+    """jax's device_kind for the default device — the table's device
+    tier. A device that cannot be asked is an error, not the CPU."""
+    import jax
 
-        return jax.devices()[0].device_kind
-    except Exception:
-        return "cpu"
+    return jax.devices()[0].device_kind
 
 
 class TuningTable:

@@ -5,27 +5,32 @@
 #      OP_BENCH.json baseline (>2x step-time regressions fail the run
 #      only with CI_STRICT_PERF=1; they always print)
 #   3. bench.py CPU dry-run of the CTR config (exercises the native PS)
+#   4. chip_smoke.py's CPU rehearsal: the chip run's control flow at a
+#      tiny size, kernels interpreted (the chip itself is reached with
+#      `python chip_smoke.py` on a machine that has one)
 # Usage: scripts/ci.sh [pytest-args...]
 set -u -o pipefail
 cd "$(dirname "$0")/.."
 
 rc=0
+out=_scratch/ci   # gitignored; nothing is written outside the checkout
+mkdir -p "$out"
 
-echo "== [1/3] pytest =="
+echo "== [1/4] pytest =="
 python -m pytest tests/ -q -x "$@" || rc=1
 
 echo "== [1b] README bench-claim hygiene =="
 python tools/check_readme_bench.py || rc=1
 
 echo "== [1c] static analyzer gate (AST lints + cached program analyses) =="
-if python tools/static_check.py --fast --json > /tmp/static_check.json; then
-  echo "static-check: pass (see /tmp/static_check.json)"
+if python tools/static_check.py --fast --json > $out/static_check.json; then
+  echo "static-check: pass (see $out/static_check.json)"
 else
-  echo "static-check: NEW findings (see /tmp/static_check.json; fix or justify in ANALYSIS_BASELINE.json)"
+  echo "static-check: NEW findings (see $out/static_check.json; fix or justify in ANALYSIS_BASELINE.json)"
   rc=1
 fi
 
-echo "== [2/3] op micro-bench (quick, vs baseline) =="
+echo "== [2/4] op micro-bench (quick, vs baseline) =="
 if python tools/op_bench.py --cpu --quick --compare; then
   echo "op-bench: no >2x regressions"
 else
@@ -34,22 +39,22 @@ else
 fi
 
 echo "== [2b] perf gate (quick 2-row smoke vs committed baselines) =="
-if python tools/perf_gate.py --cpu --quick --out /tmp/PERF_GATE.json; then
-  echo "perf-gate: pass (see /tmp/PERF_GATE.json)"
+if python tools/perf_gate.py --cpu --quick --out $out/PERF_GATE.json; then
+  echo "perf-gate: pass (see $out/PERF_GATE.json)"
 else
   echo "perf-gate: regressions/missing rows detected (see above)"
   rc=1
 fi
 
 echo "== [2c] kernel autotune smoke sweep (dry-run, mechanics only) =="
-if python tools/autotune.py --cpu --smoke --dry-run > /tmp/autotune_smoke.json; then
-  echo "autotune: smoke sweep ok (see /tmp/autotune_smoke.json)"
+if python tools/autotune.py --cpu --smoke --dry-run > $out/autotune_smoke.json; then
+  echo "autotune: smoke sweep ok (see $out/autotune_smoke.json)"
 else
   echo "autotune: smoke sweep FAILED"
   rc=1
 fi
 
-echo "== [3/3] bench dry-run (ctr_ps, small, cpu) =="
+echo "== [3/4] bench dry-run (ctr_ps, small, cpu) =="
 JAX_PLATFORMS=cpu python - <<'PY' || rc=1
 import _cpu_debug  # noqa: F401
 import bench
@@ -58,5 +63,8 @@ r = bench._ctr_dnn_ps(batch=256, chunks=2, merge_k=2)
 assert "value" in r, r
 print("ctr dry-run ok:", r["value"], r["unit"])
 PY
+
+echo "== [4/4] chip_smoke.py rehearsal (cpu, tiny, interpreted kernels) =="
+JAX_PLATFORMS=cpu python chip_smoke.py --rehearse || rc=1
 
 exit $rc

@@ -5,9 +5,8 @@
    global-block consumer scan alone under-counts readers).
 2. attention_lstm_fuse_pass must not delete the parent-side atted
    precompute chain when a SECOND sub-block reads it.
-3. _flash_usable must, in a clean trace state, execute the compiled
-   probe and refuse a kernel that compiles but produces non-finite
-   values.
+3. (rewritten for the chip) a flash kernel that fails on a TPU backend
+   raises; no probe or dispatcher falls back to the XLA reference.
 """
 import numpy as np
 
@@ -85,36 +84,34 @@ def test_attention_lstm_fuse_skips_shared_atted():
     assert "recurrent" in types, types
 
 
-def test_flash_probe_rejects_nonfinite_execution(monkeypatch):
-    """_flash_usable in a clean trace state must RUN the compiled probe
-    and reject a kernel whose outputs are non-finite, not just check
-    that it compiles (advisor r4, attention.py probe)."""
+def test_flash_failure_raises_instead_of_falling_back(monkeypatch):
+    """There is no run-time probe and no swallow any more: on a TPU
+    backend a flash kernel that fails raises out of `sdpa` /
+    `sdpa_bshd` instead of silently handing back the XLA reference
+    (which made a broken kernel look like a slow, working run). Only
+    the shape gates and the PT_FLASH_ATTENTION=0 switch choose the
+    reference."""
     import jax.numpy as jnp
+    import pytest
 
     from paddle_tpu.ops import attention
 
-    saved = dict(attention._FLASH_PROBED)
+    def broken_flash(*a, **k):
+        raise RuntimeError("mosaic refused the kernel")
 
-    def nan_flash(q, k, v, bias=None, is_causal=False, scale=None,
-                  interpret=False, block_q=256, block_k=256):
-        return (q + k + v) * jnp.nan
-
-    def good_flash(q, k, v, bias=None, is_causal=False, scale=None,
-                   interpret=False, block_q=256, block_k=256):
-        return q + k + v
-
-    try:
-        monkeypatch.setattr(attention, "flash_attention", nan_flash)
-        attention._FLASH_PROBED.clear()
-        assert attention._flash_usable() is False
-        monkeypatch.setattr(attention, "flash_attention", good_flash)
-        attention._FLASH_PROBED.clear()
-        assert attention._flash_usable() is True
-        assert attention._FLASH_PROBED.get("executed") is True
-        # the executed verdict is cached: a later consult with a
-        # broken kernel must not re-probe
-        monkeypatch.setattr(attention, "flash_attention", nan_flash)
-        assert attention._flash_usable() is True
-    finally:
-        attention._FLASH_PROBED.clear()
-        attention._FLASH_PROBED.update(saved)
+    monkeypatch.setattr(attention, "_on_tpu", lambda: True)
+    monkeypatch.setattr(attention, "flash_attention", broken_flash)
+    q = jnp.full((1, 2, 1024, 64), 0.5, jnp.float32)       # BHSD
+    with pytest.raises(RuntimeError, match="mosaic refused"):
+        attention.sdpa(q, q, q, is_causal=True)
+    qb = jnp.swapaxes(q, 1, 2)                             # BSHD
+    with pytest.raises(RuntimeError, match="mosaic refused"):
+        attention.sdpa_bshd(qb, qb, qb, is_causal=True)
+    # a shape gate is a choice, not failure handling: too short for
+    # flash takes the reference without touching the kernel
+    short = q[:, :, :128]
+    assert attention.sdpa(short, short, short).shape == short.shape
+    # and so is the documented off switch
+    monkeypatch.setenv("PT_FLASH_ATTENTION", "0")
+    assert attention._flash_usable() is False
+    assert attention.sdpa(q, q, q, is_causal=True).shape == q.shape

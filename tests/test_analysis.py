@@ -117,14 +117,22 @@ def test_pta103_dtype_promotion():
 def test_pta104_host_callback():
     import jax
 
-    def bad(x):
+    # jax 0.9 stages jax.debug.print as its own `debug_print`
+    # primitive; jax.debug.callback still stages `debug_callback`
+    def printing(x):
         jax.debug.print("x={x}", x=x)
         return x + 1
 
-    fs = analyze_program(("step", 1), _jit(bad),
-                         (jax.numpy.zeros((4,)),), large_bytes=LB)
-    assert any("debug_callback" in f.message
-               for f in _rules(fs, "PTA104"))
+    def calling(x):
+        jax.debug.callback(lambda v: None, x)
+        return x + 1
+
+    for bad, prim in ((printing, "debug_print"),
+                      (calling, "debug_callback")):
+        fs = analyze_program(("step", 1), _jit(bad),
+                             (jax.numpy.zeros((4,)),), large_bytes=LB)
+        assert any(f"`{prim}`" in f.message
+                   for f in _rules(fs, "PTA104")), (prim, fs)
 
 
 def test_pta105_unsharded_carry():

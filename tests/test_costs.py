@@ -243,6 +243,27 @@ def test_device_spec_detection_and_table():
         assert set(d) == {"name", "peak_tflops", "peak_gbps", "hbm_gb"}
 
 
+@pytest.mark.parametrize("kind,tflops", [("TPU v5 lite", 197.0),
+                                         ("TPU v9 imagined", None)])
+def test_device_spec_by_reported_kind(monkeypatch, kind, tflops):
+    """The table is keyed by the `device_kind` the chip really reports
+    (a v5e says "TPU v5 lite") with the published peaks; a non-CPU
+    device it does not know is an error, never the CPU's roofline."""
+    import jax
+
+    class _Dev:
+        device_kind = kind
+
+    monkeypatch.setattr(jax, "devices", lambda *a: [_Dev()])
+    if tflops is None:
+        with pytest.raises(LookupError, match="TPU v9 imagined"):
+            C.detect_spec()
+    else:
+        spec = C.detect_spec()
+        assert spec.as_dict() == {"name": kind, "peak_tflops": tflops,
+                                  "peak_gbps": 819.0, "hbm_gb": 16.0}
+
+
 # ----------------------------------------------------------------------
 # goodput under faults
 # ----------------------------------------------------------------------
@@ -261,8 +282,13 @@ def test_goodput_drops_under_faults_and_recovers():
                        max_fires=1):
         sched = Scheduler(max_queue=16)
         rs = np.random.RandomState(11)
-        bad = [sched.submit(_mk_request(rs, D, V, nmax=8))
-               for _ in range(4)]
+        bad = [_mk_request(rs, D, V, nmax=8) for _ in range(4)]
+        for r in bad:
+            # the wave must still be decoding when the third step
+            # fails: no eos (a 17-token vocab emits it within a token
+            # or two under some weight draws) and a full budget
+            r.eos_id, r.max_new_tokens = None, 8
+            sched.submit(r)
         eng.serve_until_idle(sched, max_iterations=2000)
         for r in bad:
             r.result(timeout=5)

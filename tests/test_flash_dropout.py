@@ -1,10 +1,9 @@
-"""Flash-attention in-kernel dropout: dispatch plumbing (CPU) and, when
-a real TPU is attached (PT_RUN_TPU_TESTS=1, run OUTSIDE the CPU-pinned
-suite), the numeric validations r05 performed on-chip: P=0 parity,
-per-seed determinism, unbiasedness of outputs and grads over seeds, and
-analytic-vs-XLA grad agreement."""
-import os
-
+"""Flash-attention in-kernel dropout: dispatch plumbing (CPU). The
+compiled kernels draw from the Mosaic PRNG, which has no CPU lowering, so
+its statistics (same seed equal, other seed differs, unbiased outputs) are
+checked on the chip by `chip_smoke.py`'s kernel phase; the numeric test
+here is skipped on the CPU backend, which is the only one the suite runs
+on."""
 import numpy as np
 import pytest
 
@@ -52,18 +51,13 @@ def test_drop_consts():
     assert int(t1) <= 2 ** 32 - 1
 
 
-@pytest.mark.skipif(os.environ.get("PT_RUN_TPU_TESTS") != "1",
-                    reason="needs a real TPU (kernel PRNG has no CPU "
-                           "interpret lowering); run standalone with "
-                           "PT_RUN_TPU_TESTS=1")
 def test_flash_dropout_numerics_on_tpu():
     import jax
     import jax.numpy as jnp
 
-    if jax.default_backend() in ("cpu",):
-        pytest.skip("process is CPU-pinned (tests/conftest.py); run "
-                    "via `PT_RUN_TPU_TESTS=1 python -m pytest "
-                    "--noconftest tests/test_flash_dropout.py`")
+    if jax.default_backend() == "cpu":
+        pytest.skip("the Mosaic PRNG has no CPU lowering; chip_smoke.py's "
+                    "kernel phase checks its statistics on the chip")
 
     b, h, s, d = 1, 2, 512, 64
     rs = np.random.RandomState(0)

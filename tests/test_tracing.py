@@ -540,7 +540,7 @@ def test_prometheus_rendering():
     assert "paging" not in to_prometheus(ServingMetrics().snapshot())
 
 
-def test_readme_documents_snapshot_keys_and_span_taxonomy():
+def test_readme_documents_snapshot_keys_and_span_catalog():
     import os
 
     readme = open(os.path.join(os.path.dirname(__file__), "..",
@@ -548,9 +548,9 @@ def test_readme_documents_snapshot_keys_and_span_taxonomy():
     for key in SNAPSHOT_DOCS:
         assert f"`{key}`" in readme, \
             f"README metrics table is missing `{key}`"
-    for name, _ in rt.SPAN_TAXONOMY:
+    for name, _ in rt.SPAN_CATALOG:
         assert f"`{name}`" in readme, \
-            f"README span-taxonomy table is missing `{name}`"
+            f"README span-catalog table is missing `{name}`"
 
 
 # ----------------------------------------------------------------------

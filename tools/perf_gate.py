@@ -1,8 +1,8 @@
 #!/usr/bin/env python
 """Noise-aware perf-regression gate over the committed baselines.
 
-The BENCH_r01 -> r05 trajectory (4.44x on ernie_base) was guarded only
-by hand-read JSON: a silent perf regression would ship. This gate
+Committed timings guarded only by hand-read JSON let a silent perf
+regression ship. This gate
 turns the committed `OP_BENCH.json` / `BENCH_DETAILS.json` baselines
 into a standing assertion: re-measure a row set fresh, compare each
 row against its baseline under a per-row relative tolerance
