@@ -77,13 +77,17 @@ class MultiHeadAttention(Layer):
         v = self._split_heads(self.v_proj(value))
         from ...serving.paging import PagedKVCache
 
-        if isinstance(cache, PagedKVCache):
-            out, cache = self._paged_kv_attention(q, k, v, attn_mask,
-                                                  cache)
-            return self.out_proj(out), cache
-        if isinstance(cache, self.StaticKVCache):
-            out, cache = self._static_kv_attention(q, k, v, attn_mask,
-                                                   cache)
+        # the KV-cache paths run inside the serving programs: the scope
+        # tells the cache write, the kernel and the copies that feed it
+        # from every other operation of the program in a profiler trace
+        if isinstance(cache, (PagedKVCache, self.StaticKVCache)):
+            import jax
+
+            attend = (self._paged_kv_attention
+                      if isinstance(cache, PagedKVCache)
+                      else self._static_kv_attention)
+            with jax.named_scope("attn"):
+                out, cache = attend(q, k, v, attn_mask, cache)
             return self.out_proj(out), cache
         if isinstance(cache, self.StaticCache):
             k, v = cache.k, cache.v

@@ -427,7 +427,8 @@ def _flash_fwd_kernels(b, h, sq, sk, d, s, is_causal, has_bias, block_q,
             num_scalar_prefetch=1, grid=(b * h, nq),
             in_specs=in_specs, out_specs=out_specs)
         return pl.pallas_call(kernel, grid_spec=grid_spec,
-                              out_shape=out_shape, interpret=interpret)
+                              out_shape=out_shape, interpret=interpret,
+                              name="flash_fwd")
     return pl.pallas_call(
         kernel,
         grid=(b * h, nq),
@@ -435,6 +436,7 @@ def _flash_fwd_kernels(b, h, sq, sk, d, s, is_causal, has_bias, block_q,
         out_specs=out_specs,
         out_shape=out_shape,
         interpret=interpret,
+        name="flash_fwd",
     )
 
 
@@ -690,13 +692,15 @@ def flash_attention_bwd(q, k, v, bias, out, lse, g, is_causal, scale,
             in_specs=dq_in, out_specs=dq_out_spec)
         dq = pl.pallas_call(dq_kernel, grid_spec=dq_grid,
                             out_shape=dq_out_shape,
-                            interpret=interpret)(seed, *dq_args)
+                            interpret=interpret,
+                            name="flash_bwd_dq")(seed, *dq_args)
     else:
         dq = pl.pallas_call(
             dq_kernel, grid=(b * h, nq), in_specs=dq_in,
             out_specs=dq_out_spec,
             out_shape=dq_out_shape,
             interpret=interpret,
+            name="flash_bwd_dq",
         )(*dq_args)
 
     def dkv_kernel(*refs):
@@ -852,12 +856,14 @@ def flash_attention_bwd(q, k, v, bias, out, lse, g, is_causal, scale,
             in_specs=dkv_in, out_specs=out_specs)
         outs = pl.pallas_call(dkv_kernel, grid_spec=dkv_grid,
                               out_shape=out_shape,
-                              interpret=interpret)(seed, *dkv_args)
+                              interpret=interpret,
+                              name="flash_bwd_dkv")(seed, *dkv_args)
     else:
         outs = pl.pallas_call(
             dkv_kernel, grid=(b * h, nk), in_specs=dkv_in,
             out_specs=out_specs, out_shape=out_shape,
             interpret=interpret,
+            name="flash_bwd_dkv",
         )(*dkv_args)
     if has_bias:
         dk, dv, db_bh = outs
@@ -1391,7 +1397,8 @@ def _flash_decode_call(b, h, L, d, s, n_splits, has_bias, interpret):
         num_scalar_prefetch=1, grid=(b * h, n_splits),
         in_specs=in_specs, out_specs=out_specs)
     return pl.pallas_call(kernel, grid_spec=grid_spec,
-                          out_shape=out_shape, interpret=interpret)
+                          out_shape=out_shape, interpret=interpret,
+                          name="flash_decode")
 
 
 def flash_decode(q, k, v, length, bias=None, scale=None, split_k=None,
@@ -1599,7 +1606,8 @@ def _flash_verify_call(b, h, L, d, T, s, n_splits, has_bias, interpret):
         num_scalar_prefetch=1, grid=(b * h, n_splits),
         in_specs=in_specs, out_specs=out_specs)
     return pl.pallas_call(kernel, grid_spec=grid_spec,
-                          out_shape=out_shape, interpret=interpret)
+                          out_shape=out_shape, interpret=interpret,
+                          name="flash_verify")
 
 
 def flash_verify(q, k, v, length, bias=None, scale=None, split_k=None,
@@ -1778,7 +1786,8 @@ def _paged_flash_decode_call(S, h, mp, psz, d, s, has_scale, has_bias,
         num_scalar_prefetch=2, grid=(S * h, mp),
         in_specs=in_specs, out_specs=out_specs)
     return pl.pallas_call(kernel, grid_spec=grid_spec,
-                          out_shape=out_shape, interpret=interpret)
+                          out_shape=out_shape, interpret=interpret,
+                          name="paged_flash_decode")
 
 
 def paged_flash_decode(q, k_pages, v_pages, k_scale, v_scale, table,
@@ -1972,7 +1981,8 @@ def _paged_flash_verify_call(S, h, mp, psz, d, T, s, has_scale,
         num_scalar_prefetch=2, grid=(S * h, mp),
         in_specs=in_specs, out_specs=out_specs)
     return pl.pallas_call(kernel, grid_spec=grid_spec,
-                          out_shape=out_shape, interpret=interpret)
+                          out_shape=out_shape, interpret=interpret,
+                          name="paged_flash_verify")
 
 
 def paged_flash_verify(q, k_pages, v_pages, k_scale, v_scale, table,

@@ -116,7 +116,7 @@ def _fwd_call(B, Ci, Co, HW, relu, has_norm, dtype_str, interpret):
     ]
     return pl.pallas_call(kernel, grid=(B,), in_specs=in_specs,
                           out_specs=out_specs, out_shape=out_shape,
-                          interpret=interpret)
+                          interpret=interpret, name="fused_conv_fwd")
 
 
 @functools.lru_cache(maxsize=None)
@@ -195,7 +195,7 @@ def _bwd_call(B, Ci, Co, HW, relu, has_norm, dtype_str, interpret):
     ]
     return pl.pallas_call(kernel, grid=(B,), in_specs=in_specs,
                           out_specs=out_specs, out_shape=out_shape,
-                          interpret=interpret)
+                          interpret=interpret, name="fused_conv_bwd")
 
 
 @functools.lru_cache(maxsize=None)

@@ -139,7 +139,7 @@ def _int8_matmul_call(m, d, n, bm, bn, interpret):
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), jnp.float32),
-        interpret=interpret)
+        interpret=interpret, name="int8_matmul")
 
 
 def _tuned_int8_blocks(m, d, n, dtype, block_m=None, block_n=None):
@@ -267,7 +267,7 @@ def _lora_gather_call(b, s, d, r, n_out, interpret):
     return pl.pallas_call(
         kernel, grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, s, n_out), jnp.float32),
-        interpret=interpret)
+        interpret=interpret, name="lora_gather")
 
 
 def lora_delta(x, A, B, ids, interpret=False):

@@ -16,6 +16,7 @@ from __future__ import annotations
 from typing import Callable, Optional
 
 from ..optimizer import functional as fopt
+from ..profiler import RecordEvent
 from .functional import functionalize
 from .mesh import DeviceMesh, get_mesh
 from .sharding import (ShardingRules, batch_sharding, infer_param_specs,
@@ -49,6 +50,7 @@ class SpmdTrainer:
         self.batch_axes = batch_axes
         self._step_fn = None
         self._eval_fn = None
+        self._steps = 0   # calls of step(): the profiler's step number
 
         params = self.fm.params()
         buffers = self.fm.buffers()
@@ -145,8 +147,21 @@ class SpmdTrainer:
         # on a mesh of several devices XLA partitions this program, and
         # it cannot partition a Pallas kernel: attention then takes its
         # XLA composition
+        # named for the profiler: the module is `jit_train_step`, and
+        # every device operation's `op_name` is under `train_step/fwd_bwd`
+        # or `train_step/optimizer`
         @_attn.partitioned_trace(self.mesh.mesh.size > 1)
-        def step(params, opt_state, buffers, rng, inputs, labels):
+        def train_step(params, opt_state, buffers, rng, inputs, labels):
+            with jax.named_scope("train_step"):
+                with jax.named_scope("fwd_bwd"):
+                    loss, buffers, grads = fwd_bwd(
+                        params, buffers, rng, inputs, labels)
+                with jax.named_scope("optimizer"):
+                    new_params, new_opt = self.tx.update(
+                        params, grads, opt_state)
+            return new_params, new_opt, buffers, loss
+
+        def fwd_bwd(params, buffers, rng, inputs, labels):
             grad_fn = jax.value_and_grad(self._forward_loss, has_aux=True)
 
             if accum > 1:
@@ -175,11 +190,10 @@ class SpmdTrainer:
             else:
                 (loss, buffers), grads = grad_fn(
                     params, buffers, rng, tuple(inputs), labels)
+            return loss, buffers, grads
 
-            new_params, new_opt = self.tx.update(params, grads, opt_state)
-            return new_params, new_opt, buffers, loss
-
-        self._raw_step = step
+        train_step.__name__ = train_step.__qualname__ = "train_step"
+        self._raw_step = train_step
 
         in_shardings = (
             self.param_shardings,
@@ -197,7 +211,7 @@ class SpmdTrainer:
         donate = (0, 1, 2) if self._donate else ()
         with self.mesh.mesh:
             self._step_fn = jax.jit(
-                step, in_shardings=in_shardings,
+                train_step, in_shardings=in_shardings,
                 out_shardings=out_shardings, donate_argnums=donate)
         return self._step_fn
 
@@ -215,20 +229,30 @@ class SpmdTrainer:
         return tuple(out)
 
     def step(self, inputs, labels, rng=None):
-        import jax
-
+        """One update. Its host phases are profiler annotations (no
+        tracer session needed): `train.step` with the step's number,
+        and under it `train.next_key` (drawing the step's key),
+        `train.shard` (the batch onto the mesh) and `train.enqueue`
+        (the call of the compiled step). Nothing is read back here:
+        the job reads the loss when it wants it."""
         if self._step_fn is None:
             self._build_step()
-        if rng is None:
-            from ..core import random as _random
+        self._steps += 1
+        with RecordEvent("train.step", "step", step_num=self._steps):
+            if rng is None:
+                from ..core import random as _random
 
-            rng = _random.next_key()
-        inputs = tuple(inputs) if isinstance(inputs, (list, tuple)) \
-            else (inputs,)
-        data = self.shard_batch(*inputs, labels)
-        inputs, labels = data[:-1], data[-1]
-        self.params, self.opt_state, self.buffers, loss = self._step_fn(
-            self.params, self.opt_state, self.buffers, rng, inputs, labels)
+                with RecordEvent("train.next_key", "step"):
+                    rng = _random.next_key()
+            inputs = tuple(inputs) if isinstance(inputs, (list, tuple)) \
+                else (inputs,)
+            with RecordEvent("train.shard", "step"):
+                data = self.shard_batch(*inputs, labels)
+            inputs, labels = data[:-1], data[-1]
+            with RecordEvent("train.enqueue", "step"):
+                self.params, self.opt_state, self.buffers, loss = \
+                    self._step_fn(self.params, self.opt_state,
+                                  self.buffers, rng, inputs, labels)
         return loss
 
     def run_steps(self, inputs, labels, n_steps, rng=None):
@@ -349,17 +373,18 @@ class SpmdTrainer:
         import jax
 
         if self._eval_fn is None:
-            def ev(params, buffers, inputs):
-                if self.compute_dtype is not None:
-                    cast = lambda t: t.astype(self.compute_dtype) if hasattr(  # noqa
-                        t, "dtype") and "float" in str(t.dtype) else t
-                    params = {n: cast(v) for n, v in params.items()}
-                out, _ = self.fm.apply(params, buffers, None, *inputs,
-                                       training=False)
+            def eval_step(params, buffers, inputs):
+                with jax.named_scope("eval_step"):
+                    if self.compute_dtype is not None:
+                        cast = lambda t: t.astype(self.compute_dtype) if hasattr(  # noqa
+                            t, "dtype") and "float" in str(t.dtype) else t
+                        params = {n: cast(v) for n, v in params.items()}
+                    out, _ = self.fm.apply(params, buffers, None,
+                                           *inputs, training=False)
                 return out
 
             with self.mesh.mesh:
-                self._eval_fn = jax.jit(ev)
+                self._eval_fn = jax.jit(eval_step)
         inputs = tuple(inputs) if isinstance(inputs, (list, tuple)) \
             else (inputs,)
         return self._eval_fn(self.params, self.buffers,

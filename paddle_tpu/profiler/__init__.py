@@ -42,45 +42,41 @@ def set_events_capacity(cap):
 
 class RecordEvent:
     """platform/profiler.h:126 parity; also usable as a decorator.
-    Records (name, event_type, duration) host-side, forwards the name
-    to jax.profiler.TraceAnnotation, and — when a `profiler.trace`
-    session is active — surfaces the event as a span in the tracer.
+    Records (name, event_type, duration) host-side, enters a profiler
+    annotation of the same name (`trace.annotation`, the one place
+    that builds them), and — when a `profiler.trace` session is
+    active — surfaces the event as a span in the tracer.
 
-    Span links: when the XPlane device trace is running in lockstep
-    with a tracer session (`start_profiler`), the span is opened at
-    __enter__ so its (trace_id, span_id) identity EXISTS before the
-    device work runs, and both ids are stamped into the
-    TraceAnnotation metadata — Perfetto shows them on the XPlane
-    event's args, so a host span and its device timeline region
-    correlate by id. Pass `trace_id=` to link the event to a request's
-    trace (serving code passes the request id)."""
+    Span links: under a session the span is opened first, so its
+    (trace_id, span_id) identity exists before the device work runs
+    and rides in the annotation's metadata — Perfetto shows both on
+    the XPlane event's args, so a host span and its device timeline
+    region correlate by id. Pass `trace_id=` to link the event to a
+    request's trace (serving code passes the request id); `step_num=`
+    makes it a step annotation (the profiler's `Steps` line). This is
+    the spelling for code that runs without a session (the trainer)."""
 
-    def __init__(self, name, event_type="op", trace_id=0):
+    def __init__(self, name, event_type="op", trace_id=0,
+                 step_num=None):
         self.name = name
         self.event_type = event_type
         self.trace_id = int(trace_id)
+        self.step_num = step_num
         self._ann = None
         self._t0 = None
-        self._span = None
+        self._span = self._tracer = None
 
     def __enter__(self):
-        import jax
-
         tr = trace._SESSION
         if tr is not None:
-            # open the span FIRST so its id can ride into the XPlane
+            self._tracer = tr
             self._span = tr.begin(self.name, cat="record_event",
                                   trace_id=self.trace_id,
-                                  attrs={"event_type": self.event_type})
-            if _active:
-                # lockstep XPlane trace: stamp the span identity into
-                # the device-timeline event metadata (span links)
-                self._ann = jax.profiler.TraceAnnotation(
-                    self.name, trace_id=self.trace_id,
-                    span_id=self._span.span_id)
-        if self._ann is None:
-            self._ann = jax.profiler.TraceAnnotation(self.name)
-        self._ann.__enter__()
+                                  attrs={"event_type": self.event_type},
+                                  forward=True, step_num=self.step_num)
+        else:
+            self._ann = trace.annotation(self.name, self.step_num)
+            self._ann.__enter__()
         self._t0 = time.perf_counter()
         return self
 
@@ -88,14 +84,13 @@ class RecordEvent:
         t1 = time.perf_counter()
         dt = t1 - self._t0
         _events.append((self.name, self.event_type, dt))
-        tr = trace._SESSION
         if self._span is not None:
-            if tr is not None:
-                tr.end(self._span)
-            self._span = None
-        elif tr is not None:
-            tr.add_complete(self.name, self._t0, t1, cat="record_event",
-                            attrs={"event_type": self.event_type})
+            # on the tracer that opened it, whatever the session is now
+            self._tracer.end(self._span)
+            self._span = self._tracer = None
+        else:
+            self._ann.__exit__(*exc)
+            self._ann = None
         # _host_lib is only non-None after enable_host_trace(): the native
         # build/load never happens (nor does any lock) on the hot path
         # unless host tracing was explicitly turned on.
@@ -103,7 +98,6 @@ class RecordEvent:
             now = _host_lib.pt_prof_now_ns()
             _host_lib.pt_prof_record(self.name.encode(),
                                      now - int(dt * 1e9), now)
-        self._ann.__exit__(*exc)
         return False
 
 

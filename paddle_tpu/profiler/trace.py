@@ -13,6 +13,15 @@ module-global read per hit when nothing is armed. Production code
 guards every tracing call site with ``if trace._SESSION is not None:``
 — no function call, no allocation, when disabled.
 
+Spans that ONE thread brackets (``with tr.span(...)``, or
+``begin(..., forward=True)`` / ``end``) are also forwarded to the JAX
+profiler as a `TraceAnnotation` of the same name carrying `span_id`
+and the span's numeric attributes, so a `jax.profiler` trace shows the
+program's own phases on the device's clock. `annotation()` is the one
+function in paddle_tpu that constructs them (`profiler.RecordEvent`
+shares it). Lifecycle spans that begin on one thread and end on
+another, and `add_complete` spans, are not forwarded.
+
 Three cooperating pieces:
 
   * **Tracer / sessions** — `start_session()` installs the module-wide
@@ -41,8 +50,10 @@ import logging
 import threading
 import time
 
+import jax
+
 __all__ = [
-    "Span", "Tracer", "start_session", "end_session", "session",
+    "Span", "Tracer", "annotation", "start_session", "end_session", "session",
     "session_scope", "ObservedCounter", "JitCache", "RetraceError",
     "RetraceSentinel", "retrace_sentinel", "add_compile_hook",
     "remove_compile_hook", "suppress_observation", "record_precompile",
@@ -107,6 +118,24 @@ def suppress_observation():
             _SUPPRESS = prev
 
 
+def annotation(name, step_num=None, **meta):
+    """The one place paddle_tpu builds a profiler annotation: a
+    `jax.profiler.TraceAnnotation` (a `StepTraceAnnotation` when
+    `step_num` is given, which fills the profiler's `Steps` line) with
+    `meta` as keyword metadata — numbers only; they come back as the
+    event's `stats` in `jax.profiler.ProfileData`. Outside a profiler
+    trace it costs well under a microsecond."""
+    if step_num is not None:
+        return jax.profiler.StepTraceAnnotation(name, step_num=step_num,
+                                                **meta)
+    return jax.profiler.TraceAnnotation(name, **meta)
+
+
+def _numeric(attrs):
+    return {k: v for k, v in attrs.items()
+            if isinstance(v, (int, float))}
+
+
 def _key_str(key):
     s = str(key)
     return s if len(s) <= 120 else s[:117] + "..."
@@ -117,7 +146,7 @@ class Span:
     `time.perf_counter()` seconds (monotonic, host-side)."""
 
     __slots__ = ("name", "cat", "trace_id", "span_id", "parent_id",
-                 "t0", "t1", "attrs")
+                 "t0", "t1", "attrs", "ann")
 
     def __init__(self, name, cat, trace_id, span_id, parent_id, t0,
                  attrs):
@@ -129,6 +158,7 @@ class Span:
         self.t0 = t0
         self.t1 = None
         self.attrs = attrs
+        self.ann = None     # the entered profiler annotation, if any
 
     @property
     def duration_s(self):
@@ -181,26 +211,56 @@ class Tracer:
         return self._clock()
 
     def begin(self, name, *, cat="span", trace_id=0, parent=None,
-              attrs=None):
+              attrs=None, forward=False, step_num=None):
+        """Open a span. `forward=True` (only where the SAME thread will
+        end it) also enters a profiler annotation of the same name with
+        `span_id`, `trace_id` and the numeric attributes as metadata;
+        `end()` adds the numeric attributes it is given and exits it."""
         sp = Span(name, cat, int(trace_id), next(self._ids),
                   None if parent is None else parent.span_id,
                   self._clock(), dict(attrs) if attrs else {})
         with self._lock:
             self._open[sp.span_id] = sp
+        if forward:
+            meta = _numeric(sp.attrs)
+            meta["span_id"], meta["trace_id"] = sp.span_id, sp.trace_id
+            sp.ann = annotation(name, step_num, **meta)
+            sp.ann.__enter__()
         return sp
 
-    def end(self, span, **attrs):
+    def end(self, span, _record=True, **attrs):
+        """Close a span. `_record=False` closes it without putting it in
+        the ring: the caller holds it and either `commit`s it later or
+        drops it (an engine iteration that turned out to be an idle
+        spin leaves nothing behind)."""
         if span is None or span.t1 is not None:
             return span
+        ann, span.ann = span.ann, None
+        if ann is not None:
+            meta = _numeric(attrs)
+            if meta:
+                ann.set_metadata(**meta)
+            ann.__exit__(None, None, None)
         span.t1 = self._clock()
         if attrs:
             span.attrs.update(attrs)
         with self._lock:
             self._open.pop(span.span_id, None)
-            if len(self._spans) == self.capacity:
-                self.dropped += 1
-            self._spans.append(span)
+            if _record:
+                self._append(span)
         return span
+
+    # callers (end, commit, add_complete) hold self._lock
+    def _append(self, span):  # analysis: single-threaded
+        if len(self._spans) == self.capacity:
+            self.dropped += 1
+        self._spans.append(span)
+
+    def commit(self, spans):
+        """Put spans closed with `_record=False` in the ring."""
+        with self._lock:
+            for sp in spans:
+                self._append(sp)
 
     def add_complete(self, name, t0, t1, *, cat="span", trace_id=0,
                      parent=None, attrs=None):
@@ -209,9 +269,7 @@ class Tracer:
                   t0, dict(attrs) if attrs else {})
         sp.t1 = t1
         with self._lock:
-            if len(self._spans) == self.capacity:
-                self.dropped += 1
-            self._spans.append(sp)
+            self._append(sp)
         return sp
 
     def instant(self, name, *, cat="span", trace_id=0, parent=None,
@@ -222,7 +280,9 @@ class Tracer:
 
     @contextlib.contextmanager
     def span(self, name, **kw):
-        sp = self.begin(name, **kw)
+        """One thread brackets the span, so it is forwarded to the
+        profiler too."""
+        sp = self.begin(name, forward=True, **kw)
         try:
             yield sp
         finally:

@@ -194,6 +194,9 @@ class _EngineBase:
         # from the decode-step active mask until _poll_pending splices
         self._pending = set()
         self._last_step_done = None   # decode-step inter-arrival clock
+        #: the running iteration's engine-track spans (tracing.
+        #: IterationTrace) under a tracer session, else None
+        self._iter_trace = None
         # trace_counts is observable: the retrace sentinel / tracer see
         # every increment (= one jax trace = one compile) as it happens
         self.trace_counts = _trace.ObservedCounter(
@@ -550,6 +553,9 @@ class _EngineBase:
         return self._join(s, r)
 
     def _decode_attempt(self, active):
+        it = self._iter_trace
+        if it is not None:
+            it.unwind(it.step)    # a failed attempt's open spans
         _PT_DECODE()
         return self._decode_step(active)
 
@@ -641,9 +647,26 @@ class _EngineBase:
     def run_iteration(self, scheduler):
         """One iteration: harvest faults, admit new work, decode one
         token for every active slot. Returns True when any work was
-        done (False = idle: empty queue, empty pool)."""
+        done (False = idle: empty queue, empty pool). Under a tracer
+        session the iteration's phases are engine-track spans
+        (`serving/tracing.py` `IterationTrace`)."""
+        tr = _trace._SESSION
+        if tr is None:
+            return self._iterate(scheduler, None)
+        it = self._iter_trace = _rt.IterationTrace(tr)
+        progress, attrs = False, {}
+        try:
+            progress = self._iterate(scheduler, it, attrs)
+            return progress
+        finally:
+            self._iter_trace = None
+            it.close(progress, **attrs)
+
+    def _iterate(self, scheduler, it, it_attrs=None):
         now = self.clock()
         progress = False
+        if it is not None:
+            sp = it.begin("iter.harvest")
         # 1. fault harvest: cancellation + deadline eviction happen at
         # iteration boundaries — partial tokens are delivered
         for s, r in enumerate(self.slots):
@@ -659,6 +682,9 @@ class _EngineBase:
         # prefills into the pool (no-op for synchronous engines)
         if self._poll_pending(now):
             progress = True
+        if it is not None:
+            it.end(sp)
+            sp = it.begin("iter.admit")
         # 2. admission: refill free slots, bounded per iteration
 
         def _queue_death(req):   # cancelled/expired while QUEUED
@@ -701,8 +727,8 @@ class _EngineBase:
             s = self._choose_slot(free)
             r.state, r.slot = "RUNNING", s
             self.slots[s] = r
-            if _trace._SESSION is not None:
-                _rt.on_join_begin(r, s)
+            if it is not None:
+                _rt.on_join_begin(r, s, sp)
             try:
                 tok = self._guarded("slot_join",
                                     lambda: self._join_attempt(s, r))
@@ -749,14 +775,22 @@ class _EngineBase:
         # host sync instead of a blocking int() per join). A request
         # finishing at token 0 frees its slot an iteration late — the
         # decode step's active mask already excludes DONE slots.
+        if it is not None:
+            it.end(sp, joins=joins)
+            sp = it.begin("iter.tok0", n=len(tok0s))
         for r, tok in tok0s:
             self._deliver(r, int(tok), self.clock())
+        if it is not None:
+            it.end(sp)
+            sp = it.begin("iter.chunks")
         # 2b. chunked prefill: one chunk per mid-prefill slot, BEFORE
         # the decode step — a freshly chunk-joined slot's first chunk
         # must set the pool index past its pad hole before any masked
         # decode-step write can land inside the prompt region
         if self._advance_chunks(self.clock()):
             progress = True
+        if it is not None:
+            it.end(sp)
         # 3. one batched decode step over the active mask (slots with a
         # disaggregated prefill still in flight stay masked out)
         active = np.asarray(
@@ -764,21 +798,24 @@ class _EngineBase:
              for s, r in enumerate(self.slots)], bool)
         if active.any():
             t0 = self.clock()
-            _ts0 = (time.perf_counter()
-                    if _trace._SESSION is not None else 0.0)
+            if it is not None:
+                it.begin_step()
             try:
                 toks = self._guarded(
                     "decode_step", lambda: self._decode_attempt(active),
                     retry_tokens=int(active.sum()))
             except Exception as e:
+                if it is not None:
+                    it.end_step(self, active, scheduler,
+                                error=type(e).__name__)
                 self.metrics.record_error("decode_step", e)
                 self._fail_active(e)
                 progress = True
             else:
                 now2 = self.clock()
-                if _trace._SESSION is not None:
-                    _rt.on_decode_step(self, _ts0, time.perf_counter(),
-                                       active, scheduler)
+                if it is not None:
+                    it.end_step(self, active, scheduler)
+                    sp = it.begin("iter.deliver")
                 n = 0
                 if isinstance(toks, tuple):
                     # speculative step: (emit [S, k], n_emit [S]) —
@@ -815,17 +852,27 @@ class _EngineBase:
                         now2 - self._last_step_done)
                 self._last_step_done = now2
                 progress = True
+                if it is not None:
+                    it.end(sp, tokens=n)
         else:
             self._last_step_done = None
-        self.metrics.record_iteration(
-            scheduler.depth(), self.occupancy() / self.num_slots,
-            **(self._iteration_gauges() or {}))
+        if it is not None:
+            sp = it.begin("iter.account")
+        # computed ONCE an iteration: it has a side effect (the memory
+        # watermark check), and the spans carry what the metrics record
+        gauges = self._iteration_gauges() or {}
+        depth, occ = scheduler.depth(), self.occupancy()
+        self.metrics.record_iteration(depth, occ / self.num_slots,
+                                      **gauges)
         lag_fn = getattr(scheduler, "wfq_lag_by_tenant", None)
         if lag_fn is not None:
             self.metrics.set_wfq_lag(lag_fn())
         self._cbs.emit("on_iteration", {
-            "queue_depth": scheduler.depth(),
-            "occupancy": self.occupancy(), "joins": joins})
+            "queue_depth": depth, "occupancy": occ, "joins": joins})
+        if it is not None:
+            it.end(sp)
+            it_attrs.update(joins=joins, occupancy=occ,
+                            queue_depth=depth, gauges=gauges)
         return progress
 
     def serve_until_idle(self, scheduler, max_iterations=None):
@@ -1553,7 +1600,9 @@ class ServingEngine(_EngineBase):
         # every placement builds it plain
         import jax
 
-        return jax.jit(self.layout.draft_body(dkey))
+        from .layers import named_program
+
+        return jax.jit(named_program(dkey, self.layout.draft_body(dkey)))
 
     # ------------------------------------------------------------------
     # zero-warmup startup: AOT precompile + persistent cache

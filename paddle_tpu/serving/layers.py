@@ -969,6 +969,26 @@ class PagedLayout(CacheLayout):
 # placements: how a body becomes a compiled program
 # --------------------------------------------------------------------------
 
+def named_program(key, body):
+    """`body` traced under `jax.named_scope(key[0])` and named
+    `key[0]`: the compiled module is `jit_<kind>` (`jit_pstep`,
+    `jit_pjoin`, ...), the host's dispatch event
+    `PjitFunction(<kind>)` (`PjitFunction(jit(<kind>))` once precompiled
+    ahead of time), and every device operation's `op_name`
+    starts `jit(<kind>)/<kind>/`, so a profiler trace tells the pool's
+    programs apart whatever their bodies are called."""
+    import jax
+
+    kind = key[0]
+
+    def program(*args):
+        with jax.named_scope(kind):
+            return body(*args)
+
+    program.__name__ = program.__qualname__ = kind
+    return program
+
+
 class SinglePlacement:
     """Plain `jax.jit` with the engine's shared donation declaration —
     the single-chip build path every engine used before placement was
@@ -984,7 +1004,7 @@ class SinglePlacement:
     def build(self, key, body, has_aux=True):
         import jax
 
-        return jax.jit(body,
+        return jax.jit(named_program(key, body),
                        donate_argnums=self.eng._donate_argnums(key))
 
 
@@ -1050,7 +1070,8 @@ class ShardedPlacement:
                 return self.constrain_state(st), aux
             return self.constrain_state(out)
 
-        return jax.jit(fn, donate_argnums=self.eng._donate_argnums(key))
+        return jax.jit(named_program(key, fn),
+                       donate_argnums=self.eng._donate_argnums(key))
 
     def place_state(self, state):
         """Lay the freshly-built pool state out on the decode mesh:
@@ -1109,9 +1130,16 @@ class PlainStepper:
 
         eng = self.eng
         lay = eng.layout
+        it = eng._iter_trace      # engine-track spans, None when off
+        if it is not None:
+            sp = it.begin("step.map_pages")
         active = lay.map_step_pages(active, 1)
+        if it is not None:
+            it.end(sp)
         if not active.any():
             return np.zeros((eng.num_slots,), np.int64)
+        if it is not None:
+            sp = it.begin("step.enqueue")
         key = lay.step_key()
         fn = eng._program(key, lambda: eng._build_step(key))
         eng._state, toks = fn(eng._params(), eng._buffers(),
@@ -1119,7 +1147,13 @@ class PlainStepper:
                               *eng._adapter_args(),
                               jnp.asarray(active))
         lay.advance_rows(active.astype(np.int64))
-        return np.asarray(toks)
+        if it is not None:
+            it.end(sp)
+            sp = it.begin("step.readback")
+        toks = np.asarray(toks)
+        if it is not None:
+            it.end(sp)
+        return toks
 
 
 class SpecStepper:
@@ -1179,9 +1213,14 @@ class SpecStepper:
 
         eng = self.eng
         lay = eng.layout
+        it = eng._iter_trace      # engine-track spans, None when off
+        if it is not None:
+            sp = it.begin("step.map_pages")
         # the verify write is the FULL fixed-k block (force-rejected
         # tail included), so the paged pool maps every page it touches
         active = lay.map_step_pages(active, eng.spec_k)
+        if it is not None:
+            it.end(sp)
         if not active.any():
             S, k = eng.num_slots, eng.spec_k
             return (np.zeros((S, k), np.int64), np.zeros((S,), np.int64))
@@ -1189,13 +1228,25 @@ class SpecStepper:
             [r is not None and getattr(r, "spec", True)
              for r in eng.slots], bool)
         st = eng._state
+        n_active = int(active.sum())
+        on = active & spec_on
+        on_count = int(on.sum())
+        proposed = on_count * (self.k_eff - 1)
         dkey = lay.draft_key()
         fn = eng._program(dkey, lambda: eng._build_draft(dkey))
+        if it is not None:
+            sp = it.begin("decode.draft", n_active=n_active,
+                          proposed=proposed)
         t0 = time.perf_counter()
         drafts = fn(st["hist"], st["tok"], st["plen"], st["pbk"],
                     lay.row_index())
         jax.block_until_ready(drafts)
         t1 = time.perf_counter()
+        if it is not None:
+            it.end(sp)
+            sp_verify = it.begin("decode.verify", n_active=n_active,
+                                 proposed=proposed)
+            sp = it.begin("step.enqueue")
         vkey = lay.spec_step_key()
         fn = eng._program(vkey, lambda: eng._build_spec_step(vkey))
         eng._state, (emit, n_emit) = fn(
@@ -1203,26 +1254,23 @@ class SpecStepper:
             *lay.step_extra_args(), *eng._adapter_args(), drafts,
             jnp.asarray(active), jnp.asarray(spec_on),
             jnp.int32(self.k_eff))
+        if it is not None:
+            it.end(sp)
+            sp = it.begin("step.readback")
         emit = np.asarray(emit)
         n_emit = np.asarray(n_emit)
         t2 = time.perf_counter()
+        if it is not None:
+            it.end(sp)
         lay.advance_rows(n_emit)
-        on = active & spec_on
-        on_count = int(on.sum())
-        proposed = on_count * (self.k_eff - 1)
         accepted = int(np.maximum(n_emit[on] - 1, 0).sum()) \
             if on_count else 0
         self._adapt(on_count, accepted)
         eng.metrics.record_spec_step(
-            int(active.sum()), proposed, accepted, t1 - t0, t2 - t1,
+            n_active, proposed, accepted, t1 - t0, t2 - t1,
             k_eff=self.k_eff, variant=eng._pool_variant(),
             k_shrinks=self.k_shrink_events,
             k_grows=self.k_grow_events)
-        from ..profiler import trace as _trace
-
-        if _trace._SESSION is not None:
-            from . import tracing as _rt
-
-            _rt.on_spec_step(t0, t1, t2, int(active.sum()), proposed,
-                             accepted)
+        if it is not None:
+            it.end(sp_verify, accepted=accepted)
         return emit, n_emit

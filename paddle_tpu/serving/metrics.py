@@ -74,6 +74,11 @@ SNAPSHOT_DOCS = {
     # records
     "paging.pages_in_use": ("gauge", "pages mapped at last iteration"),
     "paging.pages_free": ("gauge", "allocator free pages"),
+    "paging.pages_total": ("gauge", "the pool's pages (in use + free)"),
+    "paging.page_iterations": (
+        "counter", "pages in use, summed over iterations: over any "
+                   "interval, its growth / (iterations x pages_total) "
+                   "is the mean share of the pool in use"),
     "paging.prefix_hits": ("counter",
                            "joins served from the prefix cache"),
     "paging.prefix_misses": ("counter", "joins that ran a real prefill"),
@@ -420,6 +425,7 @@ class ServingMetrics:
         # snapshot only grows a "paging" section for paged pools)
         self.pages_in_use = None    # last-iteration gauge
         self.pages_free = None
+        self.page_iterations = 0    # sum over iterations of pages_in_use
         self.prefix_hits = 0        # joins served from the prefix cache
         self.prefix_misses = 0      # joins that ran a real prefill
         # radix prefix-cache accounting (PR 16): the snapshot grows a
@@ -907,6 +913,7 @@ class ServingMetrics:
                 self.tenant_slots = dict(tenant_slots)
             if pages_in_use is not None:
                 self.pages_in_use = int(pages_in_use)
+                self.page_iterations += self.pages_in_use
             if pages_free is not None:
                 self.pages_free = int(pages_free)
             if trie_nodes is not None:
@@ -1069,6 +1076,9 @@ class ServingMetrics:
                 **({} if self.pages_in_use is None else {"paging": {
                     "pages_in_use": self.pages_in_use,
                     "pages_free": self.pages_free,
+                    "pages_total": self.pages_in_use
+                    + (self.pages_free or 0),
+                    "page_iterations": self.page_iterations,
                     "prefix_hits": self.prefix_hits,
                     "prefix_misses": self.prefix_misses,
                     "prefix_hit_rate": round(

@@ -51,6 +51,7 @@ from ..testing import faults
 from . import tracing as _rt
 from .engine import (PagedServingEngine, ServingEngine, _PT_PREFILL,
                      _tree_bytes)
+from .layers import named_program
 
 __all__ = ["ShardedServingEngine", "ShardedPagedServingEngine"]
 
@@ -367,7 +368,7 @@ class ShardedServingEngine(ServingEngine):
             kvs = [(c.k, c.v) for c in inc1]
             return tok0, kvs, static1, bias_row
 
-        return jax.jit(prefill_fn)
+        return jax.jit(named_program(key, prefill_fn))
 
     def _splice_math(self, Pb):
         """The per-entry splice math (no trace counter): land one
@@ -438,7 +439,7 @@ class ShardedServingEngine(ServingEngine):
         # the pool carry donates like the rest of the join family (the
         # shared _DONATED_KINDS declaration the PTA102 audit reads) —
         # the splice lands in the pool in place, no whole-pool copy
-        return jax.jit(splice_fn,
+        return jax.jit(named_program(key, splice_fn),
                        donate_argnums=self._donate_argnums(key))
 
     def _build_batched_splice(self, Pb, nb):
@@ -472,7 +473,7 @@ class ShardedServingEngine(ServingEngine):
             # explicit (the every-carry contract the analyzer audits)
             return self.placement.constrain_state(st)
 
-        return jax.jit(bsplice_fn,
+        return jax.jit(named_program(key, bsplice_fn),
                        donate_argnums=self._donate_argnums(key))
 
     def _fail_pending_splice(self, s, r, e):

@@ -8,6 +8,17 @@ and tests share. Instrumented call sites in scheduler/engine/server
 all guard with ``if _trace._SESSION is not None:`` — one module-global
 read when tracing is off.
 
+The engine-track spans (`iteration` and everything under it) and the
+request's `join` begin and end on the engine's thread, so they are
+also forwarded to the JAX profiler as `TraceAnnotation`s
+(`Tracer.begin(forward=True)`): a `jax.profiler` trace taken under a
+session shows the program's own phases beside the device's
+operations, and the root `iteration` annotation carries the tracer
+clock's reading at its start (`t0_perf_ns`), one (profiler ns,
+`perf_counter` ns) pair per iteration, which lays the spans that are
+NOT forwarded (`request`, `queue`, `decode`, `pending_splice`, every
+`add_complete` span) on the same clock.
+
 Span catalog (exported Chrome-trace names):
 
   request         per-request root: submit() -> finish/fail
@@ -35,13 +46,36 @@ Span catalog (exported Chrome-trace names):
                   frontier — and done on the final chunk)
   preempt         instant: the slot was evicted to the prefix cache to
                   free capacity (attrs: slot, tokens so far)
-  decode.step     engine track: one batched decode step (attrs:
+  iteration       engine track root: one run_iteration that did work
+                  (an idle spin is dropped from the tracer's record;
+                  a jax.profiler trace running at the time has
+                  already seen its annotations, so a reader of the
+                  profiler's events leaves out an `iteration` that
+                  holds no decode.step and no join; attrs: joins, n_active,
+                  occupancy, queue_depth, the page-pool and shard
+                  gauges, t0_perf_ns)
+  iter.harvest    cancellation / deadline sweep and the poll of
+                  disaggregated prefills
+  iter.admit      the admission loop; the admitted requests' join
+                  spans are its children (attrs: joins)
+  iter.tok0       resolving the round's first tokens: the host's wait
+                  for the join programs (attrs: n)
+  iter.chunks     one chunk for every slot mid chunked-prefill
+  decode.step     one batched decode step, all attempts (attrs:
                   n_active, slots, occupancy, queue depth, page-pool
                   and shard gauges)
-  decode.draft    engine track: a speculative draft proposal dispatch
-                  (attrs: n_active, proposed)
-  decode.verify   engine track: the k-token verify dispatch (attrs:
-                  n_active, proposed, accepted)
+  step.map_pages  under decode.step: mapping the pages the step writes
+  step.enqueue    under decode.step: building the arguments and
+                  calling the compiled program
+  step.readback   under decode.step: the host's wait for the tokens
+  decode.draft    under decode.step: a speculative draft proposal
+                  dispatch, to its result (attrs: n_active, proposed)
+  decode.verify   under decode.step: the k-token verify dispatch and
+                  read-back (attrs: n_active, proposed, accepted)
+  iter.deliver    delivering the step's tokens to the requests and
+                  their stream callbacks (attrs: tokens)
+  iter.account    the iteration's gauges, metrics and on_iteration
+                  callbacks
   compile         engine track: one jit trace+compile (attrs: cache
                   key, duration, count)
   retrace         engine track instant: a retrace-sentinel violation
@@ -56,6 +90,7 @@ __all__ = [
     "SPAN_CATALOG", "retrace_sentinel", "RetraceSentinel",
     "RetraceError", "session_scope", "start_session", "end_session",
     "load_chrome_trace", "waterfalls", "waterfall_report",
+    "IterationTrace",
 ]
 
 # re-exported so serving code/tests have one import surface
@@ -83,9 +118,29 @@ SPAN_CATALOG = (
     ("first_token", "instant: first delivered token (TTFT)"),
     ("finish", "terminal instant: finish_reason"),
     ("error", "terminal instant: failure cause"),
+    ("iteration", "engine track root: one run_iteration that did "
+                  "work (joins, occupancy, gauges, t0_perf_ns)"),
+    ("iter.harvest", "engine track: cancellation / deadline sweep, "
+                     "poll of disaggregated prefills"),
+    ("iter.admit", "engine track: the admission loop; parent of the "
+                   "round's join spans"),
+    ("iter.tok0", "engine track: resolving the round's first tokens "
+                  "(the wait for the join programs)"),
+    ("iter.chunks", "engine track: one chunk per slot mid "
+                    "chunked-prefill"),
     ("decode.step", "engine track: one batched decode step"),
-    ("decode.draft", "engine track: speculative draft proposal"),
-    ("decode.verify", "engine track: k-token speculative verify"),
+    ("step.map_pages", "under decode.step: mapping the pages the "
+                       "step writes"),
+    ("step.enqueue", "under decode.step: arguments built, program "
+                     "called"),
+    ("step.readback", "under decode.step: the wait for the tokens"),
+    ("decode.draft", "under decode.step: speculative draft proposal"),
+    ("decode.verify", "under decode.step: k-token speculative "
+                      "verify"),
+    ("iter.deliver", "engine track: tokens to requests and stream "
+                     "callbacks"),
+    ("iter.account", "engine track: gauges, metrics, on_iteration "
+                     "callbacks"),
     ("compile", "engine track: one jit trace+compile"),
     ("precompile", "engine track: one startup program readied "
                    "(source: cache deserialize | AOT compile)"),
@@ -150,12 +205,16 @@ def on_requeue(r):
                                attrs={"deferred": True})
 
 
-def on_join_begin(r, slot):
+def on_join_begin(r, slot, parent=None):
+    """The join begins and ends on the engine's thread, so it is
+    forwarded to the profiler; `parent` is the round's `iter.admit`
+    span (the request's root where there is none)."""
     rt = r._trace
     if rt is not None:
         rt.tr.end(rt.queue)          # idempotent if already ended
         rt.join = rt.tr.begin("join", cat="request", trace_id=rt.tid,
-                              parent=rt.root, attrs={"slot": slot})
+                              parent=parent or rt.root,
+                              attrs={"slot": slot}, forward=True)
 
 
 def on_join_attr(r, **attrs):
@@ -272,46 +331,88 @@ def on_finish(r, reason, error=None):
     r._trace = None
 
 
-def on_decode_step(engine, t0, t1, active, scheduler=None):
-    """Engine-track span for one batched decode step, with the page
-    pool / shard gauges as attributes and the co-resident requests'
-    trace ids in ``slots`` — every decode step a request co-resides in
-    is recoverable from the trace."""
-    tr = _trace._SESSION
-    if tr is None:
-        return
-    tids = []
-    for s, r in enumerate(engine.slots):
-        if r is not None and active[s]:
-            tids.append(r.id)
-            rt = r._trace
-            if rt is not None:
-                _begin_decode(rt)
-                rt.steps += 1
-    attrs = {"n_active": len(tids), "slots": tids,
-             "occupancy": engine.occupancy()}
-    if scheduler is not None:
-        attrs["queue_depth"] = scheduler.depth()
-    for k, v in (engine._iteration_gauges() or {}).items():
-        attrs[k] = (round(float(v), 3) if isinstance(v, float)
-                    else list(v) if isinstance(v, (list, tuple))
-                    else v)
-    tr.add_complete("decode.step", t0, t1, cat="engine", attrs=attrs)
+class IterationTrace:
+    """The engine-track spans of ONE `run_iteration`, on the engine's
+    thread: the root `iteration` and whatever is opened under it,
+    properly nested and forwarded to the profiler. The engine keeps it
+    as `engine._iter_trace` while the iteration runs (None with no
+    session), which is what the steppers' call sites test."""
 
+    __slots__ = ("tr", "stack", "step", "held")
 
-def on_spec_step(t0, t1, t2, n_active, proposed, accepted):
-    """Engine-track spans for one speculative iteration's two
-    dispatches: the draft proposal ([t0, t1]) and the k-token verify
-    ([t1, t2]) with the device-side acceptance counts — the waterfall
-    report's speculation-phase breakdown reads these."""
-    tr = _trace._SESSION
-    if tr is None:
-        return
-    tr.add_complete("decode.draft", t0, t1, cat="engine",
-                    attrs={"n_active": n_active, "proposed": proposed})
-    tr.add_complete("decode.verify", t1, t2, cat="engine",
-                    attrs={"n_active": n_active, "proposed": proposed,
-                           "accepted": accepted})
+    def __init__(self, tr):
+        self.tr = tr
+        root = tr.begin("iteration", cat="engine", forward=True)
+        # the offset between the profiler's clock and the tracer's:
+        # this annotation's start is `t0_perf_ns` on the tracer's
+        root.attrs["t0_perf_ns"] = t0 = int(root.t0 * 1e9)
+        root.ann.set_metadata(t0_perf_ns=t0)
+        self.stack = [root]
+        self.step = None          # this iteration's decode.step span
+        self.held = []            # closed spans, recorded at close()
+
+    def begin(self, name, **attrs):
+        sp = self.tr.begin(name, cat="engine", parent=self.stack[-1],
+                           attrs=attrs, forward=True)
+        self.stack.append(sp)
+        return sp
+
+    def unwind(self, sp):
+        """Close whatever an exception left open under `sp` (the engine
+        does it before every attempt of a retried op, so a retry's spans
+        are `sp`'s children and not the failed attempt's)."""
+        while self.stack[-1] is not sp:
+            self.held.append(self.tr.end(self.stack.pop(), False))
+
+    def end(self, sp, **attrs):
+        self.unwind(sp)
+        self.stack.pop()
+        self.held.append(self.tr.end(sp, False, **attrs))
+
+    def begin_step(self):
+        self.step = self.begin("decode.step")
+        return self.step
+
+    def end_step(self, engine, active, scheduler, **attrs):
+        """Close `decode.step` with the co-resident requests' trace ids
+        in ``slots`` — every decode step a request co-resides in is
+        recoverable from the trace."""
+        tids = []
+        for s, r in enumerate(engine.slots):
+            if r is not None and active[s]:
+                tids.append(r.id)
+                rt = r._trace
+                if rt is not None:
+                    _begin_decode(rt)
+                    rt.steps += 1
+        self.end(self.step, n_active=len(tids), slots=tids,
+                 occupancy=engine.occupancy(),
+                 queue_depth=scheduler.depth(), **attrs)
+
+    def close(self, progress, gauges=None, **attrs):
+        """End the iteration: `attrs` (joins, occupancy, queue depth)
+        and the iteration's `gauges`, computed ONCE by the engine, go
+        on the root; the gauges also go on the record of `decode.step`,
+        which has always carried them. An idle spin leaves nothing in
+        the tracer's record, so sums over `iteration` cover work only
+        (its annotations were entered before that could be known: a
+        profiler trace running at the time shows them)."""
+        while len(self.stack) > 1:
+            self.held.append(self.tr.end(self.stack.pop(), False))
+        root = self.stack.pop()
+        if not progress:
+            self.tr.end(root, False)
+            return
+        for k, v in (gauges or {}).items():
+            attrs[k] = (round(float(v), 3) if isinstance(v, float)
+                        else list(v) if isinstance(v, (list, tuple))
+                        else v)
+            if self.step is not None:
+                self.step.attrs[k] = attrs[k]
+        if self.step is not None:
+            attrs["n_active"] = self.step.attrs.get("n_active", 0)
+        self.held.append(self.tr.end(root, False, **attrs))
+        self.tr.commit(self.held)
 
 
 # ----------------------------------------------------------------------

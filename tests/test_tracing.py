@@ -642,3 +642,340 @@ def test_record_event_without_profiler_still_spans():
     assert len(spans) == 1
     assert spans[0].cat == "record_event"
     assert spans[0].t1 is not None
+
+
+# ----------------------------------------------------------------------
+# the program's spans on the profiler's clock (PR 25)
+# ----------------------------------------------------------------------
+
+_ENGINE_CHAIN = ("iteration", "iter.admit", "join", "decode.step",
+                 "step.enqueue", "step.readback", "iter.deliver")
+
+
+def _host_events(trace_dir):
+    """[(name, start_ns, end_ns, stats)] of every host-plane event of
+    the newest .xplane.pb under a jax.profiler log directory."""
+    import glob
+    import os
+
+    from jax.profiler import ProfileData
+
+    paths = sorted(glob.glob(os.path.join(
+        trace_dir, "plugins", "profile", "*", "*.xplane.pb")),
+        key=os.path.getmtime)
+    assert paths, f"no .xplane.pb under {trace_dir}"
+    out = []
+    for plane in ProfileData.from_file(paths[-1]).planes:
+        if not plane.name.startswith("/host:"):
+            continue
+        for line in plane.lines:
+            for ev in line.events:
+                out.append((ev.name, ev.start_ns,
+                            ev.start_ns + ev.duration_ns,
+                            dict(ev.stats)))
+    return out
+
+
+def _profiled(tmp_path, fn):
+    import jax
+
+    d = str(tmp_path / "xplane")
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(d, profiler_options=opts)
+    try:
+        fn()
+    finally:
+        jax.profiler.stop_trace()
+    return _host_events(d)
+
+
+def test_engine_spans_reach_the_profiler_nested_and_linked(tmp_path):
+    """One served request under a session AND a jax.profiler trace: the
+    engine-track spans and the request's join are host-plane events of
+    the same names, nested in program order, each with its span_id;
+    `iteration` carries the tracer clock at its start."""
+    dec, embed, proj, D, V = _small_stack(seed=191)
+    eng = ServingEngine(dec, embed, proj, num_slots=2, max_len=32)
+    sched = Scheduler(max_queue=4)
+    r = Request(np.asarray([0, 3, 5], np.int32),
+                np.random.RandomState(5).randn(4, D).astype("f4"),
+                max_new_tokens=4, eos_id=None)
+    eng.precompile((4, D), prompt_buckets=(4,))      # no compile inside
+
+    def serve():
+        with T.session_scope() as tr:
+            sched.submit(r)
+            eng.serve_until_idle(sched, max_iterations=50)
+            serve.tracer = tr
+
+    events = _profiled(tmp_path, serve)
+    assert r.result(timeout=5).ok
+    tr = serve.tracer
+    by_id = {s.span_id: s for s in tr.spans()}
+    mine = [e for e in events if e[0] in _ENGINE_CHAIN]
+    # the first iteration admitted the request and decoded once
+    first = min((e for e in mine if e[0] == "iteration"),
+                key=lambda e: e[1])
+    inside = sorted((e for e in mine if first[1] <= e[1]
+                     and e[2] <= first[2]), key=lambda e: (e[1], -e[2]))
+    assert [e[0] for e in inside] == list(_ENGINE_CHAIN)
+    ev = {e[0]: e for e in inside}
+    for parent, child in (("iteration", "iter.admit"),
+                          ("iter.admit", "join"),
+                          ("iteration", "decode.step"),
+                          ("decode.step", "step.enqueue"),
+                          ("decode.step", "step.readback"),
+                          ("iteration", "iter.deliver")):
+        assert ev[parent][1] <= ev[child][1] and \
+            ev[child][2] <= ev[parent][2], (parent, child)
+    assert ev["step.enqueue"][2] <= ev["step.readback"][1]
+    assert ev["decode.step"][2] <= ev["iter.deliver"][1]
+    for name, _, _, stats in inside:
+        sp = by_id[stats["span_id"]]          # the SAME span, by id
+        assert sp.name == name
+    assert ev["join"][3]["trace_id"] == r.id
+    assert ev["iteration"][3]["joins"] == 1
+    assert ev["iter.deliver"][3]["tokens"] == 1
+    # the clock pair: the annotation starts where the tracer's span does
+    root = by_id[ev["iteration"][3]["span_id"]]
+    assert ev["iteration"][3]["t0_perf_ns"] == int(root.t0 * 1e9)
+    # lifecycle spans cross threads and are not forwarded
+    assert not [e for e in events if e[0] in ("request", "queue",
+                                              "decode")]
+
+
+def test_iteration_spans_nest_and_idle_spins_are_dropped():
+    """The tracer's own record of an iteration: children inside their
+    parent, their durations summing to no more than it; gauges computed
+    once and shared with decode.step; an idle spin leaves no span."""
+    dec, embed, proj, D, V = _small_stack(seed=201)
+    eng = ServingEngine(dec, embed, proj, num_slots=4, max_len=32,
+                        paged=True, page_size=8)
+    stack = (dec, embed, proj, D, V)
+    calls = []
+    gauges = eng._iteration_gauges
+    eng._iteration_gauges = lambda: calls.append(1) or gauges()
+    with T.session_scope() as tr:
+        _ragged_soak(eng, stack, 6, seed=202)
+        n_work = len([s for s in tr.spans() if s.name == "iteration"])
+        assert len(calls) == n_work           # once an iteration
+        assert eng.run_iteration(Scheduler(max_queue=2)) is False
+        assert eng._iter_trace is None
+    spans = tr.spans()
+    assert len([s for s in spans if s.name == "iteration"]) == n_work
+    assert not tr.open_spans()
+    by_id = {s.span_id: s for s in spans}
+    kids = {}
+    for s in spans:
+        if s.cat == "engine" and s.parent_id is not None:
+            kids.setdefault(s.parent_id, []).append(s)
+    assert kids
+    for pid, children in kids.items():
+        p = by_id[pid]
+        assert p.name in ("iteration", "decode.step", "iter.admit")
+        for c in children:
+            assert p.t0 <= c.t0 and c.t1 <= p.t1, (p.name, c.name)
+        assert sum(c.duration_s for c in children) <= \
+            p.duration_s + 1e-9, p.name
+    joins = [s for s in spans if s.name == "join"]
+    assert joins and all(by_id[j.parent_id].name == "iter.admit"
+                         for j in joins)
+    for it in (s for s in spans if s.name == "iteration"):
+        assert {"joins", "occupancy", "queue_depth", "pages_in_use",
+                "pages_free", "t0_perf_ns"} <= set(it.attrs), it.attrs
+        step = [c for c in kids.get(it.span_id, [])
+                if c.name == "decode.step"]
+        if step:
+            assert step[0].attrs["pages_in_use"] == \
+                it.attrs["pages_in_use"]
+            assert it.attrs["n_active"] == step[0].attrs["n_active"]
+
+
+def test_a_retried_step_nests_under_decode_step():
+    """An attempt that dies inside `step.enqueue` leaves that span open;
+    the retry closes it first, so its own spans are children of
+    `decode.step` and not of the failed attempt's."""
+    dec, embed, proj, D, V = _small_stack(seed=205)
+    eng = ServingEngine(dec, embed, proj, num_slots=2, max_len=32)
+    eng._sleep = lambda s: None
+    sched = Scheduler(max_queue=4)
+    r = Request(np.asarray([0, 3, 5], np.int32),
+                np.random.RandomState(5).randn(4, D).astype("f4"),
+                max_new_tokens=3, eos_id=None)
+    program, failed = eng._program, []
+
+    def flaky(key, build):
+        if key[0] == "step" and not failed:
+            failed.append(key)
+            raise RuntimeError("injected: the enqueue dies once")
+        return program(key, build)
+
+    eng._program = flaky
+    with T.session_scope() as tr:
+        sched.submit(r)
+        eng.serve_until_idle(sched, max_iterations=50)
+    assert failed and r.result(timeout=5).ok
+    assert not tr.open_spans()
+    by_id = {s.span_id: s for s in tr.spans()}
+    enq = [s for s in tr.spans() if s.name == "step.enqueue"]
+    # token 0 comes from the join, two decode steps follow; and the
+    # failed attempt's span is in the record too
+    assert len(enq) == 1 + 2
+    for s in tr.spans():
+        if s.name.startswith("step."):
+            assert by_id[s.parent_id].name == "decode.step", s.name
+
+
+def test_trainer_step_is_annotated_without_a_session(tmp_path):
+    """SpmdTrainer.step: train.step (the step's number on it) over
+    train.next_key, train.shard, train.enqueue — profiler annotations
+    only, no tracer session involved."""
+    import jax
+    import jax.numpy as jnp
+
+    from paddle_tpu.optimizer import functional as fopt
+    from paddle_tpu.parallel import SpmdTrainer, init_mesh
+
+    init_mesh(dp=1, devices=jax.devices("cpu")[:1])
+    net = nn.Linear(8, 3)
+    tr = SpmdTrainer(
+        net, lambda out, y: jnp.mean((out - y) ** 2), fopt.sgd(0.1))
+    x = np.random.RandomState(0).randn(4, 8).astype("f4")
+    y = np.zeros((4, 3), "f4")
+    float(tr.step((x,), y))                              # compile
+    assert T.session() is None
+    events = _profiled(tmp_path, lambda: float(tr.step((x,), y)))
+    ev = {e[0]: e for e in events if e[0].startswith("train.")}
+    assert set(ev) == {"train.step", "train.next_key", "train.shard",
+                       "train.enqueue"}
+    root = ev["train.step"]
+    assert root[3]["step_num"] == 2
+    order = [ev[n] for n in ("train.next_key", "train.shard",
+                             "train.enqueue")]
+    for a, b in zip(order, order[1:]):
+        assert a[2] <= b[1]
+    assert all(root[1] <= e[1] and e[2] <= root[2] for e in order)
+    # the compiled step is named and scoped for the device's side
+    data = tr.shard_batch(x, y)
+    text = tr._step_fn.lower(tr.params, tr.opt_state, tr.buffers,
+                             jax.random.PRNGKey(0), data[:-1],
+                             data[-1]).as_text(debug_info=True)
+    assert "module @jit_train_step" in text
+    assert "train_step/fwd_bwd/" in text
+    assert "train_step/optimizer/" in text
+
+
+def _pallas_call_names():
+    """{file: [name= of every pallas_call]} under paddle_tpu/ops, from
+    the AST; a call without a literal name= yields None."""
+    import ast
+    import glob
+    import os
+
+    root = os.path.join(os.path.dirname(__file__), "..", "paddle_tpu",
+                        "ops")
+    out = {}
+    for path in sorted(glob.glob(os.path.join(root, "*.py"))):
+        for node in ast.walk(ast.parse(open(path).read())):
+            if isinstance(node, ast.Call) and \
+                    getattr(node.func, "attr", None) == "pallas_call":
+                name = [k.value.value for k in node.keywords
+                        if k.arg == "name"
+                        and isinstance(k.value, ast.Constant)]
+                out.setdefault(os.path.basename(path), []).append(
+                    name[0] if name else None)
+    return out
+
+
+def test_every_pallas_kernel_has_a_fixed_name():
+    names = _pallas_call_names()
+    flat = [n for ns in names.values() for n in ns]
+    assert len(flat) >= 14 and None not in flat, names
+    # the two spellings of one kernel (with and without scalar
+    # prefetch) share its name; no two kernels do
+    assert sorted(set(flat)) == sorted([
+        "flash_fwd", "flash_bwd_dq", "flash_bwd_dkv", "flash_decode",
+        "flash_verify", "paged_flash_decode", "paged_flash_verify",
+        "int8_matmul", "lora_gather", "fused_conv_fwd",
+        "fused_conv_bwd"])
+    assert all(not any(c.isdigit() for c in n.replace("int8", ""))
+               for n in flat)                     # no shape in a name
+
+
+@pytest.mark.parametrize("placement", ["single", "sharded"])
+def test_pool_programs_lower_under_their_kind(placement):
+    """Every program a placement builds is jitted from a body named
+    key[0] under jax.named_scope(key[0])."""
+    import jax.numpy as jnp
+
+    dec, embed, proj, D, V = _small_stack(seed=211)
+    if placement == "single":
+        eng = ServingEngine(dec, embed, proj, num_slots=2, max_len=32,
+                            paged=True, page_size=8)
+    else:
+        from paddle_tpu.parallel import init_mesh
+        from paddle_tpu.serving import ShardedServingEngine
+
+        eng = ShardedServingEngine(
+            dec, embed, proj, mesh=init_mesh(dp=2, fsdp=2, tp=2),
+            num_slots=4, max_len=32)
+
+    def body(x):
+        return {"tok": x + 1}
+
+    for key in (("pstep", 2), ("pjoin", 8), ("attach",), ("join", 4)):
+        fn = eng.placement.build(key, body, has_aux=False)
+        assert fn.__name__ == key[0]
+        text = fn.lower(jnp.zeros((4,), jnp.int32)).as_text(
+            debug_info=True)
+        assert f"module @jit_{key[0]}" in text, text[:200]
+        assert f"jit({key[0]})/{key[0]}/add" in text
+
+
+def test_page_iterations_counts_pages_per_iteration():
+    dec, embed, proj, D, V = _small_stack(seed=221)
+    eng = ServingEngine(dec, embed, proj, num_slots=2, max_len=32,
+                        paged=True, page_size=8)
+    sched = Scheduler(max_queue=4)
+    sched.submit(Request(np.asarray([0, 3, 5], np.int32),
+                         np.random.RandomState(5).randn(4, D).astype(
+                             "f4"), max_new_tokens=12, eos_id=None))
+    want = 0
+    for _ in range(6):
+        before = eng.metrics.snapshot().get("paging", {}).get(
+            "page_iterations", 0)
+        eng.run_iteration(sched)
+        pg = eng.metrics.snapshot()["paging"]
+        assert pg["page_iterations"] - before == pg["pages_in_use"]
+        assert pg["pages_total"] == pg["pages_in_use"] + \
+            pg["pages_free"] == eng.num_pages
+        want += pg["pages_in_use"]
+    assert eng.metrics.snapshot()["paging"]["page_iterations"] == want > 0
+    eng.serve_until_idle(sched, max_iterations=50)
+
+
+def test_one_function_builds_profiler_annotations():
+    """`trace.annotation` is the only place in paddle_tpu that
+    constructs a TraceAnnotation / StepTraceAnnotation."""
+    import ast
+    import glob
+    import os
+
+    root = os.path.join(os.path.dirname(__file__), "..", "paddle_tpu")
+    sites = []
+    for path in glob.glob(os.path.join(root, "**", "*.py"),
+                          recursive=True):
+        tree = ast.parse(open(path).read())
+        for fn in ast.walk(tree):
+            if not isinstance(fn, (ast.FunctionDef, ast.Module)):
+                continue
+            for node in ast.iter_child_nodes(fn) if isinstance(
+                    fn, ast.Module) else ast.walk(fn):
+                if isinstance(node, ast.Call) and getattr(
+                        node.func, "attr", getattr(node.func, "id", "")
+                        ) in ("TraceAnnotation", "StepTraceAnnotation"):
+                    sites.append((os.path.relpath(path, root),
+                                  getattr(fn, "name", "<module>")))
+    assert sorted(set(sites)) == [("profiler/trace.py", "annotation")], \
+        sites

@@ -73,6 +73,21 @@ def main(argv=None):
               f"{total_ms:.1f}ms wall)")
     if steps:
         print(f"decode steps: {len(steps)}")
+    # the engine's own phases (children of `iteration`): where an
+    # iteration's host time goes
+    its = [e for e in events if e.get("name") == "iteration"]
+    if its:
+        total = sum(e.get("dur", 0) for e in its) or 1
+        print(f"iterations with work: {len(its)} "
+              f"({total / 1e3 / len(its):.2f}ms mean)")
+        for phase in ("iter.harvest", "iter.admit", "iter.tok0",
+                      "iter.chunks", "step.map_pages", "step.enqueue",
+                      "step.readback", "iter.deliver", "iter.account"):
+            dur = sum(e.get("dur", 0) for e in events
+                      if e.get("name") == phase)
+            if dur:
+                print(f"  {phase:<16}{dur / 1e3 / len(its):9.3f}ms "
+                      f"an iteration ({100 * dur / total:5.1f}%)")
     drafts = [e for e in events if e.get("name") == "decode.draft"]
     verifies = [e for e in events if e.get("name") == "decode.verify"]
     if verifies:
