@@ -1687,14 +1687,41 @@ def paged_gather_kv(pages, scales, table, compute_dtype):
         S, h, mp * psz, d).astype(compute_dtype)
 
 
-def _paged_flash_decode_call(S, h, mp, psz, d, s, has_scale, has_bias,
-                             interpret):
-    """One grid step per (slot*head, logical page): the page table rides
-    scalar prefetch, so each K/V BlockSpec's index map dereferences
-    table[slot, page] to pick the physical page row to DMA — the same
-    static-shape int32 indirection trick as the split-K decode kernel's
-    length prefetch, one compile per pool config. Per-page partial
-    (acc, m, l) merge in XLA with the standard logsumexp combine."""
+#: VMEM the double-buffered K and V blocks of one paged-decode grid step
+#: may take; the default scoped limit of a v5e core is 16 MiB and the
+#: q/out/bias blocks and the merge scratch need a little beside them
+_PAGED_KV_VMEM_BUDGET = 4 * 2**20
+
+
+def _paged_head_block(h, psz, d, page_dtype):
+    """Heads of one page a grid step of `paged_flash_decode` takes: all
+    of them, unless K and V blocks (two buffers each, their last two
+    dims padded to the dtype's tile) would pass the VMEM budget — then
+    the largest divisor of `h` that fits."""
+    import jax.numpy as jnp
+
+    item = jnp.dtype(page_dtype).itemsize
+    sub = 8 * max(1, 4 // item)                 # sublanes of a tile
+    per_head = (-(-psz // sub) * sub) * (-(-d // 128) * 128) * item
+    hb = h
+    while hb > 1 and (h % hb or 4 * hb * per_head > _PAGED_KV_VMEM_BUDGET):
+        hb -= 1
+    return hb
+
+
+def _paged_flash_decode_call(S, h, mp, psz, d, s, hb, has_scale,
+                             has_bias, interpret):
+    """One grid step per (slot, head block, logical page), a step taking
+    `hb` heads of the page at once (all of them at serving shapes: a
+    physical page's heads are contiguous, so one DMA brings them). The
+    page table and the written lengths ride scalar prefetch: the K/V
+    index maps dereference table[slot, page], CLAMPED to the slot's
+    last written page, so the steps past it name the block already
+    resident and cost no copy; `pl.when` skips their arithmetic. The
+    page axis is the reduction: running (m, l, acc) live in VMEM
+    scratch and the normalised [hb, 1, d] is written at the last page.
+    Logits are a multiply and a lane reduction (a (1, d) x (d, psz)
+    product wastes the MXU), float32 throughout."""
     import jax
     import jax.numpy as jnp
 
@@ -1711,89 +1738,98 @@ def _paged_flash_decode_call(S, h, mp, psz, d, s, has_scale, has_bias,
         if has_bias:
             bias_ref = refs[0]
             refs = refs[1:]
-        o_ref, m_ref, l_ref = refs
-        bh = pl.program_id(0)
-        pi = pl.program_id(1)
+        o_ref, m_sc, l_sc, acc_sc = refs
+        pi = pl.program_id(2)
         start = pi * jnp.int32(psz)
-        n_valid = len_ref[bh // jnp.int32(h)]
+        n_valid = len_ref[pl.program_id(0)]
 
+        @pl.when(pi == 0)
+        def _init():
+            m_sc[...] = jnp.full((hb, 1, 1), -1e30, jnp.float32)
+            l_sc[...] = jnp.zeros((hb, 1, 1), jnp.float32)
+            acc_sc[...] = jnp.zeros((hb, 1, d), jnp.float32)
+
+        # a page entirely past the written region adds an exact zero
         @pl.when(start < n_valid)
         def _compute():
-            sf = jnp.float32(s)
-            qb = q_ref[...].astype(jnp.float32) * sf      # (1, d)
-            kb = k_ref[...].astype(jnp.float32)           # (psz, d)
+            qb = q_ref[...].astype(jnp.float32) * jnp.float32(s)
+            kb = k_ref[...].astype(jnp.float32)           # (hb, psz, d)
             vb = v_ref[...].astype(jnp.float32)
             if has_scale:
-                kb = kb * ks_ref[0, 0]                    # dequantize
-                vb = vb * vs_ref[0, 0]                    # in-kernel
-            logits = jnp.dot(qb, kb.T,
-                             preferred_element_type=jnp.float32)
+                kb = kb * ks_ref[...]                     # dequantize
+                vb = vb * vs_ref[...]                     # in-kernel
+            logits = (qb * kb).sum(axis=-1, keepdims=True)  # (hb,psz,1)
             kpos = start + jax.lax.broadcasted_iota(
-                jnp.int32, (1, psz), 1)
+                jnp.int32, (1, psz, 1), 1)
             logits = jnp.where(kpos < n_valid, logits,
                                jnp.float32(-1e30))
             if has_bias:
-                logits = logits + bias_ref[...][:, 0][None, :]
-            m = logits.max(axis=-1, keepdims=True)
-            p = jnp.exp(logits - m)
-            l = p.sum(axis=-1, keepdims=True)
-            o_ref[...] = jnp.dot(p, vb,
-                                 preferred_element_type=jnp.float32)
-            m_ref[...] = m
-            l_ref[...] = l
+                logits = logits + bias_ref[...][None]
+            m_prev = m_sc[...]
+            m_new = jnp.maximum(m_prev,
+                                logits.max(axis=1, keepdims=True))
+            alpha = jnp.exp(m_prev - m_new)
+            p = jnp.exp(logits - m_new)
+            l_sc[...] = alpha * l_sc[...] + p.sum(axis=1, keepdims=True)
+            acc_sc[...] = alpha * acc_sc[...] + (p * vb).sum(
+                axis=1, keepdims=True)
+            m_sc[...] = m_new
 
-        @pl.when(start >= n_valid)
-        def _skip():
-            # page entirely past the written region: exact-zero partial
-            o_ref[...] = jnp.zeros((1, d), jnp.float32)
-            m_ref[...] = jnp.full((1, 1), -1e30, jnp.float32)
-            l_ref[...] = jnp.zeros((1, 1), jnp.float32)
+        @pl.when(pi == mp - 1)
+        def _finish():
+            # a slot of length 0 (inactive, trash-mapped) never
+            # computes: l = 0, and the floor keeps its output finite
+            o_ref[...] = acc_sc[...] / jnp.maximum(l_sc[...],
+                                                   jnp.float32(1e-30))
 
-    def page_ix(bh, pi, tbl, lens):
-        # physical page row out of the prefetched table; head from the
-        # flattened (slot, head) grid axis
-        return (tbl[bh // jnp.int32(h), pi], bh % jnp.int32(h),
-                _z(), _z())
+    def live_page(si, pi, lens):
+        # logical page, clamped to the slot's last written one
+        last = jnp.maximum(lens[si] - jnp.int32(1),
+                           jnp.int32(0)) // jnp.int32(psz)
+        return jnp.minimum(pi, last)
+
+    def page_ix(si, hi, pi, tbl, lens):
+        return (tbl[si, live_page(si, pi, lens)], hi, _z(), _z())
+
+    def q_ix(si, hi, pi, *_):
+        return (si, hi, _z(), _z())
 
     in_specs = [
-        pl.BlockSpec((None, 1, d), lambda bh, pi, *_: (bh, _z(), _z())),
-        pl.BlockSpec((None, None, psz, d), page_ix),
-        pl.BlockSpec((None, None, psz, d), page_ix),
+        pl.BlockSpec((None, hb, 1, d), q_ix),
+        pl.BlockSpec((None, hb, psz, d), page_ix),
+        pl.BlockSpec((None, hb, psz, d), page_ix),
     ]
     if has_scale:
-        in_specs.append(pl.BlockSpec((None, None, 1, 1), page_ix))
-        in_specs.append(pl.BlockSpec((None, None, 1, 1), page_ix))
+        in_specs.append(pl.BlockSpec((None, hb, 1, 1), page_ix))
+        in_specs.append(pl.BlockSpec((None, hb, 1, 1), page_ix))
     if has_bias:
         # bias lives in LOGICAL per-slot coordinates [S, L, 1]: block
-        # by (slot, logical page), no table dereference
+        # by (slot, logical page), no table dereference; the heads of
+        # a step share it
         in_specs.append(pl.BlockSpec(
             (None, psz, 1),
-            lambda bh, pi, *_: (bh // jnp.int32(h), pi, _z())))
-    out_specs = [
-        pl.BlockSpec((None, None, 1, d),
-                     lambda bh, pi, *_: (bh, pi, _z(), _z())),
-        pl.BlockSpec((None, None, 1, 1),
-                     lambda bh, pi, *_: (bh, pi, _z(), _z())),
-        pl.BlockSpec((None, None, 1, 1),
-                     lambda bh, pi, *_: (bh, pi, _z(), _z())),
-    ]
-    out_shape = [
-        jax.ShapeDtypeStruct((S * h, mp, 1, d), jnp.float32),
-        jax.ShapeDtypeStruct((S * h, mp, 1, 1), jnp.float32),
-        jax.ShapeDtypeStruct((S * h, mp, 1, 1), jnp.float32),
-    ]
+            lambda si, hi, pi, tbl, lens: (si, live_page(si, pi, lens),
+                                           _z())))
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2, grid=(S * h, mp),
-        in_specs=in_specs, out_specs=out_specs)
-    return pl.pallas_call(kernel, grid_spec=grid_spec,
-                          out_shape=out_shape, interpret=interpret,
-                          name="paged_flash_decode")
+        num_scalar_prefetch=2, grid=(S, h // hb, mp),
+        in_specs=in_specs,
+        out_specs=pl.BlockSpec((None, hb, 1, d), q_ix),
+        scratch_shapes=[pltpu.VMEM((hb, 1, 1), jnp.float32),
+                        pltpu.VMEM((hb, 1, 1), jnp.float32),
+                        pltpu.VMEM((hb, 1, d), jnp.float32)])
+    return pl.pallas_call(
+        kernel, grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((S, h, 1, d), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        interpret=interpret, name="paged_flash_decode")
 
 
 def paged_flash_decode(q, k_pages, v_pages, k_scale, v_scale, table,
                        length, bias=None, scale=None, interpret=False):
     """Pallas paged decode: one query token per slot against K/V
-    gathered THROUGH the page table — no dense materialization. q
+    gathered THROUGH the page table — no dense materialization, no
+    per-page partials: the merge over pages happens in the kernel. q
     [S, h, 1, d]; pages [N+1, h, psz, d] (+1 = trash row); table
     [S, max_pages] int32 (trash-clipped); length [S] written counts;
     k_scale/v_scale optional [N+1, h, 1, 1] per-page dequant scales;
@@ -1807,21 +1843,18 @@ def paged_flash_decode(q, k_pages, v_pages, k_scale, v_scale, table,
     mp = table.shape[1]
     psz = k_pages.shape[2]
     s = scale if scale is not None else 1.0 / math.sqrt(d)
-    call = _paged_flash_decode_call(S, h, mp, psz, d, s,
-                                    k_scale is not None,
-                                    bias is not None, interpret)
-    args = [q.reshape(S * h, 1, d), k_pages, v_pages]
+    call = _paged_flash_decode_call(
+        S, h, mp, psz, d, s,
+        _paged_head_block(h, psz, d, k_pages.dtype),
+        k_scale is not None, bias is not None, interpret)
+    args = [q, k_pages, v_pages]
     if k_scale is not None:
         args += [k_scale, v_scale]
     if bias is not None:
         args.append(jnp.asarray(bias, jnp.float32)[:, :, None])
-    acc, m, l = call(jnp.asarray(table, jnp.int32),
-                     jnp.asarray(length, jnp.int32), *args)
-    m_star = m.max(axis=1, keepdims=True)
-    alpha = jnp.exp(m - m_star)
-    num = (acc * alpha).sum(axis=1)                # [S*h, 1, d]
-    den = jnp.maximum((l * alpha).sum(axis=1), 1e-30)
-    return (num / den).astype(q.dtype).reshape(S, h, 1, d)
+    out = call(jnp.asarray(table, jnp.int32),
+               jnp.asarray(length, jnp.int32), *args)
+    return out.astype(q.dtype)
 
 
 def paged_decode_attention(q, k_pages, v_pages, k_scale, v_scale, table,
@@ -1841,9 +1874,9 @@ def paged_decode_attention(q, k_pages, v_pages, k_scale, v_scale, table,
         _on_tpu() and q.shape[-1] <= 256 and psz % 8 == 0
         and _flash_usable())
     if use_kernel and not interpret:
-        # dispatch-level tuning knob: the paged grid is (slot*head,
-        # page) — no block-shape freedom — but a device tier can force
-        # the XLA gather path where the scalar-prefetch kernel loses
+        # dispatch-level tuning knob: the kernel reads its one block
+        # shape (heads a step) from the shapes, but a device tier can
+        # force the XLA gather path where the kernel loses
         cfg = _tuned("paged_flash_decode",
                      (q.shape[-1], psz, str(k_pages.dtype)))
         if cfg is not None and not cfg.get("kernel", True):
@@ -1875,10 +1908,10 @@ def _paged_verify_heuristic():
 
 def _paged_flash_verify_call(S, h, mp, psz, d, T, s, has_scale,
                              has_bias, interpret):
-    """The paged split-K verify kernel: `_paged_flash_decode_call`'s
-    grid — one step per (slot*head, logical page), each K/V BlockSpec
-    index map dereferencing the scalar-prefetched table to pick the
-    physical page row to DMA, int8 dequant in-kernel — with
+    """The paged split-K verify kernel: one grid step per (slot*head,
+    logical page), each K/V BlockSpec index map dereferencing the
+    scalar-prefetched table to pick the physical page row to DMA, int8
+    dequant in-kernel — with
     `_flash_verify_call`'s (T, d) query block and causal-within-the-
     block masking: key position j stays visible to query row i only
     while j <= the row's absolute position (n_valid - T + i). Per-page

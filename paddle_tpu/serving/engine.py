@@ -1989,9 +1989,14 @@ class PagedServingEngine(ServingEngine):
             st = self._prefix.stats()
             gauges.update({"trie_nodes": st["nodes"],
                            "trie_pages": st["pages"]})
-        active_toks = sum(int(self._index[s])
-                          for s, r in enumerate(self.slots)
-                          if r is not None)
+        written = [int(self._index[s])
+                   for s, r in enumerate(self.slots) if r is not None]
+        active_toks = sum(written)
+        # the (slot, page) steps of a paged decode call that do work
+        gauges.update({
+            "live_pages": sum(pages_for(n, self.page_size)
+                              for n in written),
+            "table_entries": self.num_slots * self.max_pages})
         if active_toks and self._page_bytes:
             gauges["bytes_per_active_token"] = \
                 self._alloc.pages_in_use * self._page_bytes \

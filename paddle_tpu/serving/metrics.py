@@ -79,6 +79,15 @@ SNAPSHOT_DOCS = {
         "counter", "pages in use, summed over iterations: over any "
                    "interval, its growth / (iterations x pages_total) "
                    "is the mean share of the pool in use"),
+    "paging.table_entries_total": (
+        "gauge", "page-table entries: slots x max pages a slot, the "
+                 "(slot, page) grid steps of one paged decode call"),
+    "paging.live_page_iterations": (
+        "counter", "written pages of the occupied slots, "
+                   "sum of ceil(written / page_size), summed over "
+                   "iterations: its growth / (iterations x "
+                   "table_entries_total) is the share of a paged "
+                   "decode call's grid steps that fetch and compute"),
     "paging.prefix_hits": ("counter",
                            "joins served from the prefix cache"),
     "paging.prefix_misses": ("counter", "joins that ran a real prefill"),
@@ -426,6 +435,8 @@ class ServingMetrics:
         self.pages_in_use = None    # last-iteration gauge
         self.pages_free = None
         self.page_iterations = 0    # sum over iterations of pages_in_use
+        self.live_page_iterations = 0   # ... of the slots' WRITTEN pages
+        self.table_entries_total = None     # slots x max pages a slot
         self.prefix_hits = 0        # joins served from the prefix cache
         self.prefix_misses = 0      # joins that ran a real prefill
         # radix prefix-cache accounting (PR 16): the snapshot grows a
@@ -903,7 +914,8 @@ class ServingMetrics:
     def record_iteration(self, queue_depth, occupancy, pages_in_use=None,
                          pages_free=None, bytes_per_active_token=None,
                          shard_occupancy=None, tenant_slots=None,
-                         trie_nodes=None, trie_pages=None):
+                         trie_nodes=None, trie_pages=None,
+                         live_pages=None, table_entries=None):
         with self._lock:
             self.iterations += 1
             self.queue_depth.add(queue_depth)
@@ -914,6 +926,9 @@ class ServingMetrics:
             if pages_in_use is not None:
                 self.pages_in_use = int(pages_in_use)
                 self.page_iterations += self.pages_in_use
+            if live_pages is not None:
+                self.live_page_iterations += int(live_pages)
+                self.table_entries_total = int(table_entries)
             if pages_free is not None:
                 self.pages_free = int(pages_free)
             if trie_nodes is not None:
@@ -1079,6 +1094,8 @@ class ServingMetrics:
                     "pages_total": self.pages_in_use
                     + (self.pages_free or 0),
                     "page_iterations": self.page_iterations,
+                    "table_entries_total": self.table_entries_total,
+                    "live_page_iterations": self.live_page_iterations,
                     "prefix_hits": self.prefix_hits,
                     "prefix_misses": self.prefix_misses,
                     "prefix_hit_rate": round(

@@ -328,39 +328,61 @@ def test_gather_pages_reproduces_dense_exactly():
     np.testing.assert_array_equal(np.asarray(g), dense)
 
 
-def test_paged_flash_decode_interpret_parity():
+# (S, H, psz, mp, D) and the written length of every slot. 0 is an
+# inactive slot (its table row is all trash, as the pool maps it); the
+# others cover one token, a page boundary, mid-page and all `mp` pages.
+# The wide shape's K and V blocks pass the kernel's VMEM budget, so a
+# grid step takes half the heads and the head-block axis has two steps.
+_FLASH_SHAPES = {
+    "pool": ((5, 2, 16, 4, 8), [0, 1, 16, 33, 64]),
+    "wide": ((2, 8, 256, 3, 256), [300, 768]),
+}
+
+
+@pytest.mark.parametrize("shape", sorted(_FLASH_SHAPES))
+@pytest.mark.parametrize("with_bias", [True, False],
+                         ids=["bias", "nobias"])
+@pytest.mark.parametrize("kv", ["float32", "int8"])
+def test_paged_flash_decode_interpret_parity(kv, with_bias, shape):
     """The scalar-prefetch page-table kernel (interpret mode on CPU)
-    matches the gathered XLA reference, fp32 and int8 pages."""
+    matches the gathered XLA reference over a shuffled table: fp32 and
+    int8 pages (dequantized in-kernel), with and without a key bias."""
     import jax.numpy as jnp
 
-    from paddle_tpu.ops.attention import (decode_attention_reference,
-                                          paged_flash_decode)
+    from paddle_tpu.ops import attention as A
 
+    (S, H, psz, mp, D), lens = _FLASH_SHAPES[shape]
+    hb = A._paged_head_block(H, psz, D, jnp.float32)
+    assert (hb < H) == (shape == "wide") and H % hb == 0
     rs = np.random.RandomState(3)
-    S, H, psz, mp, D, N = 3, 2, 16, 4, 8, 14
-    table = np.zeros((S, mp), np.int32)
-    perm = rs.permutation(N)[:S * mp]
-    table[:] = perm.reshape(S, mp)
+    N = S * mp + 2
+    table = rs.permutation(N)[:S * mp].reshape(S, mp).astype(np.int32)
+    for s, n in enumerate(lens):
+        table[s, PG.pages_for(n, psz):] = N         # unmapped -> trash
     pages = jnp.asarray(rs.randn(N + 1, H, psz, D).astype("f4"))
     tbl = jnp.asarray(table)
     q = jnp.asarray(rs.randn(S, H, 1, D).astype("f4"))
-    length = jnp.asarray([5, 33, 64], jnp.int32)
-    bias = jnp.asarray(rs.randn(S, mp * psz).astype("f4") * 0.1)
-    ref = decode_attention_reference(
-        q, PG.gather_pages(pages, None, tbl, jnp.float32),
-        PG.gather_pages(pages, None, tbl, jnp.float32), length, bias)
-    out = paged_flash_decode(q, pages, pages, None, None, tbl, length,
-                             bias, interpret=True)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
-                               rtol=2e-5, atol=2e-6)
-    # int8 with per-page scales, dequantized in-kernel
-    qp, sp = PG.quantize_chunks(pages, jnp.int8, True)
-    gi = PG.gather_pages(qp, sp, tbl, jnp.float32)
-    ref_i = decode_attention_reference(q, gi, gi, length, bias)
-    out_i = paged_flash_decode(q, qp, qp, sp, sp, tbl, length, bias,
-                               interpret=True)
-    np.testing.assert_allclose(np.asarray(out_i), np.asarray(ref_i),
-                               rtol=2e-5, atol=2e-6)
+    length = jnp.asarray(lens, jnp.int32)
+    bias = None
+    if with_bias:
+        bias = rs.randn(S, mp * psz).astype("f4") * 0.1
+        bias[:, 2:5] = -1e9         # a bucketed prompt's pad hole
+        bias = jnp.asarray(bias)
+    scales = None
+    if kv == "int8":
+        pages, scales = PG.quantize_chunks(pages, jnp.int8, True)
+    dense = PG.gather_pages(pages, scales, tbl, jnp.float32)
+    ref = np.asarray(A.decode_attention_reference(q, dense, dense, length,
+                                                  bias))
+    out = np.asarray(A.paged_flash_decode(q, pages, pages, scales, scales,
+                                          tbl, length, bias,
+                                          interpret=True))
+    assert out.shape == (S, H, 1, D) and np.isfinite(out).all()
+    live = np.asarray(lens) > 0
+    # a slot that holds nothing has no reference (a softmax over no
+    # key); the kernel's answer for it is an exact, finite zero
+    assert not out[~live].any()
+    np.testing.assert_allclose(out[live], ref[live], rtol=2e-5, atol=2e-6)
 
 
 # ----------------------------------------------------------------------

@@ -109,6 +109,33 @@ def test_paged_flash_kernels_compile(one_chip, kv_dtype, T):
     assert mem.temp_size_in_bytes < 2 * 2**30
 
 
+# the benchmark's pool (`bart_large_dec`: 64 slots x 1024 positions,
+# 16 heads of 64, 16-token float32 pages, a pad bias)
+BENCH_POOL = dict(S=64, h=16, L=1024, d=64, psz=16)
+# `temp_size_in_bytes` of this call before the kernel merged over pages
+# itself (PR 26's parent, same compile): the two lane-padded page pools
+# plus the per-page partials
+BENCH_POOL_TEMP_BEFORE = 1107751936
+
+
+def test_paged_flash_decode_compiles_at_benchmark_shapes(one_chip):
+    S, h, L, d, psz = (BENCH_POOL[k] for k in ("S", "h", "L", "d", "psz"))
+    mp = L // psz
+    pages = ((S * mp + 1, h, psz, d), jnp.float32)
+    compiled = _compile(A.paged_flash_decode, one_chip,
+                        ((S, h, 1, d), jnp.float32), pages, pages, None,
+                        None, ((S, mp), jnp.int32), ((S,), jnp.int32),
+                        ((S, L), jnp.float32))
+    calls = [ln for ln in compiled.as_text().splitlines()
+             if "custom-call(" in ln and "tpu_custom_call" in ln]
+    assert len(calls) == 1
+    # one output, the merged [S, h, 1, d]: no `mp`-long partials
+    result = calls[0].split("custom-call(")[0].split("=", 1)[1].strip()
+    assert result.startswith(f"f32[{S},{h},1,{d}]"), result
+    assert compiled.memory_analysis().temp_size_in_bytes <= \
+        BENCH_POOL_TEMP_BEFORE
+
+
 @pytest.mark.parametrize("m", [16, 2048])
 def test_int8_matmul_kernel_compiles(one_chip, m):
     d, n = 512, 2048

@@ -955,6 +955,49 @@ def test_page_iterations_counts_pages_per_iteration():
     eng.serve_until_idle(sched, max_iterations=50)
 
 
+def test_live_page_iterations_counts_the_written_pages():
+    """`paging.live_page_iterations` grows each iteration by the sum
+    over occupied slots of ceil(written / page_size) — the (slot, page)
+    grid steps of a paged decode call that do work — and its growth
+    over iterations x `paging.table_entries_total` is their share; an
+    idle spin adds nothing."""
+    from paddle_tpu.serving.paging import pages_for
+
+    dec, embed, proj, D, V = _small_stack(seed=221)
+    eng = ServingEngine(dec, embed, proj, num_slots=3, max_len=32,
+                        paged=True, page_size=8)
+    sched = Scheduler(max_queue=4)
+    rs = np.random.RandomState(5)
+    for prompt, n_new in (([0, 3, 5], 14), ([0, 2, 4, 6, 7, 9, 3, 5, 8],
+                                            6)):
+        sched.submit(Request(np.asarray(prompt, np.int32),
+                             rs.randn(4, D).astype("f4"),
+                             max_new_tokens=n_new, eos_id=None))
+    want, its = 0, 0
+    while sched.depth() > 0 or eng.occupancy() > 0:
+        before = eng.metrics.snapshot().get("paging", {}).get(
+            "live_page_iterations", 0)
+        eng.run_iteration(sched)
+        its += 1
+        live = sum(pages_for(int(eng._index[s]), 8)
+                   for s, r in enumerate(eng.slots) if r is not None)
+        pg = eng.metrics.snapshot()["paging"]
+        assert pg["live_page_iterations"] - before == live
+        # a slot's written pages are mapped pages
+        assert live <= pg["pages_in_use"]
+        want += live
+    pg = eng.metrics.snapshot()["paging"]
+    assert pg["table_entries_total"] == 3 * eng.max_pages == 12
+    assert pg["live_page_iterations"] == want > its
+    share = want / (its * pg["table_entries_total"])
+    assert 0 < share < 1
+    assert eng.metrics.snapshot()["iterations"] == its
+    for _ in range(3):
+        eng.run_iteration(sched)            # idle: no slot, no queue
+    assert eng.metrics.snapshot()["paging"]["live_page_iterations"] \
+        == want
+
+
 def test_one_function_builds_profiler_annotations():
     """`trace.annotation` is the only place in paddle_tpu that
     constructs a TraceAnnotation / StepTraceAnnotation."""
