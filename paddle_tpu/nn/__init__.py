@@ -16,9 +16,10 @@ from .layer.common import (  # noqa: F401
     Sequential, Sigmoid, Silu, SmoothL1Loss, Softmax, Softplus, Softshrink,
     Softsign, Swish, SyncBatchNorm, Tanh, Tanhshrink, Unfold, Upsample,
     UpsamplingBilinear2D, UpsamplingNearest2D)
-from .layer.moe import MoELayer  # noqa: F401
+from .layer.moe import MoELayer, SparseMoELayer  # noqa: F401
+from .layer.ssm import Mamba2Mixer  # noqa: F401
 from .layer.transformer import (  # noqa: F401
-    MultiHeadAttention, Transformer, TransformerDecoder,
+    GroupedQueryAttention, MultiHeadAttention, Transformer, TransformerDecoder,
     TransformerDecoderLayer, TransformerEncoder, TransformerEncoderLayer)
 from .layer.rnn import (  # noqa: F401
     GRU, GRUCell, LSTM, LSTMCell, RNN, RNNCellBase, SimpleRNN, SimpleRNNCell)

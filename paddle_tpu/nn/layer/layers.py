@@ -10,6 +10,7 @@ train steps and to pjit shardings.
 from __future__ import annotations
 
 import collections
+import contextlib
 from typing import Iterator
 
 import numpy as np
@@ -60,6 +61,61 @@ class Parameter(Tensor):
         self.optimize_attr = {"learning_rate": learning_rate}
         self.regularizer = regularizer
         self.need_clip = need_clip
+
+
+def keep_float32(param):
+    """Mark a parameter that a trainer's `compute_dtype` must not round
+    (a decay rate, a router): `SpmdTrainer` hands it to the forward pass
+    as it is held. Returns the parameter."""
+    param.optimize_attr["keep_float32"] = True
+    return param
+
+
+#: trace-scoped: the forward pass being traced recomputes each block of
+#: a layer stack in the backward pass instead of keeping its activations
+_RECOMPUTE_BLOCKS = [False]
+
+
+@contextlib.contextmanager
+def recompute_blocks(on=True):
+    """Scope a functional forward pass (`SpmdTrainer(remat=True)`) in which
+    every stack that runs its blocks through `run_block` puts each under
+    `jax.checkpoint`: between blocks only the residual stream is kept, and
+    a block's activations live only while its own backward pass runs."""
+    prev = _RECOMPUTE_BLOCKS[0]
+    _RECOMPUTE_BLOCKS[0] = bool(on)
+    try:
+        yield
+    finally:
+        _RECOMPUTE_BLOCKS[0] = prev
+
+
+def run_block(block, x, *args, **kwargs):
+    """`block(x, *args, **kwargs)` for one block of a stack; under
+    `recompute_blocks`, inside `jax.checkpoint`. `x` is the one Tensor the
+    block is differentiated through as an argument; parameters are closed
+    over. What the block would leave behind goes through the checkpoint
+    as a value, so no tracer of the inner trace outlives it: the buffers
+    it writes (an expert layer's counters) are returned and written back
+    outside, and its random ops (dropout) draw from a key split off the
+    step's scoped key outside and scoped anew inside."""
+    if not _RECOMPUTE_BLOCKS[0]:
+        return block(x, *args, **kwargs)
+    import jax
+
+    from ...core import random as _random
+
+    def run(raw, key):
+        with _random.scoped_key(key):
+            out = block(Tensor._wrap(raw), *args, **kwargs)
+        return out._data, {n: b._data for n, b in block.named_buffers()}
+
+    # None outside a scoped region: the block then draws from the eager
+    # chain, whose keys are no tracers
+    out, written = jax.checkpoint(run)(x._data, _random._scoped_next())
+    for n, b in block.named_buffers():
+        b._data = written[n]
+    return Tensor._wrap(out)
 
 
 _name_counters = collections.defaultdict(int)

@@ -134,3 +134,140 @@ class MoELayer(Layer):
             self._last_aux = None
 
         return T.reshape(out, [B, S, D])
+
+
+class _Router(Layer):
+    """`gate.weight` [experts, d_model], kept float32 under a trainer's
+    `compute_dtype`, and `gate.e_score_correction_bias` [experts]: a
+    buffer added to the scores for the CHOICE of experts only."""
+
+    def __init__(self, num_experts, d_model):
+        super().__init__()
+        import numpy as np
+
+        import paddle_tpu.nn.initializer as I
+
+        from ...core.tensor import Tensor
+        from .layers import keep_float32
+
+        self.weight = keep_float32(self.create_parameter(
+            [num_experts, d_model], default_initializer=I.XavierUniform()))
+        self.register_buffer("e_score_correction_bias",
+                             Tensor(np.zeros((num_experts,), np.float32)))
+
+
+class _SharedExpert(Layer):
+    def __init__(self, d_model, d_ff):
+        super().__init__()
+        from .common import Linear
+
+        self.up_proj = Linear(d_model, d_ff, bias_attr=False)
+        self.down_proj = Linear(d_ff, d_model, bias_attr=False)
+
+
+class SparseMoELayer(Layer):
+    """Top-k sigmoid-routed experts plus a shared expert, holding a
+    share of the routed experts and dropping no token.
+
+        s = sigmoid(W_r u)                    over all `num_experts`, float32
+        chosen = top_k(s + correction_bias)   the bias steers the choice only
+        w = scaling * s[chosen] / sum(s[chosen])
+        y = sum_{e chosen and held} w_e W_out[e] relu(W_in[e] u)^2
+            + W_down relu(W_up u)^2           the shared expert, every token
+
+    `experts_held = (first, count)` says which routed experts live here
+    (default: all). The layer routes over all of them and leaves the
+    terms of experts it does not hold out of `y`: another rank adds
+    those. Every token-slot that falls on a held expert is computed,
+    whatever the imbalance (`ops.moe.routed_experts`).
+
+    Counters of the last forward ride non-persistable buffers, as
+    `MoELayer.aux_loss_val` does: `routed_slots_val` (token-slots on held
+    experts), `load_max_val`, `load_mean_val` (of a held expert),
+    `dropped_slots_val` (held slots less the rows the experts' loops
+    counted as they gathered them: 0) and `expert_load_val` [num_experts]
+    (token-slots on EVERY expert, held or not: what a job that balances
+    the correction bias steers by)."""
+
+    COUNTERS = ("routed_slots_val", "load_max_val", "load_mean_val",
+                "dropped_slots_val")
+
+    def __init__(self, d_model, d_ff, num_experts, top_k, shared_d_ff=0,
+                 routed_scaling=1.0, experts_held=None):
+        super().__init__()
+        import numpy as np
+
+        from ...core.tensor import Tensor
+
+        self.num_experts, self.top_k = int(num_experts), int(top_k)
+        first, count = experts_held or (0, self.num_experts)
+        if not 0 <= first <= first + count <= self.num_experts or count < 1:
+            raise ValueError(f"experts_held {experts_held!r} is not a "
+                             f"range of the {num_experts} experts")
+        self.experts_held = (int(first), int(count))
+        self.routed_scaling = float(routed_scaling)
+        self.gate = _Router(self.num_experts, d_model)
+        self.experts = _Experts(int(count), d_model, d_ff)
+        self.shared_experts = (_SharedExpert(d_model, shared_d_ff)
+                               if shared_d_ff else None)
+        for name in self.COUNTERS:
+            self.register_buffer(name, Tensor(np.zeros((), np.float32)),
+                                 persistable=False)
+        self.register_buffer(
+            "expert_load_val",
+            Tensor(np.zeros((self.num_experts,), np.float32)),
+            persistable=False)
+
+    def forward(self, x):
+        """x: [B, S, d_model] -> [B, S, d_model]."""
+        from ...tensor.ops import _op
+
+        first, count = self.experts_held
+        k = self.top_k
+
+        def routed(xr, gate_w, bias, w_in, w_out):
+            import jax
+            import jax.numpy as jnp
+
+            from ...ops import moe
+
+            f32 = jnp.float32
+            tokens = xr.reshape(-1, xr.shape[-1])
+            with jax.named_scope("router"):
+                scores = jax.nn.sigmoid(jnp.dot(
+                    tokens.astype(f32), gate_w.astype(f32).T,
+                    precision="highest"))
+                idx, weights = moe.route_top_k(
+                    scores, bias.astype(f32), k, self.routed_scaling)
+                order, starts, counts = moe.plan_held(idx, first, count)
+            y, visited = moe.routed_experts(tokens, weights, w_in, w_out,
+                                            order, starts, counts)
+            held = counts.sum()
+            stats = jnp.stack([
+                held, counts.max(), held / count,
+                held - visited.sum()]).astype(f32)
+            loads = (idx[..., None] == jnp.arange(
+                self.num_experts, dtype=jnp.int32)).sum((0, 1)).astype(f32)
+            return y.reshape(xr.shape), stats, loads
+
+        y, stats, loads = _op("sparse_moe", routed, x, self.gate.weight,
+                              self.gate.e_score_correction_bias,
+                              self.experts.weight_in,
+                              self.experts.weight_out, n_outputs=3)
+        for i, name in enumerate(self.COUNTERS):
+            self._buffers[name]._data = stats._data[i]
+        self._buffers["expert_load_val"]._data = loads._data
+        if self.shared_experts is not None:
+            h = self.shared_experts.up_proj(x)
+            h = _op("relu2", _relu2, h)
+            y = y + self.shared_experts.down_proj(h)
+        return y
+
+
+def _relu2(t):
+    import jax.numpy as jnp
+
+    from ...ops import moe
+
+    return moe.relu2(t.astype(jnp.float32)).astype(t.dtype)
+

@@ -11,7 +11,7 @@ import collections
 
 from .. import functional as F
 from .common import Dropout, LayerList, LayerNorm, Linear
-from .layers import Layer
+from .layers import Layer, run_block
 
 
 class MultiHeadAttention(Layer):
@@ -385,6 +385,37 @@ StaticCache = MultiHeadAttention.StaticCache
 StaticKVCache = MultiHeadAttention.StaticKVCache
 
 
+class GroupedQueryAttention(Layer):
+    """Causal self-attention with `num_heads` query heads over
+    `num_kv_heads` key-value heads of `head_dim` (each serves num_heads /
+    num_kv_heads query heads), softmax at scale head_dim^-1/2, no bias,
+    no positional term. q_proj [hidden, num_heads x head_dim], k_proj and
+    v_proj [hidden, num_kv_heads x head_dim], o_proj back to hidden."""
+
+    def __init__(self, hidden_size, num_heads, num_kv_heads, head_dim):
+        super().__init__()
+        self.num_heads, self.num_kv_heads = int(num_heads), int(num_kv_heads)
+        self.head_dim = int(head_dim)
+        self.q_proj = Linear(hidden_size, self.num_heads * self.head_dim,
+                             bias_attr=False)
+        self.k_proj = Linear(hidden_size, self.num_kv_heads * self.head_dim,
+                             bias_attr=False)
+        self.v_proj = Linear(hidden_size, self.num_kv_heads * self.head_dim,
+                             bias_attr=False)
+        self.o_proj = Linear(self.num_heads * self.head_dim, hidden_size,
+                             bias_attr=False)
+
+    def forward(self, x):
+        b, s, _ = x.shape
+        q = self.q_proj(x).reshape([b, s, self.num_heads, self.head_dim])
+        k = self.k_proj(x).reshape([b, s, self.num_kv_heads, self.head_dim])
+        v = self.v_proj(x).reshape([b, s, self.num_kv_heads, self.head_dim])
+        out = F.scaled_dot_product_attention(q, k, v, is_causal=True,
+                                             layout="BSHD")
+        return self.o_proj(out.reshape(
+            [b, s, self.num_heads * self.head_dim]))
+
+
 class TransformerEncoderLayer(Layer):
     def __init__(self, d_model, nhead, dim_feedforward, dropout=0.1,
                  activation="relu", attn_dropout=None, act_dropout=None,
@@ -462,8 +493,8 @@ class TransformerEncoder(Layer):
         new_caches = []
         for i, layer in enumerate(self.layers):
             if cache is None:
-                output = layer(output, src_mask,
-                               segment_ids=segment_ids)
+                output = run_block(layer, output, src_mask,
+                                   segment_ids=segment_ids)
             else:
                 output, c = layer(output, src_mask, cache[i])
                 new_caches.append(c)

@@ -850,6 +850,17 @@ def flash_attention_bwd(q, k, v, bias, out, lse, g, is_causal, scale,
                                       lambda bh, ki, *_: (bh, ki, _z())))
         out_shape.append(jax.ShapeDtypeStruct((b * h, sk, 1),
                                               jnp.float32))
+    # this kernel keeps a head's whole q, dO, lse and delta rows in fast
+    # memory (double-buffered; a [sq, 1] float32 row pads to 128 lanes):
+    # past about 4k positions that is more than the compiler's default
+    # scoped limit (16 MiB of the v5e's 128), so ask for what it needs
+    rows = 2 * (2 * sq * d * q.dtype.itemsize + 2 * sq * 128 * 4)
+    extra = {}
+    if rows > 12 * 2 ** 20 and not interpret:
+        from jax.experimental.pallas import tpu as pltpu
+
+        extra["compiler_params"] = pltpu.CompilerParams(
+            vmem_limit_bytes=rows + 8 * 2 ** 20)
     if has_drop:
         dkv_grid = pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1, grid=(b * h, nk),
@@ -857,13 +868,13 @@ def flash_attention_bwd(q, k, v, bias, out, lse, g, is_causal, scale,
         outs = pl.pallas_call(dkv_kernel, grid_spec=dkv_grid,
                               out_shape=out_shape,
                               interpret=interpret,
-                              name="flash_bwd_dkv")(seed, *dkv_args)
+                              name="flash_bwd_dkv", **extra)(seed, *dkv_args)
     else:
         outs = pl.pallas_call(
             dkv_kernel, grid=(b * h, nk), in_specs=dkv_in,
             out_specs=out_specs, out_shape=out_shape,
             interpret=interpret,
-            name="flash_bwd_dkv",
+            name="flash_bwd_dkv", **extra,
         )(*dkv_args)
     if has_bias:
         dk, dv, db_bh = outs
@@ -1187,6 +1198,17 @@ def sdpa_bshd(q, k, v, mask=None, is_causal=False, scale=None,
     cross-segment blocks — it wins earlier."""
     import jax.numpy as jnp
 
+    if q.ndim == 4 and k.shape[2] != q.shape[2]:
+        # grouped-query attention: fewer key-value heads than query heads.
+        # K and V are repeated to the query heads before either path (the
+        # kernels index one K/V per query head); the repeat's transpose
+        # sums dK and dV over each group
+        group = q.shape[2] // k.shape[2]
+        if group * k.shape[2] != q.shape[2]:
+            raise ValueError(f"{q.shape[2]} query heads are not a "
+                             f"multiple of {k.shape[2]} key-value heads")
+        k = jnp.repeat(k, group, axis=2)
+        v = jnp.repeat(v, group, axis=2)
     if q.ndim == 4:
         if segment_ids is None:
             env = "PT_FLASH_MIN_SEQ_BSHD_DROP" if dropout_p else \

@@ -28,6 +28,18 @@ class SpmdTrainer:
 
     loss_fn(outputs, labels) -> scalar, over raw jax arrays.
     Batches are (inputs_tuple, labels) of raw arrays / np arrays.
+
+    `remat=True` recomputes block by block: the forward pass is traced
+    under `nn.layer.layers.recompute_blocks`, in which every stack that
+    runs its blocks through `run_block` (TransformerEncoder,
+    NemotronHForCausalLM) puts each block under `jax.checkpoint`. Between
+    blocks only the residual stream is kept, so the step's peak falls by
+    about the activations of all blocks but one. A model with no such
+    stack is left as it is.
+
+    `compute_dtype` casts every floating parameter for the forward and
+    backward passes, except those a layer marked `keep_float32` (decay
+    rates, a router).
     """
 
     def __init__(self, layer, loss_fn: Callable, optimizer,
@@ -54,6 +66,9 @@ class SpmdTrainer:
 
         params = self.fm.params()
         buffers = self.fm.buffers()
+        self._keep_f32 = frozenset(
+            n for n in params if getattr(
+                self.fm._tensors[n], "optimize_attr", {}).get("keep_float32"))
         self.param_specs = infer_param_specs(params, rules)
         self.param_shardings = {
             n: named_sharding(s, self.mesh)
@@ -101,24 +116,39 @@ class SpmdTrainer:
         return out
 
     # ------------------------------------------------------------------
-    def _forward_loss(self, params, buffers, rng, inputs, labels):
+    def _cast(self, t):
+        return t.astype(self.compute_dtype) if hasattr(
+            t, "dtype") and "float" in str(t.dtype) else t
+
+    def cast_params(self, params):
+        """`params` as the forward pass takes them: cast to
+        `compute_dtype`, except those a layer marked `keep_float32`."""
+        if self.compute_dtype is None:
+            return params
+        return {n: v if n in self._keep_f32 else self._cast(v)
+                for n, v in params.items()}
+
+    def set_buffer(self, name, value):
+        """Replace buffer `name` (a routing bias, a running statistic) by
+        `value`, placed as the step holds its buffers."""
         import jax
 
+        if name not in self.buffers:
+            raise KeyError(name)
+        self.buffers[name] = jax.device_put(value, self._repl)
+
+    def _forward_loss(self, params, buffers, rng, inputs, labels):
+        from ..nn.layer.layers import recompute_blocks
+
+        params = self.cast_params(params)
         if self.compute_dtype is not None:
-            cast = lambda t: t.astype(self.compute_dtype) if hasattr(  # noqa
-                t, "dtype") and "float" in str(t.dtype) else t
-            params = {n: cast(v) for n, v in params.items()}
             # float INPUTS too (conv images etc.): mixed f32xbf16 operands
             # are an error for lax.conv and silently promote elsewhere
-            inputs = tuple(cast(x) for x in inputs)
+            inputs = tuple(self._cast(x) for x in inputs)
 
-        apply = self.fm.apply
-        if self.remat:
-            raw = lambda p, b, r, *xs: apply(p, b, r, *xs, training=True)  # noqa
-            out, new_buf = jax.checkpoint(raw)(params, buffers, rng, *inputs)
-        else:
-            out, new_buf = apply(params, buffers, rng, *inputs,
-                                 training=True)
+        with recompute_blocks(self.remat):
+            out, new_buf = self.fm.apply(params, buffers, rng, *inputs,
+                                         training=True)
         loss = self.loss_fn(out, labels)
         if hasattr(loss, "_data"):  # paddle Tensor from a paddle loss fn
             loss = loss._data
@@ -375,10 +405,7 @@ class SpmdTrainer:
         if self._eval_fn is None:
             def eval_step(params, buffers, inputs):
                 with jax.named_scope("eval_step"):
-                    if self.compute_dtype is not None:
-                        cast = lambda t: t.astype(self.compute_dtype) if hasattr(  # noqa
-                            t, "dtype") and "float" in str(t.dtype) else t
-                        params = {n: cast(v) for n, v in params.items()}
+                    params = self.cast_params(params)
                     out, _ = self.fm.apply(params, buffers, None,
                                            *inputs, training=False)
                 return out

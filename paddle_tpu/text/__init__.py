@@ -38,5 +38,6 @@ from .datasets import (Conll05st, Imdb, Imikolov,  # noqa: F401,E402
                        Movielens, MovieReviews, UCIHousing, WMT14, WMT16)
 from . import models  # noqa: F401,E402
 from .models import (ErnieConfig, ErnieForPretraining,  # noqa: F401,E402
-                     ErnieForSequenceClassification, ErnieModel, ernie_base,
+                     ErnieForSequenceClassification, ErnieModel,
+                     NemotronHConfig, NemotronHForCausalLM, ernie_base,
                      ernie_tiny)

@@ -170,3 +170,137 @@ def ernie_base(**kw):
 
 def ernie_tiny(**kw):
     return ErnieModel(ErnieConfig.tiny(**kw))
+
+
+# --------------------------------------------------------------------------
+# NemotronH: a hybrid of Mamba-2 mixers, sparse experts and grouped-query
+# attention (`model_type` "nemotron_h"). One mixer a layer under a pre-norm
+# residual; the kind of each layer is a character of
+# `hybrid_override_pattern`: M Mamba-2, E experts, * attention.
+# --------------------------------------------------------------------------
+
+class NemotronHConfig:
+    """Keys as the source's `config.json` names them. `experts_held` =
+    (first, count) is the one key of this repo's own: the routed experts
+    this rank holds (default: all `n_routed_experts`)."""
+
+    def __init__(self, vocab_size=131072, hidden_size=2688,
+                 hybrid_override_pattern="MEMEM*EME",
+                 num_attention_heads=32, num_key_value_heads=2,
+                 head_dim=128, mamba_num_heads=64, mamba_head_dim=64,
+                 n_groups=8, ssm_state_size=128, conv_kernel=4,
+                 chunk_size=128, time_step_min=0.001, time_step_max=0.1,
+                 time_step_floor=1e-4, n_routed_experts=128,
+                 num_experts_per_tok=6, moe_intermediate_size=1856,
+                 moe_shared_expert_intermediate_size=3712,
+                 routed_scaling_factor=2.5, norm_topk_prob=True,
+                 mlp_hidden_act="relu2", layer_norm_epsilon=1e-5,
+                 experts_held=None, **unused):
+        bad = set(hybrid_override_pattern) - set("ME*")
+        if bad:
+            raise ValueError(f"hybrid_override_pattern holds {sorted(bad)}: "
+                             f"only M, E and * layers are built")
+        if mlp_hidden_act != "relu2" or not norm_topk_prob:
+            raise ValueError(
+                f"mlp_hidden_act {mlp_hidden_act!r}, norm_topk_prob "
+                f"{norm_topk_prob!r}: the experts are built with relu(h)^2 "
+                f"and normalised top-k weights only")
+        self.vocab_size = vocab_size
+        self.hidden_size = hidden_size
+        self.hybrid_override_pattern = hybrid_override_pattern
+        self.num_attention_heads = num_attention_heads
+        self.num_key_value_heads = num_key_value_heads
+        self.head_dim = head_dim
+        self.mamba_num_heads = mamba_num_heads
+        self.mamba_head_dim = mamba_head_dim
+        self.n_groups = n_groups
+        self.ssm_state_size = ssm_state_size
+        self.conv_kernel = conv_kernel
+        self.chunk_size = chunk_size
+        self.time_step_min = time_step_min
+        self.time_step_max = time_step_max
+        self.time_step_floor = time_step_floor
+        self.n_routed_experts = n_routed_experts
+        self.num_experts_per_tok = num_experts_per_tok
+        self.moe_intermediate_size = moe_intermediate_size
+        self.moe_shared_expert_intermediate_size = \
+            moe_shared_expert_intermediate_size
+        self.routed_scaling_factor = routed_scaling_factor
+        self.norm_topk_prob = norm_topk_prob
+        self.mlp_hidden_act = mlp_hidden_act
+        self.layer_norm_epsilon = layer_norm_epsilon
+        self.experts_held = tuple(experts_held) if experts_held else (
+            0, n_routed_experts)
+
+    @classmethod
+    def tiny(cls, **kw):
+        d = dict(vocab_size=96, hidden_size=32,
+                 hybrid_override_pattern="ME*E", num_attention_heads=4,
+                 num_key_value_heads=2, head_dim=8, mamba_num_heads=4,
+                 mamba_head_dim=8, n_groups=2, ssm_state_size=8,
+                 chunk_size=8, n_routed_experts=8, num_experts_per_tok=3,
+                 moe_intermediate_size=16,
+                 moe_shared_expert_intermediate_size=24)
+        d.update(kw)
+        return cls(**d)
+
+
+class NemotronHBlock(nn.Layer):
+    """x + mixer(RMSNorm(x)); `kind` is the layer's pattern character."""
+
+    SCOPES = {"M": "mamba", "E": "moe", "*": "attn"}
+
+    def __init__(self, cfg: NemotronHConfig, kind):
+        super().__init__()
+        self.kind = kind
+        self.norm = nn.RMSNorm(cfg.hidden_size, cfg.layer_norm_epsilon)
+        if kind == "M":
+            self.mixer = nn.Mamba2Mixer(
+                cfg.hidden_size, cfg.mamba_num_heads, cfg.mamba_head_dim,
+                cfg.n_groups, cfg.ssm_state_size, cfg.conv_kernel,
+                cfg.chunk_size, cfg.layer_norm_epsilon, cfg.time_step_min,
+                cfg.time_step_max, cfg.time_step_floor)
+        elif kind == "E":
+            self.mixer = nn.SparseMoELayer(
+                cfg.hidden_size, cfg.moe_intermediate_size,
+                cfg.n_routed_experts, cfg.num_experts_per_tok,
+                shared_d_ff=cfg.moe_shared_expert_intermediate_size,
+                routed_scaling=cfg.routed_scaling_factor,
+                experts_held=cfg.experts_held)
+        else:
+            self.mixer = nn.GroupedQueryAttention(
+                cfg.hidden_size, cfg.num_attention_heads,
+                cfg.num_key_value_heads, cfg.head_dim)
+
+    def forward(self, x):
+        import jax
+
+        # the scope names the layer's kind on every device operation
+        with jax.named_scope(self.SCOPES[self.kind]):
+            return x + self.mixer(self.norm(x))
+
+
+class NemotronHForCausalLM(nn.Layer):
+    """ids [b, s] -> logits [b, s, vocab]. Embedding, the pattern's
+    blocks, a final RMSNorm and an untied head; no positional term
+    anywhere (the state-space layers carry order). Each block goes through
+    `run_block`, so `SpmdTrainer(remat=True)` recomputes block by block."""
+
+    def __init__(self, cfg: NemotronHConfig):
+        super().__init__()
+        self.cfg = cfg
+        self.embeddings = nn.Embedding(cfg.vocab_size, cfg.hidden_size)
+        self.layers = nn.LayerList([
+            NemotronHBlock(cfg, kind)
+            for kind in cfg.hybrid_override_pattern])
+        self.norm_f = nn.RMSNorm(cfg.hidden_size, cfg.layer_norm_epsilon)
+        self.lm_head = nn.Linear(cfg.hidden_size, cfg.vocab_size,
+                                 bias_attr=False)
+
+    def forward(self, input_ids):
+        from ..nn.layer.layers import run_block
+
+        x = self.embeddings(input_ids)
+        for block in self.layers:
+            x = run_block(block, x)
+        return self.lm_head(self.norm_f(x))

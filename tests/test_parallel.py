@@ -212,6 +212,50 @@ class TestSpmdTrainer:
         y = np.zeros((8,), "int64")
         assert np.isfinite(float(tr.step((x,), y)))
 
+    def test_remat_blocks_with_dropout(self, monkeypatch):
+        """Block-wise recomputation over an encoder whose blocks draw
+        dropout masks: each block's key goes into its checkpoint as a
+        value, so no tracer of a finished block reaches the next one, and
+        the recomputed masks are the forward's. The same step with
+        `jax.checkpoint` taken out (same keys, nothing recomputed) gives
+        the same losses and the same parameters."""
+        import jax
+
+        from paddle_tpu.text import ErnieConfig, \
+            ErnieForSequenceClassification
+
+        rs = np.random.RandomState(0)
+        ids = rs.randint(1, 64, (4, 16)).astype("int64")
+        y = rs.randint(0, 2, (4,)).astype("int64")
+        runs = []
+        for recompute in (True, False):
+            if not recompute:
+                monkeypatch.setattr(jax, "checkpoint", lambda f: f)
+            paddle.seed(5)
+            init_mesh(dp=1, devices=jax.devices()[:1])
+            net = ErnieForSequenceClassification(ErnieConfig.tiny(
+                vocab_size=64, hidden_size=32, num_layers=3,
+                max_position=32, intermediate_size=64, hidden_dropout=0.2,
+                attn_dropout=0.2))
+            tr = SpmdTrainer(net, ce_loss, fopt.sgd(0.1), remat=True)
+            losses = [float(tr.step((ids,), y, rng=jax.random.PRNGKey(7)))
+                      for _ in range(3)]
+            assert np.isfinite(losses).all()
+            runs.append((losses, {k: np.asarray(v)
+                                  for k, v in tr.params.items()}))
+        np.testing.assert_allclose(runs[0][0], runs[1][0], rtol=1e-5)
+        for k, v in runs[0][1].items():
+            np.testing.assert_allclose(v, runs[1][1][k], rtol=2e-4,
+                                       atol=1e-6)
+        # and the masks are drawn: without dropout the losses differ
+        paddle.seed(5)
+        net = ErnieForSequenceClassification(ErnieConfig.tiny(
+            vocab_size=64, hidden_size=32, num_layers=3, max_position=32,
+            intermediate_size=64, hidden_dropout=0.0, attn_dropout=0.0))
+        tr = SpmdTrainer(net, ce_loss, fopt.sgd(0.1), remat=True)
+        assert abs(float(tr.step((ids,), y, rng=jax.random.PRNGKey(7)))
+                   - runs[0][0][0]) > 1e-4
+
     def test_sync_to_layer(self):
         import jax
 

@@ -136,6 +136,118 @@ def test_paged_flash_decode_compiles_at_benchmark_shapes(one_chip):
         BENCH_POOL_TEMP_BEFORE
 
 
+# the training cell `nemotron3_nano_ep16.pretrain_b2_s8192`: 2 sequences
+# of 8,192, bfloat16; attention 32 query heads over 2 key-value heads of
+# 128; the scan 64 heads of 64 in 8 groups, state 128, chunk 128; the
+# experts 8 held, hidden 2688, width 1856, 6 slots a token
+NEMO = dict(b=2, s=8192, hq=32, hkv=2, d=128, h=64, p=64, g=8, n=128,
+            chunk=128, held=8, hid=2688, ff=1856, k=6)
+
+
+def test_grouped_query_flash_compiles_at_the_training_cell_shapes(
+        one_chip, monkeypatch):
+    """s = 8192 through the flash kernels forward and backward, K and V
+    of 2 heads repeated to the 32 query heads by the dispatcher; the
+    dK/dV kernel there needs more scoped fast memory than the default."""
+    monkeypatch.setattr(A, "_on_tpu", lambda: True)
+    b, s, hq, hkv, d = (NEMO[k] for k in ("b", "s", "hq", "hkv", "d"))
+
+    def loss(q, k, v):
+        out = A.sdpa_bshd(q, k, v, is_causal=True)
+        return out.astype(jnp.float32).sum()
+
+    compiled = _compile(jax.value_and_grad(loss, (0, 1, 2)), one_chip,
+                        ((b, s, hq, d), jnp.bfloat16),
+                        ((b, s, hkv, d), jnp.bfloat16),
+                        ((b, s, hkv, d), jnp.bfloat16))
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") >= 3
+    assert f"bf16[{b},{s},{s}]" not in text and f"[{s},{s}]" not in text
+
+
+def _compile_xla(fn, one_chip, *shapes):
+    args = [jax.ShapeDtypeStruct(s[0], s[1], sharding=one_chip)
+            for s in shapes]
+    return jax.jit(fn).lower(*args).compile()
+
+
+def test_chunked_scan_compiles_at_the_training_cell_shapes(one_chip):
+    from paddle_tpu.ops import ssm
+
+    b, s, h, p, g, n = (NEMO[k] for k in ("b", "s", "h", "p", "g", "n"))
+
+    def loss(x, dt, a, bm, cm, d):
+        return ssm.ssd_scan(x, dt, a, bm, cm, d,
+                            chunk=NEMO["chunk"]).astype(jnp.float32).sum()
+
+    compiled = _compile_xla(
+        jax.value_and_grad(loss, range(6)), one_chip,
+        ((b, s, h, p), jnp.bfloat16), ((b, s, h), jnp.float32),
+        ((h,), jnp.float32), ((b, s, g, n), jnp.bfloat16),
+        ((b, s, g, n), jnp.bfloat16), ((h,), jnp.float32))
+    # one group of heads at a time: far under a state per position
+    # (b s h p n float32 = 34 GB) and under all groups' decay matrices
+    assert compiled.memory_analysis().temp_size_in_bytes < 1.5 * 2**30
+
+
+def test_routed_experts_compile_at_the_training_cell_shapes(one_chip):
+    from paddle_tpu.ops import moe
+
+    t = NEMO["b"] * NEMO["s"]
+    held, hid, ff, k = (NEMO[x] for x in ("held", "hid", "ff", "k"))
+
+    def loss(x, w, w_in, w_out, order, starts, counts):
+        return moe.routed_experts(x, w, w_in, w_out, order, starts,
+                                  counts)[0].astype(jnp.float32).sum()
+
+    compiled = _compile_xla(
+        jax.value_and_grad(loss, range(4)), one_chip,
+        ((t, hid), jnp.bfloat16), ((t, k), jnp.float32),
+        ((held, hid, ff), jnp.bfloat16), ((held, ff, hid), jnp.bfloat16),
+        ((t * k,), jnp.int32), ((held,), jnp.int32), ((held,), jnp.int32))
+    # no buffer sized for a load: the worst case (every slot on a held
+    # expert) would gather t k rows of hid, 528 MB in bfloat16
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.5 * 2**30
+
+
+def test_block_recomputation_lowers_the_step_scratch(one_chip):
+    """`SpmdTrainer(remat=True)` recomputes block by block: on a small
+    stack whose activations outweigh its parameters (eight encoder layers
+    of width 64 over 16 x 256 tokens) the compiled step's scratch falls by
+    more than half. (Compiled for the chip: the CPU compiler drops the
+    barriers that keep a recomputation apart.)"""
+    import paddle_tpu as paddle
+    from paddle_tpu.optimizer import functional as fopt
+    from paddle_tpu.parallel import SpmdTrainer, init_mesh
+    from paddle_tpu.text import ErnieConfig, ErnieForSequenceClassification
+
+    def ce(logits, labels):
+        lp = jax.nn.log_softmax(logits.astype(jnp.float32), -1)
+        return -jnp.take_along_axis(lp, labels[:, None], -1).mean()
+
+    def sds(x):
+        return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip)
+
+    temp = {}
+    for remat in (False, True):
+        paddle.seed(5)
+        net = ErnieForSequenceClassification(ErnieConfig.tiny(
+            vocab_size=64, hidden_size=64, num_layers=8, max_position=256,
+            intermediate_size=128, hidden_dropout=0.0, attn_dropout=0.0))
+        tr = SpmdTrainer(net, ce, fopt.sgd(0.1), remat=remat,
+                         mesh=init_mesh(dp=1, devices=jax.devices()[:1]))
+        tr._build_step()
+        state = jax.tree_util.tree_map(
+            sds, (tr.params, tr.opt_state, tr.buffers,
+                  jax.random.PRNGKey(0)))
+        ids = jax.ShapeDtypeStruct((16, 256), jnp.int64, sharding=one_chip)
+        labels = jax.ShapeDtypeStruct((16,), jnp.int64, sharding=one_chip)
+        temp[remat] = jax.jit(tr._raw_step).lower(
+            *state, (ids,), labels).compile().memory_analysis() \
+            .temp_size_in_bytes
+    assert temp[True] < 0.5 * temp[False], temp
+
+
 @pytest.mark.parametrize("m", [16, 2048])
 def test_int8_matmul_kernel_compiles(one_chip, m):
     d, n = 512, 2048
