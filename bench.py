@@ -1215,7 +1215,7 @@ def _expected_paged_pool_bytes(dec, *, num_slots, max_len, mem_len,
         h, dh = layer.self_attn.num_heads, layer.self_attn.head_dim
         total += 2 * (num_pages + 1) * h * page_size * dh * st_item
         if quantized:
-            total += 2 * (num_pages + 1) * h * 4  # [P+1, H, 1, 1] f32
+            total += 2 * (num_pages + 1) * h * 4  # [P+1, 1, H] f32
         hc, dc = layer.cross_attn.num_heads, layer.cross_attn.head_dim
         total += 2 * S * hc * M * dc * itemsize
     return total

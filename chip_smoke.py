@@ -416,6 +416,7 @@ def phase_kernels(size, seed, rehearse):
 
     from paddle_tpu.ops import attention as A
     from paddle_tpu.ops import quant as Q
+    from paddle_tpu.serving import paging as PG
 
     interp = bool(rehearse)
     rs = np.random.RandomState(seed)
@@ -508,17 +509,12 @@ def phase_kernels(size, seed, rehearse):
     table = jnp.asarray(rs.permutation(n_pages).reshape(b, mp), jnp.int32)
     for kv_dt, atol in (("float32", 1e-2), ("bfloat16", 2e-2),
                         ("int8", 2e-2)):
-        kp, vp = rnd(n_pages + 1, h, psz, d), rnd(n_pages + 1, h, psz, d)
-        ks = vs = None
-        if kv_dt == "int8":
-            ks = jnp.max(jnp.abs(kp), axis=(2, 3), keepdims=True) / 127.0
-            vs = jnp.max(jnp.abs(vp), axis=(2, 3), keepdims=True) / 127.0
-            kp = jnp.round(kp / ks).astype(jnp.int8)
-            vp = jnp.round(vp / vs).astype(jnp.int8)
-        else:
-            kp, vp = kp.astype(kv_dt), vp.astype(kv_dt)
-        kd = A.paged_gather_kv(kp, ks, table, jnp.float32)
-        vd = A.paged_gather_kv(vp, vs, table, jnp.float32)
+        kp, ks = PG.quantize_chunks(rnd(n_pages + 1, psz, h * d), kv_dt,
+                                    kv_dt == "int8", h)
+        vp, vs = PG.quantize_chunks(rnd(n_pages + 1, psz, h * d), kv_dt,
+                                    kv_dt == "int8", h)
+        kd = A.paged_gather_kv(kp, ks, table, h, jnp.float32)
+        vd = A.paged_gather_kv(vp, vs, table, h, jnp.float32)
         out = jax.jit(lambda *a: A.paged_flash_decode(
             *a, interpret=interp))(q1, kp, vp, ks, vs, table, lengths,
                                    kbias)

@@ -236,12 +236,13 @@ class MultiHeadAttention(Layer):
     def gen_paged_cache(self, num_pages, page_size, num_slots,
                         max_pages, dtype, kv_dtype=None,
                         page_sharding=None):
-        """Per-layer paged pool: zeroed [num_pages + 1, H, page_size,
-        D] K/V page arrays (the +1 row is the trash page inactive
-        slots' masked writes land on), per-page scales when kv_dtype
-        is int8, an unmapped (trash-clipped) table and zero write
-        indices. The serving engine owns the host-side PageAllocator /
-        page table; this just shapes the device state.
+        """Per-layer paged pool: zeroed [num_pages + 1, page_size,
+        H * D] K/V page arrays (token rows, the heads side by side;
+        the +1 row is the trash page inactive slots' masked writes land
+        on), [num_pages + 1, 1, H] per-(page, head) scales when
+        kv_dtype is int8, an unmapped (trash-clipped) table and zero
+        write indices. The serving engine owns the host-side
+        PageAllocator / page table; this just shapes the device state.
         `page_sharding`: optional NamedSharding laying the page axis
         out across the mesh (the sharded engine's data-parallel page
         pool); page reads/writes stay pure selection, so placement
@@ -251,9 +252,9 @@ class MultiHeadAttention(Layer):
         from ...serving import paging as PG
 
         storage, quantized = PG.resolve_kv_dtype(kv_dtype, dtype)
-        buf = jnp.zeros((int(num_pages) + 1, self.num_heads,
-                         int(page_size), self.head_dim), storage)
-        sc = jnp.zeros((int(num_pages) + 1, self.num_heads, 1, 1),
+        buf = jnp.zeros((int(num_pages) + 1, int(page_size),
+                         self.num_heads * self.head_dim), storage)
+        sc = jnp.zeros((int(num_pages) + 1, 1, self.num_heads),
                        jnp.float32) if quantized else None
         if page_sharding is not None:
             import jax

@@ -1692,63 +1692,92 @@ def verify_attention(q, k, v, length, bias=None, scale=None,
 # paged decode attention: one query token against a paged KV cache
 # --------------------------------------------------------------------------
 
-def paged_gather_kv(pages, scales, table, compute_dtype):
-    """Dense [S, H, L, D] logical view of a paged cache ([N+1, H, psz,
-    D] pages indexed by a [S, max_pages] int32 table), dequantized via
-    the per-(page, head) scales when present. The XLA fallback read for
-    `paged_decode_attention`; garbage gathered through trash-clipped
-    table entries is hidden by the written-length mask downstream."""
+def paged_gather_kv(pages, scales, table, num_heads, compute_dtype):
+    """Dense [S, H, L, D] logical view of a paged cache ([N+1, psz,
+    H * D] pages — token rows, the heads side by side on the minor
+    axis — indexed by a [S, max_pages] int32 table), dequantized via
+    the per-(page, head) [N+1, 1, H] scales when present. The XLA
+    fallback read for `paged_decode_attention`; garbage gathered through
+    trash-clipped table entries is hidden by the written-length mask
+    downstream."""
     import jax.numpy as jnp
 
     S, mp = table.shape
-    _, h, psz, d = pages.shape
-    g = pages[table]                                # [S, mp, h, psz, d]
+    _, psz, hd = pages.shape
+    h = int(num_heads)
+    g = pages[table].reshape(S, mp, psz, h, hd // h)
     if scales is not None:
-        g = g.astype(jnp.float32) * scales[table]
-    return jnp.transpose(g, (0, 2, 1, 3, 4)).reshape(
-        S, h, mp * psz, d).astype(compute_dtype)
+        g = g.astype(jnp.float32) * scales[table][..., None]
+    return jnp.transpose(g, (0, 3, 1, 2, 4)).reshape(
+        S, h, mp * psz, hd // h).astype(compute_dtype)
 
 
-#: VMEM the double-buffered K and V blocks of one paged-decode grid step
-#: may take; the default scoped limit of a v5e core is 16 MiB and the
-#: q/out/bias blocks and the merge scratch need a little beside them
-_PAGED_KV_VMEM_BUDGET = 4 * 2**20
+#: float32 bytes of one page ([psz, H * D]) the paged kernels take, and
+#: of the [T * psz, H * D] block the T query rows make of it: a grid
+#: step holds the K and V pages (two buffers each) and a handful of
+#: block-sized float32 temporaries in VMEM, 16 MiB scoped on a v5e core
+_PAGED_PAGE_BYTES = 2**20
+_PAGED_BLOCK_BYTES = 2**22
 
 
-def _paged_head_block(h, psz, d, page_dtype):
-    """Heads of one page a grid step of `paged_flash_decode` takes: all
-    of them, unless K and V blocks (two buffers each, their last two
-    dims padded to the dtype's tile) would pass the VMEM budget — then
-    the largest divisor of `h` that fits."""
+def _paged_kernel_fits(psz, hd, T=1):
+    """The pools the paged kernels take, decided here from the shapes
+    alone: rows that tile (`psz` a sublane multiple, `hd` = heads x head
+    size a lane multiple), a page and a block of `T` query rows against
+    it that the step's working set holds in VMEM. Every other pool takes
+    the XLA gather composition."""
+    page = 4 * psz * hd
+    return (psz % 8 == 0 and hd % 128 == 0 and page <= _PAGED_PAGE_BYTES
+            and T * page <= _PAGED_BLOCK_BYTES)
+
+
+def _dot_indicator(x, ind):
+    """float32 x [n, k] times a 0/1 matrix `ind` [k, m] (bfloat16), to
+    float32 rounding: x goes in as three bfloat16 pieces that sum to it
+    exactly, so every product is exact and the MXU's float32
+    accumulation is the only rounding. One product at default
+    precision would round x to bfloat16 (8 bits); asking the MXU for
+    full precision splits `ind` as well and takes twice the passes."""
     import jax.numpy as jnp
 
-    item = jnp.dtype(page_dtype).itemsize
-    sub = 8 * max(1, 4 // item)                 # sublanes of a tile
-    per_head = (-(-psz // sub) * sub) * (-(-d // 128) * 128) * item
-    hb = h
-    while hb > 1 and (h % hb or 4 * hb * per_head > _PAGED_KV_VMEM_BUDGET):
-        hb -= 1
-    return hb
+    n = x.shape[0]
+    hi = x.astype(jnp.bfloat16)
+    rest = x - hi.astype(jnp.float32)
+    mid = rest.astype(jnp.bfloat16)
+    lo = (rest - mid.astype(jnp.float32)).astype(jnp.bfloat16)
+    y = jnp.dot(jnp.concatenate([hi, mid, lo], axis=0), ind,
+                preferred_element_type=jnp.float32)
+    return y[:n] + y[n:2 * n] + y[2 * n:]
 
 
-def _paged_flash_decode_call(S, h, mp, psz, d, s, hb, has_scale,
-                             has_bias, interpret):
-    """One grid step per (slot, head block, logical page), a step taking
-    `hb` heads of the page at once (all of them at serving shapes: a
-    physical page's heads are contiguous, so one DMA brings them). The
-    page table and the written lengths ride scalar prefetch: the K/V
-    index maps dereference table[slot, page], CLAMPED to the slot's
-    last written page, so the steps past it name the block already
-    resident and cost no copy; `pl.when` skips their arithmetic. The
-    page axis is the reduction: running (m, l, acc) live in VMEM
-    scratch and the normalised [hb, 1, d] is written at the last page.
-    Logits are a multiply and a lane reduction (a (1, d) x (d, psz)
-    product wastes the MXU), float32 throughout."""
+def _paged_flash_call(S, h, mp, psz, d, T, s, has_scale, has_bias,
+                      interpret):
+    """Both paged kernels: one grid step per (slot, logical page), a
+    step taking the whole page — `psz` token rows of all heads, one
+    contiguous DMA — against the slot's `T` query rows (one is
+    `paged_flash_decode`; more are `paged_flash_verify`: the pending
+    token plus the drafts, causal within the block: key j stays visible
+    to row t only while j <= n_valid - T + t). The page table and the
+    written lengths ride scalar prefetch: the K/V index maps
+    dereference table[slot, page], CLAMPED to the slot's last written
+    page, so the steps past it name the block already resident and cost
+    no copy; `pl.when` skips their arithmetic. The page axis is the
+    reduction: running m and l ([T, H], a value a head) and acc
+    ([T, H * d]) live in VMEM scratch and the normalised output is
+    written at the last page. The `T` rows go through every product as
+    ONE block ([T * psz, H * d] against the page), so the body traces to
+    the same equations at any `T`. With the heads on the lanes a head's
+    logit is a sum over its own `d` lanes: a product with a thin 0/1
+    matrix ([H * d, H]), and one back ([H, H * d]) spreads a head's
+    weight over its lanes — `_dot_indicator`, float32 sums of float32
+    products throughout."""
     import jax
     import jax.numpy as jnp
 
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
+
+    hd = h * d
 
     def kernel(tbl_ref, len_ref, *refs):
         refs = list(refs)
@@ -1760,49 +1789,68 @@ def _paged_flash_decode_call(S, h, mp, psz, d, s, hb, has_scale,
         if has_bias:
             bias_ref = refs[0]
             refs = refs[1:]
-        o_ref, m_sc, l_sc, acc_sc = refs
-        pi = pl.program_id(2)
+        o_ref, m_sc, l_sc, acc_sc, sum_sc, spread_sc = refs
+        pi = pl.program_id(1)
         start = pi * jnp.int32(psz)
         n_valid = len_ref[pl.program_id(0)]
 
         @pl.when(pi == 0)
         def _init():
-            m_sc[...] = jnp.full((hb, 1, 1), -1e30, jnp.float32)
-            l_sc[...] = jnp.zeros((hb, 1, 1), jnp.float32)
-            acc_sc[...] = jnp.zeros((hb, 1, d), jnp.float32)
+            m_sc[...] = jnp.full((T, h), -1e30, jnp.float32)
+            l_sc[...] = jnp.zeros((T, h), jnp.float32)
+            acc_sc[...] = jnp.zeros((T, hd), jnp.float32)
+            # which lanes are which head's: [hd, h] sums a head's lanes,
+            # [h, hd] spreads a head's value back over them. Built once
+            # a slot, not once a page (128 vregs of compares)
+            for ref in (sum_sc, spread_sc):
+                ax = ref.shape.index(hd)
+                lane = jax.lax.broadcasted_iota(jnp.int32, ref.shape, ax)
+                lo = jax.lax.broadcasted_iota(
+                    jnp.int32, ref.shape, 1 - ax) * jnp.int32(d)
+                ref[...] = ((lane >= lo) & (lane < lo + jnp.int32(d))
+                            ).astype(jnp.bfloat16)
+
+        def over_lanes(x):                      # (n, h) -> (n, hd)
+            return _dot_indicator(x, spread_sc[...])
 
         # a page entirely past the written region adds an exact zero
         @pl.when(start < n_valid)
         def _compute():
-            qb = q_ref[...].astype(jnp.float32) * jnp.float32(s)
-            kb = k_ref[...].astype(jnp.float32)           # (hb, psz, d)
+            kb = k_ref[...].astype(jnp.float32)           # (psz, hd)
             vb = v_ref[...].astype(jnp.float32)
             if has_scale:
-                kb = kb * ks_ref[...]                     # dequantize
-                vb = vb * vs_ref[...]                     # in-kernel
-            logits = (qb * kb).sum(axis=-1, keepdims=True)  # (hb,psz,1)
+                kb = kb * over_lanes(ks_ref[...])         # dequantize
+                vb = vb * over_lanes(vs_ref[...])         # in-kernel
+            qb = q_ref[...].astype(jnp.float32) * jnp.float32(s)
+            # per-head logits of every (row, key) pair: (T, psz, h)
+            logits = _dot_indicator(
+                (qb[:, None, :] * kb[None]).reshape(T * psz, hd),
+                sum_sc[...]).reshape(T, psz, h)
             kpos = start + jax.lax.broadcasted_iota(
-                jnp.int32, (1, psz, 1), 1)
-            logits = jnp.where(kpos < n_valid, logits,
-                               jnp.float32(-1e30))
+                jnp.int32, (T, psz, h), 1)
+            qpos = (n_valid - jnp.int32(T)) + jax.lax.broadcasted_iota(
+                jnp.int32, (T, psz, h), 0)
+            logits = jnp.where(kpos <= qpos, logits, jnp.float32(-1e30))
             if has_bias:
                 logits = logits + bias_ref[...][None]
-            m_prev = m_sc[...]
-            m_new = jnp.maximum(m_prev,
-                                logits.max(axis=1, keepdims=True))
+            # a row whose own position precedes the page keeps m_prev
+            # (page 0 gave every row a finite one) and adds exact zeros
+            m_prev = m_sc[...]                            # (T, h)
+            m_new = jnp.maximum(m_prev, logits.max(axis=1))
             alpha = jnp.exp(m_prev - m_new)
-            p = jnp.exp(logits - m_new)
-            l_sc[...] = alpha * l_sc[...] + p.sum(axis=1, keepdims=True)
-            acc_sc[...] = alpha * acc_sc[...] + (p * vb).sum(
-                axis=1, keepdims=True)
+            p = jnp.exp(logits - m_new[:, None, :])       # (T, psz, h)
+            l_sc[...] = alpha * l_sc[...] + p.sum(axis=1)
+            pv = over_lanes(p.reshape(T * psz, h)).reshape(T, psz, hd)
+            acc_sc[...] = over_lanes(alpha) * acc_sc[...] + (
+                pv * vb[None]).sum(axis=1)
             m_sc[...] = m_new
 
         @pl.when(pi == mp - 1)
         def _finish():
             # a slot of length 0 (inactive, trash-mapped) never
             # computes: l = 0, and the floor keeps its output finite
-            o_ref[...] = acc_sc[...] / jnp.maximum(l_sc[...],
-                                                   jnp.float32(1e-30))
+            o_ref[...] = acc_sc[...] / over_lanes(
+                jnp.maximum(l_sc[...], jnp.float32(1e-30)))
 
     def live_page(si, pi, lens):
         # logical page, clamped to the slot's last written one
@@ -1810,41 +1858,77 @@ def _paged_flash_decode_call(S, h, mp, psz, d, s, hb, has_scale,
                            jnp.int32(0)) // jnp.int32(psz)
         return jnp.minimum(pi, last)
 
-    def page_ix(si, hi, pi, tbl, lens):
-        return (tbl[si, live_page(si, pi, lens)], hi, _z(), _z())
+    def page_ix(si, pi, tbl, lens):
+        return (tbl[si, live_page(si, pi, lens)], _z(), _z())
 
-    def q_ix(si, hi, pi, *_):
-        return (si, hi, _z(), _z())
+    def q_ix(si, pi, *_):
+        return (si, _z(), _z())
 
     in_specs = [
-        pl.BlockSpec((None, hb, 1, d), q_ix),
-        pl.BlockSpec((None, hb, psz, d), page_ix),
-        pl.BlockSpec((None, hb, psz, d), page_ix),
+        pl.BlockSpec((None, T, hd), q_ix),
+        pl.BlockSpec((None, psz, hd), page_ix),
+        pl.BlockSpec((None, psz, hd), page_ix),
     ]
     if has_scale:
-        in_specs.append(pl.BlockSpec((None, hb, 1, 1), page_ix))
-        in_specs.append(pl.BlockSpec((None, hb, 1, 1), page_ix))
+        in_specs.append(pl.BlockSpec((None, 1, h), page_ix))
+        in_specs.append(pl.BlockSpec((None, 1, h), page_ix))
     if has_bias:
         # bias lives in LOGICAL per-slot coordinates [S, L, 1]: block
-        # by (slot, logical page), no table dereference; the heads of
-        # a step share it
+        # by (slot, logical page), no table dereference; the heads and
+        # the query rows of a step share it
         in_specs.append(pl.BlockSpec(
             (None, psz, 1),
-            lambda si, hi, pi, tbl, lens: (si, live_page(si, pi, lens),
-                                           _z())))
+            lambda si, pi, tbl, lens: (si, live_page(si, pi, lens),
+                                       _z())))
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2, grid=(S, h // hb, mp),
+        num_scalar_prefetch=2, grid=(S, mp),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((None, hb, 1, d), q_ix),
-        scratch_shapes=[pltpu.VMEM((hb, 1, 1), jnp.float32),
-                        pltpu.VMEM((hb, 1, 1), jnp.float32),
-                        pltpu.VMEM((hb, 1, d), jnp.float32)])
+        out_specs=pl.BlockSpec((None, T, hd), q_ix),
+        scratch_shapes=[pltpu.VMEM((T, h), jnp.float32),
+                        pltpu.VMEM((T, h), jnp.float32),
+                        pltpu.VMEM((T, hd), jnp.float32),
+                        pltpu.VMEM((hd, h), jnp.bfloat16),
+                        pltpu.VMEM((h, hd), jnp.bfloat16)])
     return pl.pallas_call(
         kernel, grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((S, h, 1, d), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((S, T, hd), jnp.float32),
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
-        interpret=interpret, name="paged_flash_decode")
+            dimension_semantics=("parallel", "arbitrary")),
+        interpret=interpret,
+        name="paged_flash_decode" if T == 1 else "paged_flash_verify")
+
+
+@functools.lru_cache(maxsize=None)
+def _paged_flash_jit(scale, interpret):
+    """The call of a paged kernel as ONE jitted function a (scale,
+    interpret): a stack's layers call it with the same shapes, so the
+    second layer on reads the first's trace and lowering instead of
+    tracing the kernel body again — a third of what `pstep` and
+    `pattach` cost to trace at twelve layers. XLA inlines it."""
+    import jax
+    import jax.numpy as jnp
+
+    def paged_flash(q, k_pages, v_pages, k_scale, v_scale, table, length,
+                    bias):
+        S, h, T, d = q.shape
+        mp = table.shape[1]
+        psz = k_pages.shape[1]
+        s = scale if scale is not None else 1.0 / math.sqrt(d)
+        call = _paged_flash_call(S, h, mp, psz, d, T, s,
+                                 k_scale is not None, bias is not None,
+                                 interpret)
+        # the query and the output in the pages' row form, [S, T, H * d]
+        args = [jnp.swapaxes(q, 1, 2).reshape(S, T, h * d), k_pages,
+                v_pages]
+        if k_scale is not None:
+            args += [k_scale, v_scale]
+        if bias is not None:
+            args.append(jnp.asarray(bias, jnp.float32)[:, :, None])
+        out = call(jnp.asarray(table, jnp.int32),
+                   jnp.asarray(length, jnp.int32), *args)
+        return jnp.swapaxes(out.reshape(S, T, h, d), 1, 2).astype(q.dtype)
+
+    return jax.jit(paged_flash)
 
 
 def paged_flash_decode(q, k_pages, v_pages, k_scale, v_scale, table,
@@ -1852,31 +1936,16 @@ def paged_flash_decode(q, k_pages, v_pages, k_scale, v_scale, table,
     """Pallas paged decode: one query token per slot against K/V
     gathered THROUGH the page table — no dense materialization, no
     per-page partials: the merge over pages happens in the kernel. q
-    [S, h, 1, d]; pages [N+1, h, psz, d] (+1 = trash row); table
+    [S, h, 1, d]; pages [N+1, psz, h * d] (+1 = trash row); table
     [S, max_pages] int32 (trash-clipped); length [S] written counts;
-    k_scale/v_scale optional [N+1, h, 1, 1] per-page dequant scales;
-    bias optional [S, L] additive key bias in logical coordinates."""
-    import jax.numpy as jnp
-
-    S, h, sq, d = q.shape
-    if sq != 1:
+    k_scale/v_scale optional [N+1, 1, h] per-(page, head) dequant
+    scales; bias optional [S, L] additive key bias in logical
+    coordinates."""
+    if q.shape[2] != 1:
         raise ValueError("paged_flash_decode takes a single query "
                          "token per slot")
-    mp = table.shape[1]
-    psz = k_pages.shape[2]
-    s = scale if scale is not None else 1.0 / math.sqrt(d)
-    call = _paged_flash_decode_call(
-        S, h, mp, psz, d, s,
-        _paged_head_block(h, psz, d, k_pages.dtype),
-        k_scale is not None, bias is not None, interpret)
-    args = [q, k_pages, v_pages]
-    if k_scale is not None:
-        args += [k_scale, v_scale]
-    if bias is not None:
-        args.append(jnp.asarray(bias, jnp.float32)[:, :, None])
-    out = call(jnp.asarray(table, jnp.int32),
-               jnp.asarray(length, jnp.int32), *args)
-    return out.astype(q.dtype)
+    return _paged_flash_jit(scale, interpret)(
+        q, k_pages, v_pages, k_scale, v_scale, table, length, bias)
 
 
 def paged_decode_attention(q, k_pages, v_pages, k_scale, v_scale, table,
@@ -1888,19 +1957,18 @@ def paged_decode_attention(q, k_pages, v_pages, k_scale, v_scale, table,
     reference — with same-dtype pages the gathered buffer reproduces
     the dense StaticKVCache bit-for-bit, which is what makes paged
     serving bit-identical to the dense pool on the fallback path."""
-    psz = k_pages.shape[2]
+    h, d = q.shape[1], q.shape[-1]
+    psz = k_pages.shape[1]
     q = _constrain_decode(q, "q")
     k_pages = _constrain_decode(k_pages, "pages")
     v_pages = _constrain_decode(v_pages, "pages")
     use_kernel = interpret or (
-        _on_tpu() and q.shape[-1] <= 256 and psz % 8 == 0
-        and _flash_usable())
+        _on_tpu() and _paged_kernel_fits(psz, h * d) and _flash_usable())
     if use_kernel and not interpret:
-        # dispatch-level tuning knob: the kernel reads its one block
-        # shape (heads a step) from the shapes, but a device tier can
-        # force the XLA gather path where the kernel loses
-        cfg = _tuned("paged_flash_decode",
-                     (q.shape[-1], psz, str(k_pages.dtype)))
+        # dispatch-level tuning knob: the kernel's one block is the
+        # page, but a device tier can force the XLA gather path where
+        # the kernel loses
+        cfg = _tuned("paged_flash_decode", (d, psz, str(k_pages.dtype)))
         if cfg is not None and not cfg.get("kernel", True):
             use_kernel = False
     if use_kernel:
@@ -1908,8 +1976,8 @@ def paged_decode_attention(q, k_pages, v_pages, k_scale, v_scale, table,
             paged_flash_decode(q, k_pages, v_pages, k_scale, v_scale,
                                table, length, bias, scale, interpret),
             "out")
-    kd = paged_gather_kv(k_pages, k_scale, table, q.dtype)
-    vd = paged_gather_kv(v_pages, v_scale, table, q.dtype)
+    kd = paged_gather_kv(k_pages, k_scale, table, h, q.dtype)
+    vd = paged_gather_kv(v_pages, v_scale, table, h, q.dtype)
     return _constrain_decode(
         decode_attention_reference(q, kd, vd, length, bias, scale),
         "out")
@@ -1928,149 +1996,20 @@ def _paged_verify_heuristic():
     return {"kernel": True, "split_k": 0}
 
 
-def _paged_flash_verify_call(S, h, mp, psz, d, T, s, has_scale,
-                             has_bias, interpret):
-    """The paged split-K verify kernel: one grid step per (slot*head,
-    logical page), each K/V BlockSpec index map dereferencing the
-    scalar-prefetched table to pick the physical page row to DMA, int8
-    dequant in-kernel — with
-    `_flash_verify_call`'s (T, d) query block and causal-within-the-
-    block masking: key position j stays visible to query row i only
-    while j <= the row's absolute position (n_valid - T + i). Per-page
-    partial (acc, m, l) merge in XLA with the standard logsumexp
-    combine."""
-    import jax
-    import jax.numpy as jnp
-
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
-    def kernel(tbl_ref, len_ref, *refs):
-        refs = list(refs)
-        q_ref, k_ref, v_ref = refs[:3]
-        refs = refs[3:]
-        if has_scale:
-            ks_ref, vs_ref = refs[:2]
-            refs = refs[2:]
-        if has_bias:
-            bias_ref = refs[0]
-            refs = refs[1:]
-        o_ref, m_ref, l_ref = refs
-        bh = pl.program_id(0)
-        pi = pl.program_id(1)
-        start = pi * jnp.int32(psz)
-        n_valid = len_ref[bh // jnp.int32(h)]
-
-        # every query sees keys < n_valid only, so pages entirely past
-        # the written region contribute an exact zero to the combine
-        @pl.when(start < n_valid)
-        def _compute():
-            sf = jnp.float32(s)
-            qb = q_ref[...].astype(jnp.float32) * sf      # (T, d)
-            kb = k_ref[...].astype(jnp.float32)           # (psz, d)
-            vb = v_ref[...].astype(jnp.float32)
-            if has_scale:
-                kb = kb * ks_ref[0, 0]                    # dequantize
-                vb = vb * vs_ref[0, 0]                    # in-kernel
-            logits = jnp.dot(qb, kb.T,
-                             preferred_element_type=jnp.float32)
-            kpos = start + jax.lax.broadcasted_iota(
-                jnp.int32, (T, psz), 1)
-            qpos = (n_valid - jnp.int32(T)) + jax.lax.broadcasted_iota(
-                jnp.int32, (T, psz), 0)
-            logits = jnp.where(kpos <= qpos, logits,
-                               jnp.float32(-1e30))
-            if has_bias:
-                logits = logits + bias_ref[...][:, 0][None, :]
-            m = logits.max(axis=-1, keepdims=True)        # (T, 1)
-            p = jnp.exp(logits - m)
-            # a query row fully masked within an active page (its
-            # position precedes the page) leaves m = -1e30; the XLA
-            # combine's alpha flushes that page's contribution to an
-            # exact zero — every row's own position guarantees some
-            # page holds a finite m
-            l = p.sum(axis=-1, keepdims=True)
-            o_ref[...] = jnp.dot(p, vb,
-                                 preferred_element_type=jnp.float32)
-            m_ref[...] = m
-            l_ref[...] = l
-
-        @pl.when(start >= n_valid)
-        def _skip():
-            o_ref[...] = jnp.zeros((T, d), jnp.float32)
-            m_ref[...] = jnp.full((T, 1), -1e30, jnp.float32)
-            l_ref[...] = jnp.zeros((T, 1), jnp.float32)
-
-    def page_ix(bh, pi, tbl, lens):
-        return (tbl[bh // jnp.int32(h), pi], bh % jnp.int32(h),
-                _z(), _z())
-
-    in_specs = [
-        pl.BlockSpec((None, T, d), lambda bh, pi, *_: (bh, _z(), _z())),
-        pl.BlockSpec((None, None, psz, d), page_ix),
-        pl.BlockSpec((None, None, psz, d), page_ix),
-    ]
-    if has_scale:
-        in_specs.append(pl.BlockSpec((None, None, 1, 1), page_ix))
-        in_specs.append(pl.BlockSpec((None, None, 1, 1), page_ix))
-    if has_bias:
-        # bias lives in LOGICAL per-slot coordinates [S, L, 1]: block
-        # by (slot, logical page), no table dereference
-        in_specs.append(pl.BlockSpec(
-            (None, psz, 1),
-            lambda bh, pi, *_: (bh // jnp.int32(h), pi, _z())))
-    out_specs = [
-        pl.BlockSpec((None, None, T, d),
-                     lambda bh, pi, *_: (bh, pi, _z(), _z())),
-        pl.BlockSpec((None, None, T, 1),
-                     lambda bh, pi, *_: (bh, pi, _z(), _z())),
-        pl.BlockSpec((None, None, T, 1),
-                     lambda bh, pi, *_: (bh, pi, _z(), _z())),
-    ]
-    out_shape = [
-        jax.ShapeDtypeStruct((S * h, mp, T, d), jnp.float32),
-        jax.ShapeDtypeStruct((S * h, mp, T, 1), jnp.float32),
-        jax.ShapeDtypeStruct((S * h, mp, T, 1), jnp.float32),
-    ]
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2, grid=(S * h, mp),
-        in_specs=in_specs, out_specs=out_specs)
-    return pl.pallas_call(kernel, grid_spec=grid_spec,
-                          out_shape=out_shape, interpret=interpret,
-                          name="paged_flash_verify")
-
-
 def paged_flash_verify(q, k_pages, v_pages, k_scale, v_scale, table,
                        length, bias=None, scale=None, interpret=False):
     """Pallas paged verify: T query tokens per slot (the pending token
     plus T-1 drafts, just written through the page table at each
-    slot's own offset) against K/V read THROUGH the table — no dense
-    materialization. q [S, h, T, d]; pages [N+1, h, psz, d] (+1 =
-    trash row); table [S, max_pages] int32 (trash-clipped); length [S]
-    written counts AFTER the T-token write; k_scale/v_scale optional
-    [N+1, h, 1, 1] per-page dequant scales; bias optional [S, L]
-    additive key bias in logical coordinates."""
-    import jax.numpy as jnp
-
-    S, h, T, d = q.shape
-    mp = table.shape[1]
-    psz = k_pages.shape[2]
-    s = scale if scale is not None else 1.0 / math.sqrt(d)
-    call = _paged_flash_verify_call(S, h, mp, psz, d, T, s,
-                                    k_scale is not None,
-                                    bias is not None, interpret)
-    args = [q.reshape(S * h, T, d), k_pages, v_pages]
-    if k_scale is not None:
-        args += [k_scale, v_scale]
-    if bias is not None:
-        args.append(jnp.asarray(bias, jnp.float32)[:, :, None])
-    acc, m, l = call(jnp.asarray(table, jnp.int32),
-                     jnp.asarray(length, jnp.int32), *args)
-    m_star = m.max(axis=1, keepdims=True)
-    alpha = jnp.exp(m - m_star)
-    num = (acc * alpha).sum(axis=1)                # [S*h, T, d]
-    den = jnp.maximum((l * alpha).sum(axis=1), 1e-30)
-    return (num / den).astype(q.dtype).reshape(S, h, T, d)
+    slot's own offset) against K/V read THROUGH the table — the decode
+    kernel's grid with a [T, h * d] query block, causal within the
+    block, merged over pages in the kernel. q [S, h, T, d]; pages
+    [N+1, psz, h * d] (+1 = trash row); table [S, max_pages] int32
+    (trash-clipped); length [S] written counts AFTER the T-token write;
+    k_scale/v_scale optional [N+1, 1, h] per-(page, head) dequant
+    scales; bias optional [S, L] additive key bias in logical
+    coordinates."""
+    return _paged_flash_jit(scale, interpret)(
+        q, k_pages, v_pages, k_scale, v_scale, table, length, bias)
 
 
 def paged_verify_attention(q, k_pages, v_pages, k_scale, v_scale,
@@ -2084,25 +2023,25 @@ def paged_verify_attention(q, k_pages, v_pages, k_scale, v_scale,
     which keeps paged speculative serving bit-identical to the dense
     pool on the fallback path. The tuned table's (kernel, split_k)
     ladder picks the path and the gather-side split factor."""
-    psz = k_pages.shape[2]
-    T = q.shape[2]
+    h, T, d = q.shape[1:]
+    psz = k_pages.shape[1]
     q = _constrain_decode(q, "q")
     k_pages = _constrain_decode(k_pages, "pages")
     v_pages = _constrain_decode(v_pages, "pages")
     cfg = _tuned("paged_flash_verify",
-                 (q.shape[-1], psz, str(k_pages.dtype), int(T)))
+                 (d, psz, str(k_pages.dtype), int(T)))
     if cfg is None:
         cfg = _paged_verify_heuristic()
     use_kernel = interpret or (
-        _on_tpu() and q.shape[-1] <= 256 and psz % 8 == 0
+        _on_tpu() and _paged_kernel_fits(psz, h * d, T)
         and _flash_usable() and bool(cfg.get("kernel", True)))
     if use_kernel:
         return _constrain_decode(
             paged_flash_verify(q, k_pages, v_pages, k_scale, v_scale,
                                table, length, bias, scale, interpret),
             "out")
-    kd = paged_gather_kv(k_pages, k_scale, table, q.dtype)
-    vd = paged_gather_kv(v_pages, v_scale, table, q.dtype)
+    kd = paged_gather_kv(k_pages, k_scale, table, h, q.dtype)
+    vd = paged_gather_kv(v_pages, v_scale, table, h, q.dtype)
     split = int(cfg.get("split_k", 0)) or None
     return _constrain_decode(
         verify_attention(q, kd, vd, length, bias, scale,

@@ -1727,7 +1727,7 @@ def _make_cross_kv_fm(decoder):
 class PagedServingEngine(ServingEngine):
     """The serving pool over PAGED KV storage: `ServingEngine(...,
     paged=True)`. Device K/V lives in a global pool of fixed-size pages
-    ([num_pages + 1, H, page_size, D] per layer — static shape, one
+    ([num_pages + 1, page_size, H * D] per layer — static shape, one
     compile per pool config); each slot maps its logical positions
     through a host-owned int32 page table shipped to the device as a
     traced input every step, so page mapping, joins, and evictions

@@ -7,9 +7,13 @@ identical system prompts are re-prefilled for every request. This
 module replaces the per-slot rows with a **global pool of fixed-size
 pages** plus an int32 indirection:
 
-  * pages live in static-shape arrays `[n_pages + 1, H, page_size, D]`
-    per layer (row `n_pages` is the TRASH page — inactive slots' masked
-    decode writes land there, never on live data);
+  * pages live in static-shape arrays `[n_pages + 1, page_size, H * D]`
+    per layer: a page is `page_size` token rows, a row all heads' `D`
+    values side by side — token-major with the heads merged on the
+    minor axis, which the chip tiles without padding and every pool
+    program (decode write, join scatter, the paged kernels) reads and
+    writes in place (row `n_pages` is the TRASH page — inactive slots'
+    masked decode writes land there, never on live data);
   * each slot owns an int32 `page_table[S, max_pages]` row mapping its
     logical block i to a physical page (host-side `-1` = unmapped,
     clipped to the trash row before it reaches the device);
@@ -708,45 +712,45 @@ class RadixPrefixCache:
 # device side: pure jnp page math (safe under jit; shapes static)
 # --------------------------------------------------------------------------
 
-def quantize_chunks(chunks, storage_dtype, quantized):
-    """[N, H, page_size, D] compute-dtype chunks -> (stored, scale).
-    int8: symmetric per-(page, head) amax/127 scale (1.0 for all-zero
-    pages so dequant never divides by zero); other dtypes: plain cast,
-    scale None."""
+def quantize_chunks(chunks, storage_dtype, quantized, num_heads=None):
+    """[N, page_size, H * D] compute-dtype chunks -> (stored, scale).
+    int8: symmetric per-(page, head) amax/127 scale, [N, 1, H] (1.0 for
+    all-zero pages so dequant never divides by zero), which needs
+    `num_heads` to find the heads on the merged axis; other dtypes:
+    plain cast, scale None."""
     import jax.numpy as jnp
 
     if not quantized:
         return chunks.astype(storage_dtype), None
-    amax = jnp.max(jnp.abs(chunks.astype(jnp.float32)), axis=(2, 3),
-                   keepdims=True)                     # [N, H, 1, 1]
+    N, psz, HD = chunks.shape
+    x = chunks.astype(jnp.float32).reshape(N, psz, num_heads, -1)
+    amax = jnp.max(jnp.abs(x), axis=(1, 3), keepdims=True)  # [N,1,H,1]
     scale = jnp.where(amax > 0, amax / _QMAX, 1.0).astype(jnp.float32)
-    q = jnp.clip(jnp.round(chunks.astype(jnp.float32) / scale),
-                 -_QMAX, _QMAX).astype(jnp.int8)
-    return q, scale
+    q = jnp.clip(jnp.round(x / scale), -_QMAX, _QMAX).astype(jnp.int8)
+    return q.reshape(N, psz, HD), scale[..., 0]
 
 
 def chunk_prompt(kv, page_size):
-    """A prefilled [1, H, P, D] K or V block -> [n_pages, H, page_size,
-    D] page chunks (tail zero-padded to the page boundary)."""
+    """A prefilled [1, H, P, D] K or V block -> [n_pages, page_size,
+    H * D] page chunks (tail zero-padded to the page boundary): one
+    transpose of the PROMPT to token-major rows, never of the pool."""
     import jax.numpy as jnp
 
     _, H, P, D = kv.shape
     n_pp = pages_for(P, page_size)
     pad = n_pp * page_size - P
-    x = kv[0]
+    x = jnp.transpose(kv[0], (1, 0, 2)).reshape(P, H * D)
     if pad:
-        x = jnp.concatenate(
-            [x, jnp.zeros((H, pad, D), x.dtype)], axis=1)
-    return jnp.transpose(
-        x.reshape(H, n_pp, page_size, D), (1, 0, 2, 3))
+        x = jnp.concatenate([x, jnp.zeros((pad, H * D), x.dtype)], axis=0)
+    return x.reshape(n_pp, page_size, H * D)
 
 
 def write_prompt_pages(pages, scales, page_ids, kv, quantized):
     """Scatter a prefilled [1, H, P, D] block into `pages` at the
     (traced int32 [n_pages]) `page_ids`. Returns (pages, scales)."""
-    page_size = pages.shape[2]
-    chunks = chunk_prompt(kv, page_size)
-    stored, sc = quantize_chunks(chunks, pages.dtype, quantized)
+    chunks = chunk_prompt(kv, pages.shape[1])
+    stored, sc = quantize_chunks(chunks, pages.dtype, quantized,
+                                 kv.shape[1])
     pages = pages.at[page_ids].set(stored)
     if quantized:
         scales = scales.at[page_ids].set(sc)
@@ -755,38 +759,39 @@ def write_prompt_pages(pages, scales, page_ids, kv, quantized):
 
 def write_token(pages, scales, table, index, tok):
     """The decode write: slot s's token K or V ([S, H, D]) lands at
-    logical position index[s] — physical page table[s, index[s] //
-    page_size], offset index[s] % page_size. Slots whose table entry
-    points at the trash row write garbage there harmlessly (the engine
-    maps every ACTIVE slot's write page before the step). int8 pages
-    whose scale the new token outranges are rescaled in place (the
-    per-page scale only ever grows)."""
+    logical position index[s] — row index[s] % page_size of physical
+    page table[s, index[s] // page_size], one H * D row a slot. Slots
+    whose table entry points at the trash row write garbage there
+    harmlessly (the engine maps every ACTIVE slot's write page before
+    the step). int8 pages whose scale the new token outranges are
+    rescaled in place (the per-(page, head) scale only ever grows)."""
     import jax.numpy as jnp
 
-    page_size = pages.shape[2]
-    S = tok.shape[0]
+    page_size = pages.shape[1]
+    S, H, D = tok.shape
     pid = jnp.take_along_axis(
         table, (index // page_size)[:, None], axis=1)[:, 0]   # [S]
     off = index % page_size
     if scales is None:
-        return pages.at[pid, :, off, :].set(tok.astype(pages.dtype)), \
-            None
+        return pages.at[pid, off].set(
+            tok.reshape(S, H * D).astype(pages.dtype)), None
     # gather the S target pages, grow their scales to cover the new
     # token, rescale the existing int8 payload, write, scatter back
-    pg = pages[pid].astype(jnp.float32)              # [S, H, psz, D]
-    s_old = scales[pid]                              # [S, H, 1, 1]
+    pg = pages[pid].astype(jnp.float32).reshape(S, page_size, H, D)
+    s_old = scales[pid][..., None]                   # [S, 1, H, 1]
     t32 = tok.astype(jnp.float32)
     amax = jnp.max(jnp.abs(t32), axis=-1,
-                   keepdims=True)[..., None]         # [S, H, 1, 1]
+                   keepdims=True)[:, None]           # [S, 1, H, 1]
     s_new = jnp.maximum(s_old, amax / _QMAX)
     s_new = jnp.where(s_new > 0, s_new, 1.0)
     factor = s_old / s_new                           # <= 1; exact 1.0
     #                                                  when no growth
     pg = jnp.clip(jnp.round(pg * factor), -_QMAX, _QMAX)
-    qt = jnp.clip(jnp.round(t32 / s_new[..., 0]), -_QMAX, _QMAX)
-    pg = pg.at[jnp.arange(S), :, off, :].set(qt)
-    return (pages.at[pid].set(pg.astype(jnp.int8)),
-            scales.at[pid].set(s_new))
+    qt = jnp.clip(jnp.round(t32 / s_new[:, 0]), -_QMAX, _QMAX)
+    pg = pg.at[jnp.arange(S), off].set(qt)
+    return (pages.at[pid].set(
+                pg.reshape(S, page_size, H * D).astype(jnp.int8)),
+            scales.at[pid].set(s_new[..., 0]))
 
 
 def write_tokens(pages, scales, table, index, toks):
@@ -794,9 +799,10 @@ def write_tokens(pages, scales, table, index, toks):
     ([S, H, T, D]) land at logical positions index[s] .. index[s] +
     T - 1, crossing page boundaries wherever they fall — position j
     resolves its OWN physical page through the table, so a block that
-    straddles two (or more) pages scatters into each. Rides
-    `write_token`'s math position by position (T is a static trace
-    constant), so int8 pages inherit the grow-only scale rescale
+    straddles two (or more) pages scatters into each: ONE scatter of
+    S * T rows, the positions of a slot being distinct. int8 pages
+    ride `write_token`'s math position by position instead (T is a
+    static trace constant), so they inherit the grow-only scale rescale
     exactly: a later token that outranges the page re-rescales the
     payload the earlier tokens just wrote. Rejected speculative tokens
     need no undo — the caller rolls the per-slot index back and the
@@ -804,8 +810,15 @@ def write_tokens(pages, scales, table, index, toks):
     before any query can see them."""
     import jax.numpy as jnp
 
-    T = toks.shape[2]
+    S, H, T, D = toks.shape
     index = jnp.asarray(index, jnp.int32)
+    if scales is None:
+        page_size = pages.shape[1]
+        pos = index[:, None] + jnp.arange(T, dtype=jnp.int32)  # [S, T]
+        pid = jnp.take_along_axis(table, pos // page_size, axis=1)
+        rows = jnp.swapaxes(toks, 1, 2).reshape(S, T, H * D)
+        return pages.at[pid, pos % page_size].set(
+            rows.astype(pages.dtype)), None
     for j in range(T):
         pages, scales = write_token(pages, scales, table,
                                     index + jnp.int32(j),
@@ -823,7 +836,7 @@ def copy_page(pages, scales, src, dst):
     return pages, scales
 
 
-def gather_pages(pages, scales, table, compute_dtype):
+def gather_pages(pages, scales, table, num_heads, compute_dtype):
     """Dense [S, H, max_pages * page_size, D] logical view of each
     slot's cache, dequantized — the XLA fallback read path (the pallas
     kernel reads pages in place through the scalar-prefetched table
@@ -831,4 +844,5 @@ def gather_pages(pages, scales, table, compute_dtype):
     that the written-length mask hides."""
     from ..ops.attention import paged_gather_kv
 
-    return paged_gather_kv(pages, scales, table, compute_dtype)
+    return paged_gather_kv(pages, scales, table, num_heads,
+                           compute_dtype)

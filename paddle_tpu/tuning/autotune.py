@@ -122,8 +122,8 @@ def candidates(kernel, key):
         return [{"split_k": n} for n in SPLIT_LADDER
                 if L % n == 0 and (L // n) % 128 == 0]
     if kernel == "paged_flash_decode":
-        # dispatch-level knob only: the grid is (slot, page), the
-        # heads a step read from the shapes
+        # dispatch-level knob only: the grid is (slot, page), a step
+        # the whole page with all its heads
         return [{"kernel": True}, {"kernel": False}]
     if kernel == "paged_flash_verify":
         # the kernel grid is fixed by the pages, so kernel-on has no
@@ -382,7 +382,7 @@ def build_runner(kernel, key, config, batch=4, heads=4):
         n_pages, mp = 32, 8
         q = jnp.asarray(rs.randn(batch, heads, T, d), jnp.float32)
         pages = jnp.asarray(
-            rs.randn(n_pages + 1, heads, psz, d), jnp.float32)
+            rs.randn(n_pages + 1, psz, heads * d), jnp.float32)
         tbl = jnp.asarray(
             rs.randint(0, n_pages, (batch, mp)), jnp.int32)
         length = jnp.full((batch,), mp * psz, jnp.int32)
@@ -396,8 +396,8 @@ def build_runner(kernel, key, config, batch=4, heads=4):
         else:
             split = int(config.get("split_k", 0)) or None
             fn = jax.jit(lambda a, kp, vp, t, n: A.verify_attention(
-                a, A.paged_gather_kv(kp, None, t, a.dtype),
-                A.paged_gather_kv(vp, None, t, a.dtype), n,
+                a, A.paged_gather_kv(kp, None, t, heads, a.dtype),
+                A.paged_gather_kv(vp, None, t, heads, a.dtype), n,
                 split_k=split))
         return lambda: fn(q, pages, pages, tbl, length)
     if kernel == "int8_matmul":
@@ -437,7 +437,7 @@ def build_runner(kernel, key, config, batch=4, heads=4):
         n_pages, mp = 32, 8
         q = jnp.asarray(rs.randn(batch, heads, 1, d), jnp.float32)
         pages = jnp.asarray(
-            rs.randn(n_pages + 1, heads, psz, d), jnp.float32)
+            rs.randn(n_pages + 1, psz, heads * d), jnp.float32)
         tbl = jnp.asarray(
             rs.randint(0, n_pages, (batch, mp)), jnp.int32)
         length = jnp.full((batch,), mp * psz, jnp.int32)
@@ -451,9 +451,9 @@ def build_runner(kernel, key, config, batch=4, heads=4):
         else:
             fn = jax.jit(lambda a, kp, vp, t, n:
                          A.decode_attention_reference(
-                             a, A.paged_gather_kv(kp, None, t,
+                             a, A.paged_gather_kv(kp, None, t, heads,
                                                   a.dtype),
-                             A.paged_gather_kv(vp, None, t,
+                             A.paged_gather_kv(vp, None, t, heads,
                                                a.dtype), n))
         return lambda: fn(q, pages, pages, tbl, length)
     raise ValueError(f"unknown kernel {kernel!r}")

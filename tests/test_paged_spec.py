@@ -89,37 +89,43 @@ def _assert_leak_free(eng):
 
 @pytest.mark.parametrize("kv_dtype,T,with_bias", [
     ("f32", 4, True), ("f32", 2, False), ("int8", 4, True),
+    ("bf16", 4, True), ("bf16", 2, False), ("int8", 2, False),
+    ("f32", 16, True),          # a tail prefill's block: over two pages
 ])
 def test_paged_flash_verify_interpret_parity(kv_dtype, T, with_bias):
-    """The block-table verify kernel (interpret mode on CPU) must
+    """The page-table verify kernel (interpret mode on CPU) must
     reproduce gather + the dense verify reference — fp32 exactly to
-    float tolerance, int8 through the same per-page dequant."""
+    float tolerance, bf16 and int8 pages through the same widening and
+    per-(page, head) dequant. The written lengths (after the T-token
+    write) cover the block alone, a page boundary, one past it, and the
+    whole table."""
     import jax.numpy as jnp
 
     from paddle_tpu.ops import attention as A
-    from paddle_tpu.serving.paging import quantize_chunks
+    from paddle_tpu.serving.paging import quantize_chunks, resolve_kv_dtype
 
     rs = np.random.RandomState(0)
-    S, h, d, psz, mp = 3, 2, 8, 8, 4
+    S, h, d, psz, mp = 4, 2, 8, 8, 4
     n_pages = S * mp
     L = mp * psz
-    raw_k = jnp.asarray(rs.randn(n_pages + 1, h, psz, d), jnp.float32)
-    raw_v = jnp.asarray(rs.randn(n_pages + 1, h, psz, d), jnp.float32)
-    if kv_dtype == "int8":
-        kp, ks = quantize_chunks(raw_k, jnp.int8, True)
-        vp, vs = quantize_chunks(raw_v, jnp.int8, True)
-    else:
-        kp, ks, vp, vs = raw_k, None, raw_v, None
+    storage, quantized = resolve_kv_dtype(
+        None if kv_dtype == "f32" else kv_dtype, jnp.float32)
+    kp, ks = quantize_chunks(
+        jnp.asarray(rs.randn(n_pages + 1, psz, h * d), jnp.float32),
+        storage, quantized, h)
+    vp, vs = quantize_chunks(
+        jnp.asarray(rs.randn(n_pages + 1, psz, h * d), jnp.float32),
+        storage, quantized, h)
     table = jnp.asarray(
         rs.permutation(n_pages).reshape(S, mp), jnp.int32)
-    length = jnp.asarray([T + 1, 17, L], jnp.int32)  # after the write
+    length = jnp.asarray([T, 2 * psz, 2 * psz + 1, L], jnp.int32)
     q = jnp.asarray(rs.randn(S, h, T, d), jnp.float32)
     bias = (jnp.asarray(rs.randn(S, L), jnp.float32) * 0.1
             if with_bias else None)
     out_k = A.paged_flash_verify(q, kp, vp, ks, vs, table, length,
                                  bias=bias, interpret=True)
-    kd = A.paged_gather_kv(kp, ks, table, q.dtype)
-    vd = A.paged_gather_kv(vp, vs, table, q.dtype)
+    kd = A.paged_gather_kv(kp, ks, table, h, q.dtype)
+    vd = A.paged_gather_kv(vp, vs, table, h, q.dtype)
     out_r = A.verify_attention_reference(q, kd, vd, length, bias=bias)
     np.testing.assert_allclose(np.asarray(out_k), np.asarray(out_r),
                                rtol=1e-5, atol=1e-5)
@@ -136,16 +142,16 @@ def test_paged_verify_attention_cpu_fallback_is_reference():
     rs = np.random.RandomState(1)
     S, h, d, psz, mp, T = 2, 2, 8, 8, 2, 3
     n_pages = S * mp
-    kp = jnp.asarray(rs.randn(n_pages + 1, h, psz, d), jnp.float32)
-    vp = jnp.asarray(rs.randn(n_pages + 1, h, psz, d), jnp.float32)
+    kp = jnp.asarray(rs.randn(n_pages + 1, psz, h * d), jnp.float32)
+    vp = jnp.asarray(rs.randn(n_pages + 1, psz, h * d), jnp.float32)
     table = jnp.asarray(
         rs.permutation(n_pages).reshape(S, mp), jnp.int32)
     length = jnp.asarray([7, 12], jnp.int32)
     q = jnp.asarray(rs.randn(S, h, T, d), jnp.float32)
     out = A.paged_verify_attention(q, kp, vp, None, None, table,
                                    length)
-    kd = A.paged_gather_kv(kp, None, table, q.dtype)
-    vd = A.paged_gather_kv(vp, None, table, q.dtype)
+    kd = A.paged_gather_kv(kp, None, table, h, q.dtype)
+    vd = A.paged_gather_kv(vp, None, table, h, q.dtype)
     ref = A.verify_attention_reference(q, kd, vd, length)
     np.testing.assert_array_equal(np.asarray(out), np.asarray(ref))
 
@@ -162,7 +168,7 @@ def test_write_tokens_page_crossing_and_int8_rescale():
     rs = np.random.RandomState(2)
     S, h, d, psz, mp, T = 3, 2, 4, 8, 4, 5
     n_pages = S * mp
-    pages = jnp.asarray(rs.randn(n_pages + 1, h, psz, d), jnp.float32)
+    pages = jnp.asarray(rs.randn(n_pages + 1, psz, h * d), jnp.float32)
     table = jnp.asarray(
         rs.permutation(n_pages).reshape(S, mp), jnp.int32)
     toks = jnp.asarray(rs.randn(S, h, T, d), jnp.float32)
@@ -174,10 +180,20 @@ def test_write_tokens_page_crossing_and_int8_rescale():
         want, _ = PG.write_token(want, None, table, idx + j,
                                  toks[:, :, j, :])
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    # and the tokens sit where the logical view reads them: positions
+    # idx .. idx + T - 1 of each slot, everything else untouched
+    dense = np.asarray(PG.gather_pages(got, None, table, h, jnp.float32))
+    before = np.array(PG.gather_pages(pages, None, table, h,
+                                      jnp.float32))
+    for s_, i0 in enumerate(np.asarray(idx)):
+        np.testing.assert_array_equal(dense[s_, :, i0:i0 + T],
+                                      np.asarray(toks)[s_])
+        before[s_, :, i0:i0 + T] = np.asarray(toks)[s_]
+    np.testing.assert_array_equal(dense, before)
     # int8: identical to the sequential composition, and the scale
     # GROWS when a later token outranges the page
-    qp = jnp.zeros((n_pages + 1, h, psz, d), jnp.int8)
-    sc = jnp.full((n_pages + 1, h, 1, 1), 0.01, jnp.float32)
+    qp = jnp.zeros((n_pages + 1, psz, h * d), jnp.int8)
+    sc = jnp.full((n_pages + 1, 1, h), 0.01, jnp.float32)
     big = toks.at[:, :, T - 1, :].mul(100.0)
     got_q, got_s = PG.write_tokens(qp, sc, table, idx, big)
     want_q, want_s = qp, sc

@@ -283,7 +283,7 @@ def test_page_roundtrip_exact_fp32_bf16():
     import jax.numpy as jnp
 
     rs = np.random.RandomState(0)
-    chunks = jnp.asarray(rs.randn(3, 2, 16, 8).astype("f4"))
+    chunks = jnp.asarray(rs.randn(3, 16, 2 * 8).astype("f4"))
     q32, s32 = PG.quantize_chunks(chunks, jnp.float32, False)
     assert s32 is None
     np.testing.assert_array_equal(np.asarray(q32), np.asarray(chunks))
@@ -299,16 +299,18 @@ def test_page_roundtrip_int8_within_tolerance():
     import jax.numpy as jnp
 
     rs = np.random.RandomState(1)
-    chunks = jnp.asarray((rs.randn(4, 2, 16, 8) * 3).astype("f4"))
-    q, s = PG.quantize_chunks(chunks, jnp.int8, True)
-    assert q.dtype == jnp.int8 and s.shape == (4, 2, 1, 1)
-    deq = q.astype(jnp.float32) * s
+    chunks = jnp.asarray((rs.randn(4, 16, 2 * 8) * 3).astype("f4"))
+    q, s = PG.quantize_chunks(chunks, jnp.int8, True, 2)
+    assert q.dtype == jnp.int8 and s.shape == (4, 1, 2)
+    assert q.shape == chunks.shape
+    s_lanes = jnp.repeat(s, 8, axis=-1)       # a head's scale on its D
+    deq = q.astype(jnp.float32) * s_lanes
     err = np.asarray(jnp.abs(deq - chunks))
-    bound = np.asarray(s / 2) + 1e-7
+    bound = np.asarray(s_lanes / 2) + 1e-7
     assert (err <= bound).all()
     # all-zero pages quantize with scale 1 (no divide-by-zero)
-    qz, sz = PG.quantize_chunks(jnp.zeros((1, 2, 16, 8)), jnp.int8,
-                                True)
+    qz, sz = PG.quantize_chunks(jnp.zeros((1, 16, 2 * 8)), jnp.int8,
+                                True, 2)
     assert float(jnp.abs(qz).max()) == 0 and float(sz.min()) == 1.0
 
 
@@ -319,11 +321,14 @@ def test_gather_pages_reproduces_dense_exactly():
     S, H, psz, mp, D = 3, 2, 16, 4, 8
     dense = rs.randn(S, H, mp * psz, D).astype("f4")
     table = np.arange(S * mp, dtype=np.int32).reshape(S, mp)
-    pages = np.zeros((S * mp + 1, H, psz, D), "f4")
+    pages = np.zeros((S * mp + 1, psz, H * D), "f4")
     for s in range(S):
         for p in range(mp):
-            pages[table[s, p]] = dense[s, :, p * psz:(p + 1) * psz, :]
-    g = PG.gather_pages(jnp.asarray(pages), None, jnp.asarray(table),
+            # a page is psz token rows, a row the heads side by side
+            pages[table[s, p]] = dense[
+                s, :, p * psz:(p + 1) * psz, :].transpose(
+                    1, 0, 2).reshape(psz, H * D)
+    g = PG.gather_pages(jnp.asarray(pages), None, jnp.asarray(table), H,
                         jnp.float32)
     np.testing.assert_array_equal(np.asarray(g), dense)
 
@@ -331,35 +336,47 @@ def test_gather_pages_reproduces_dense_exactly():
 # (S, H, psz, mp, D) and the written length of every slot. 0 is an
 # inactive slot (its table row is all trash, as the pool maps it); the
 # others cover one token, a page boundary, mid-page and all `mp` pages.
-# The wide shape's K and V blocks pass the kernel's VMEM budget, so a
-# grid step takes half the heads and the head-block axis has two steps.
+# The wide shape's heads are two lane tiles each, so the per-head sums
+# pair lanes across tiles.
+# "shared" is the pool's shape with the prefix cache's sharing in the
+# table: two slots map ONE physical first page, and a third maps a
+# `copy_page` duplicate of it (the copy-on-write a joiner decodes into).
+# "cell" is the benchmark pool's page: 16 heads of 64 on 1,024 lanes.
 _FLASH_SHAPES = {
     "pool": ((5, 2, 16, 4, 8), [0, 1, 16, 33, 64]),
-    "wide": ((2, 8, 256, 3, 256), [300, 768]),
+    "shared": ((5, 2, 16, 4, 8), [0, 1, 16, 33, 64]),
+    "wide": ((2, 4, 32, 3, 256), [40, 96]),
+    "cell": ((3, 16, 16, 3, 64), [0, 17, 48]),
 }
 
 
 @pytest.mark.parametrize("shape", sorted(_FLASH_SHAPES))
 @pytest.mark.parametrize("with_bias", [True, False],
                          ids=["bias", "nobias"])
-@pytest.mark.parametrize("kv", ["float32", "int8"])
+@pytest.mark.parametrize("kv", ["float32", "bfloat16", "int8"])
 def test_paged_flash_decode_interpret_parity(kv, with_bias, shape):
     """The scalar-prefetch page-table kernel (interpret mode on CPU)
-    matches the gathered XLA reference over a shuffled table: fp32 and
-    int8 pages (dequantized in-kernel), with and without a key bias."""
+    matches the gathered XLA reference over a shuffled table: fp32,
+    bf16 and int8 pages (widened / dequantized in-kernel), with and
+    without a key bias."""
     import jax.numpy as jnp
 
     from paddle_tpu.ops import attention as A
 
     (S, H, psz, mp, D), lens = _FLASH_SHAPES[shape]
-    hb = A._paged_head_block(H, psz, D, jnp.float32)
-    assert (hb < H) == (shape == "wide") and H % hb == 0
+    assert A._paged_kernel_fits(psz, H * D) == (shape in ("wide", "cell"))
     rs = np.random.RandomState(3)
     N = S * mp + 2
     table = rs.permutation(N)[:S * mp].reshape(S, mp).astype(np.int32)
     for s, n in enumerate(lens):
         table[s, PG.pages_for(n, psz):] = N         # unmapped -> trash
-    pages = jnp.asarray(rs.randn(N + 1, H, psz, D).astype("f4"))
+    pages = jnp.asarray(rs.randn(N + 1, psz, H * D).astype("f4"))
+    if shape == "shared":
+        spare = table[4, 0]
+        table[4, 0] = table[3, 0]                   # mapped twice
+        pages, _ = PG.copy_page(pages, None, jnp.int32(table[3, 0]),
+                                jnp.int32(spare))
+        table[2, 0] = spare                         # the COW duplicate
     tbl = jnp.asarray(table)
     q = jnp.asarray(rs.randn(S, H, 1, D).astype("f4"))
     length = jnp.asarray(lens, jnp.int32)
@@ -368,10 +385,8 @@ def test_paged_flash_decode_interpret_parity(kv, with_bias, shape):
         bias = rs.randn(S, mp * psz).astype("f4") * 0.1
         bias[:, 2:5] = -1e9         # a bucketed prompt's pad hole
         bias = jnp.asarray(bias)
-    scales = None
-    if kv == "int8":
-        pages, scales = PG.quantize_chunks(pages, jnp.int8, True)
-    dense = PG.gather_pages(pages, scales, tbl, jnp.float32)
+    pages, scales = PG.quantize_chunks(pages, kv, kv == "int8", H)
+    dense = PG.gather_pages(pages, scales, tbl, H, jnp.float32)
     ref = np.asarray(A.decode_attention_reference(q, dense, dense, length,
                                                   bias))
     out = np.asarray(A.paged_flash_decode(q, pages, pages, scales, scales,
@@ -382,7 +397,7 @@ def test_paged_flash_decode_interpret_parity(kv, with_bias, shape):
     # a slot that holds nothing has no reference (a softmax over no
     # key); the kernel's answer for it is an exact, finite zero
     assert not out[~live].any()
-    np.testing.assert_allclose(out[live], ref[live], rtol=2e-5, atol=2e-6)
+    np.testing.assert_allclose(out[live], ref[live], rtol=1e-5, atol=2e-6)
 
 
 # ----------------------------------------------------------------------

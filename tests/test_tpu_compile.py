@@ -97,8 +97,8 @@ def test_flash_decode_and_verify_compile(one_chip, has_bias):
 def test_paged_flash_kernels_compile(one_chip, kv_dtype, T):
     S, h, L, d, psz = (POOL[k] for k in ("S", "h", "L", "d", "psz"))
     mp = L // psz
-    pages = ((S * mp + 1, h, psz, d), jnp.dtype(kv_dtype))
-    scale = (((S * mp + 1, h, 1, 1), jnp.float32)
+    pages = ((S * mp + 1, psz, h * d), jnp.dtype(kv_dtype))
+    scale = (((S * mp + 1, 1, h), jnp.float32)
              if kv_dtype == "int8" else None)
     fn = A.paged_flash_decode if T == 1 else A.paged_flash_verify
     compiled = _compile(fn, one_chip, ((S, h, T, d), jnp.float32), pages,
@@ -112,16 +112,16 @@ def test_paged_flash_kernels_compile(one_chip, kv_dtype, T):
 # the benchmark's pool (`bart_large_dec`: 64 slots x 1024 positions,
 # 16 heads of 64, 16-token float32 pages, a pad bias)
 BENCH_POOL = dict(S=64, h=16, L=1024, d=64, psz=16)
-# `temp_size_in_bytes` of this call before the kernel merged over pages
-# itself (PR 26's parent, same compile): the two lane-padded page pools
-# plus the per-page partials
-BENCH_POOL_TEMP_BEFORE = 1107751936
+# the most scratch a pool program may take at these shapes. With pages
+# stored [pages + 1, heads, page_size, head_dim] the decode write + the
+# kernel took 2,148,330,496 bytes: four lane-padded copies of a pool
+BENCH_POOL_TEMP_LIMIT = 64 * 2**20
 
 
 def test_paged_flash_decode_compiles_at_benchmark_shapes(one_chip):
     S, h, L, d, psz = (BENCH_POOL[k] for k in ("S", "h", "L", "d", "psz"))
     mp = L // psz
-    pages = ((S * mp + 1, h, psz, d), jnp.float32)
+    pages = ((S * mp + 1, psz, h * d), jnp.float32)
     compiled = _compile(A.paged_flash_decode, one_chip,
                         ((S, h, 1, d), jnp.float32), pages, pages, None,
                         None, ((S, mp), jnp.int32), ((S,), jnp.int32),
@@ -129,11 +129,189 @@ def test_paged_flash_decode_compiles_at_benchmark_shapes(one_chip):
     calls = [ln for ln in compiled.as_text().splitlines()
              if "custom-call(" in ln and "tpu_custom_call" in ln]
     assert len(calls) == 1
-    # one output, the merged [S, h, 1, d]: no `mp`-long partials
+    # one output, merged over the pages in the kernel, in the pages'
+    # row form [S, 1, h * d]: no `mp`-long partials
     result = calls[0].split("custom-call(")[0].split("=", 1)[1].strip()
-    assert result.startswith(f"f32[{S},{h},1,{d}]"), result
+    assert result.startswith(f"f32[{S},1,{h * d}]"), result
     assert compiled.memory_analysis().temp_size_in_bytes <= \
-        BENCH_POOL_TEMP_BEFORE
+        BENCH_POOL_TEMP_LIMIT
+
+
+def _pool_copies(compiled, n_pages, psz, hd):
+    """The `copy` operations of a compiled program whose result has the
+    page pool's shape: what the compiler puts in when two users of the
+    pool want it in two layouts."""
+    pool = f"[{n_pages + 1},{psz},{hd}]"
+    return [ln.strip() for ln in compiled.as_text().splitlines()
+            if " copy(" in ln and pool in ln.split(" copy(")[0]]
+
+
+@pytest.mark.parametrize("program", ["decode_step", "join_scatter"])
+def test_pool_programs_copy_no_pool_at_benchmark_shapes(one_chip, program):
+    """The mechanism of ROADMAP S1, held without a chip: with the pools
+    donated, the decode step (the token write for K and V + the paged
+    kernel) and the join scatter (a 512-token prompt's K and V into 32
+    pages) update the page arrays in place: the compiled program holds
+    no copy of a pool and next to no scratch."""
+    from paddle_tpu.serving import paging as PG
+
+    S, h, L, d, psz = (BENCH_POOL[k] for k in ("S", "h", "L", "d", "psz"))
+    mp = L // psz
+    n_pages = S * mp
+
+    def sd(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    pages = sd((n_pages + 1, psz, h * d))
+
+    def decode_step(kp, vp, q, ktok, vtok, tbl, idx, bias):
+        kp, _ = PG.write_token(kp, None, tbl, idx, ktok)
+        vp, _ = PG.write_token(vp, None, tbl, idx, vtok)
+        return kp, vp, A.paged_flash_decode(q, kp, vp, None, None, tbl,
+                                            idx + 1, bias)
+
+    def join_scatter(kp, vp, ids, k, v):
+        kp, _ = PG.write_prompt_pages(kp, None, ids, k, False)
+        vp, _ = PG.write_prompt_pages(vp, None, ids, v, False)
+        return kp, vp
+
+    if program == "decode_step":
+        args = (pages, pages, sd((S, h, 1, d)), sd((S, h, d)),
+                sd((S, h, d)), sd((S, mp), jnp.int32), sd((S,), jnp.int32),
+                sd((S, L)))
+        fn = decode_step
+    else:
+        P = 512
+        args = (pages, pages, sd((P // psz,), jnp.int32),
+                sd((1, h, P, d)), sd((1, h, P, d)))
+        fn = join_scatter
+    compiled = jax.jit(fn, donate_argnums=(0, 1)).lower(*args).compile()
+    assert (program == "decode_step") == \
+        ("tpu_custom_call" in compiled.as_text())
+    assert not _pool_copies(compiled, n_pages, psz, h * d)
+    assert compiled.memory_analysis().temp_size_in_bytes <= \
+        BENCH_POOL_TEMP_LIMIT
+
+
+# the most scratch the whole decode step of the benchmark's pool may
+# take (two layers of it; the scratch is a layer's, not the stack's).
+# With pages stored heads-major `pstep` took 2.25 GB: three lane-padded
+# copies of each pool a layer
+BENCH_PSTEP_TEMP_LIMIT = 100 * 10**6
+BENCH_PROGRAMS = ("pstep", "pjoin", "attach", "cow", "pattach")
+
+
+@pytest.fixture(scope="module")
+def bench_pool_programs(one_chip):
+    """{kind: (traced, compiled)} of the five programs the benchmark's
+    serving cells start with — `engine._startup_programs([512])` of the
+    `bart_large_dec` pool with two of its twelve layers (the pool of
+    zeros is 0.5 GB a layer on the host) — compiled for the described
+    chip, arguments as the engine hands them over."""
+    import json
+
+    from benchmark.builders import paged_pool
+
+    with open(os.path.join(os.path.dirname(__file__), os.pardir,
+                           "benchmark", "configs",
+                           "bart_large_dec.json")) as f:
+        cfg = json.load(f)
+    cfg["decoder_layers"] = 2
+    eng = paged_pool.build(cfg, 1, None)
+    eng._ensure_state(np.zeros(cfg["assumed"]["memory_shape"], "f4"))
+
+    def described(x):
+        x = x if hasattr(x, "dtype") else jnp.asarray(x)
+        return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip)
+
+    out = {}
+    with pytest.MonkeyPatch.context() as mp:
+        # the dispatchers ask the backend, see the CPU, and would take
+        # the gather composition
+        mp.setattr(A, "_on_tpu", lambda: True)
+        for key, build, args in eng._startup_programs([512]):
+            traced = build().trace(*jax.tree_util.tree_map(described,
+                                                           args))
+            out[key[0]] = (traced, traced.lower().compile())
+    out["pool_shape"] = eng._state["paged"][0]["k"].shape
+    assert eng.trace_counts and set(eng.trace_counts.values()) == {1}
+    return out
+
+
+@pytest.mark.parametrize("program", BENCH_PROGRAMS)
+def test_bench_pool_programs_copy_no_pool(bench_pool_programs, program):
+    """The whole programs of ROADMAP S1 and S3, held without a chip: the
+    decode step, the join, the prefix cache's attach, copy-on-write and
+    tail prefill all update the token-major pages in place — no `copy`
+    with the pool's shape, and the decode step's scratch is a few
+    activations, not pools."""
+    S, h, L, d, psz = (BENCH_POOL[k] for k in ("S", "h", "L", "d", "psz"))
+    n_pages = S * L // psz
+    assert bench_pool_programs["pool_shape"] == (n_pages + 1, psz, h * d)
+    _, compiled = bench_pool_programs[program]
+    assert not _pool_copies(compiled, n_pages, psz, h * d)
+    kernels = compiled.as_text().count('custom_call_target="tpu_custom_call"')
+    # the paged kernel once a layer where the program attends over pages
+    # (pjoin's are the prompt's flash kernels)
+    assert (kernels >= 2) == (program in ("pstep", "pattach", "pjoin"))
+    if program == "pstep":
+        assert compiled.memory_analysis().temp_size_in_bytes < \
+            BENCH_PSTEP_TEMP_LIMIT
+
+
+def _traced_equations(jaxpr, seen):
+    """Equations a trace paid for: every equation of the program and of
+    each DISTINCT sub-program it holds (kernel bodies, inner jits, loop
+    bodies) — a sub-program called twelve times was traced once."""
+    if id(jaxpr) in seen:
+        return 0
+    seen.add(id(jaxpr))
+    n = len(jaxpr.eqns)
+    for eqn in jaxpr.eqns:
+        for v in eqn.params.values():
+            for sub in (v if isinstance(v, (list, tuple)) else (v,)):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    n += _traced_equations(sub, seen)
+    return n
+
+
+def test_pattach_traces_no_larger_than_pjoin(bench_pool_programs):
+    """Start-up cost, held where a CPU can hold it: the serving cells
+    compile `pattach` and never run it, so what it costs to trace is
+    pure set-up time. The tail prefill's program (write T rows, attend
+    through the table) is no larger than the join's of the same 512
+    tokens. (PR 28's was four times it: a Python loop over the tail's
+    rows in the kernel body and one in the page write.)"""
+    size = {k: _traced_equations(bench_pool_programs[k][0].jaxpr.jaxpr,
+                                 set())
+            for k in ("pattach", "pjoin")}
+    assert size["pattach"] <= size["pjoin"], size
+
+
+def test_paged_verify_traces_to_the_same_size_at_any_T():
+    """The verify-mode kernel takes its T query rows as one block: the
+    call traces to the same number of equations at T = 2 and T = 16 (and
+    the float32 page write beside it to one scatter at any T)."""
+    from paddle_tpu.serving import paging as PG
+
+    S, h, d, psz, mp = 2, 2, 64, 16, 4
+    pages = jnp.zeros((S * mp + 1, psz, h * d), jnp.float32)
+    table = jnp.zeros((S, mp), jnp.int32)
+    n = jnp.full((S,), 20, jnp.int32)
+    bias = jnp.zeros((S, mp * psz), jnp.float32)
+
+    def sizes(T):
+        q = jnp.zeros((S, h, T, d), jnp.float32)
+        kernel = jax.make_jaxpr(
+            lambda q: A.paged_flash_verify(q, pages, pages, None, None,
+                                           table, n, bias))(q)
+        write = jax.make_jaxpr(
+            lambda q: PG.write_tokens(pages, None, table, n, q)[0])(q)
+        return (_traced_equations(kernel.jaxpr, set()),
+                _traced_equations(write.jaxpr, set()))
+
+    assert sizes(2) == sizes(16)
 
 
 # the training cell `nemotron3_nano_ep16.pretrain_b2_s8192`: 2 sequences
@@ -284,7 +462,7 @@ def test_kernel_failure_on_tpu_backend_raises(monkeypatch):
     monkeypatch.setattr(A, "flash_verify", boom)
     with pytest.raises(RuntimeError, match="mosaic refused"):
         A.verify_attention(jnp.zeros((1, 2, 4, 64)), kv, kv, n)
-    pages = jnp.zeros((17, 2, 16, 64), jnp.float32)
+    pages = jnp.zeros((17, 16, 2 * 64), jnp.float32)
     table = jnp.asarray(np.arange(16).reshape(1, 16), jnp.int32)
     monkeypatch.setattr(A, "paged_flash_decode", boom)
     with pytest.raises(RuntimeError, match="mosaic refused"):
@@ -301,6 +479,59 @@ def test_kernel_failure_on_tpu_backend_raises(monkeypatch):
     with pytest.raises(RuntimeError, match="mosaic refused"):
         Q.lora_delta(jnp.zeros((2, 1, 128)), jnp.zeros((3, 128, 8)),
                      jnp.zeros((3, 8, 128)), jnp.asarray([0, 1]))
+
+
+# (heads, head size, page size, query rows) -> does the pool take the
+# paged kernels. The gate reads shapes alone, in `_paged_kernel_fits`.
+_PAGED_GATE = {
+    "bench_pool": ((16, 64, 16, 1), True),
+    "bench_tail": ((16, 64, 16, 16), True),
+    "smoke_pool": ((8, 64, 16, 4), True),
+    "lanes_off_tile": ((3, 32, 16, 1), False),      # 96 lanes
+    "rows_off_tile": ((2, 64, 12, 1), False),
+    "page_over_1MiB": ((16, 64, 512, 1), False),
+    "rows_block_over_4MiB": ((16, 64, 16, 128), False),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_PAGED_GATE))
+def test_paged_dispatch_reads_the_fallback_from_shapes(monkeypatch, case):
+    """One layout, one gate: on a TPU backend a pool whose rows tile and
+    whose step fits VMEM takes the kernel, every other takes the XLA
+    gather composition over the SAME pages — chosen from the shapes,
+    never from a name or an option, and equal to the reference."""
+    (h, d, psz, T), fits = _PAGED_GATE[case]
+    assert A._paged_kernel_fits(psz, h * d, T) == fits
+    monkeypatch.setattr(A, "_on_tpu", lambda: True)
+    took = []
+
+    def kernel(q, *a, **k):
+        took.append(q.shape)
+        return q
+
+    monkeypatch.setattr(A, "paged_flash_decode", kernel)
+    monkeypatch.setattr(A, "paged_flash_verify", kernel)
+    # the composition's own dense kernel is not this test's business
+    monkeypatch.setattr(
+        A, "verify_attention",
+        lambda q, k, v, n, bias=None, scale=None, split_k=None:
+        A.verify_attention_reference(q, k, v, n, bias, scale))
+    rs = np.random.RandomState(7)
+    mp = max(2, -(-2 * T // psz))               # room for two blocks
+    pages = jnp.asarray(rs.randn(2 * mp + 1, psz, h * d), jnp.float32)
+    table = jnp.asarray(rs.permutation(2 * mp).reshape(2, mp), jnp.int32)
+    n = jnp.asarray([T + 1, mp * psz], jnp.int32)
+    q = jnp.asarray(rs.randn(2, h, T, d), jnp.float32)
+    dispatch, reference = (
+        (A.paged_decode_attention, A.decode_attention_reference)
+        if T == 1 else
+        (A.paged_verify_attention, A.verify_attention_reference))
+    out = dispatch(q, pages, pages, None, None, table, n)
+    assert bool(took) == fits
+    if not fits:
+        dense = A.paged_gather_kv(pages, None, table, h, q.dtype)
+        np.testing.assert_array_equal(
+            np.asarray(out), np.asarray(reference(q, dense, dense, n)))
 
 
 def test_partitioned_trace_takes_the_xla_composition(monkeypatch):
@@ -321,7 +552,7 @@ def test_partitioned_trace_takes_the_xla_composition(monkeypatch):
     q = jnp.full((1, 2, 1024, 64), 0.5, jnp.float32)
     q1 = jnp.zeros((1, 2, 1, 64), jnp.float32)
     kv = jnp.zeros((1, 2, 256, 64), jnp.float32)
-    pages = jnp.zeros((17, 2, 16, 64), jnp.float32)
+    pages = jnp.zeros((17, 16, 2 * 64), jnp.float32)
     table = jnp.asarray(np.arange(16).reshape(1, 16), jnp.int32)
     n = jnp.asarray([3], jnp.int32)
     assert A._flash_usable()

@@ -868,7 +868,9 @@ def test_trainer_step_is_annotated_without_a_session(tmp_path):
 
 def _pallas_call_names():
     """{file: [name= of every pallas_call]} under paddle_tpu/ops, from
-    the AST; a call without a literal name= yields None."""
+    the AST; a call without a literal name= yields None (a choice
+    between two literals, one kernel body under two names, yields
+    both)."""
     import ast
     import glob
     import os
@@ -880,11 +882,14 @@ def _pallas_call_names():
         for node in ast.walk(ast.parse(open(path).read())):
             if isinstance(node, ast.Call) and \
                     getattr(node.func, "attr", None) == "pallas_call":
-                name = [k.value.value for k in node.keywords
+                name = [c.value for k in node.keywords
                         if k.arg == "name"
-                        and isinstance(k.value, ast.Constant)]
-                out.setdefault(os.path.basename(path), []).append(
-                    name[0] if name else None)
+                        for c in ((k.value.body, k.value.orelse)
+                                  if isinstance(k.value, ast.IfExp)
+                                  else (k.value,))
+                        if isinstance(c, ast.Constant)]
+                out.setdefault(os.path.basename(path), []).extend(
+                    name or [None])
     return out
 
 

@@ -1202,9 +1202,10 @@ def _configs_paged_decode():
             mp = L // psz
             n_pages = batch * mp
             raw = jnp.asarray(
-                rs.randn(n_pages + 1, heads, psz, d).astype("f4"))
+                rs.randn(n_pages + 1, psz, heads * d).astype("f4"))
             if kv_dtype == "int8":
-                pages, scales = quantize_chunks(raw, jnp.int8, True)
+                pages, scales = quantize_chunks(raw, jnp.int8, True,
+                                                heads)
             else:
                 pages, scales = raw, None
             table = jnp.asarray(
@@ -1262,9 +1263,10 @@ def _configs_paged_verify():
             mp = L // psz
             n_pages = batch * mp
             raw = jnp.asarray(
-                rs.randn(n_pages + 1, heads, psz, d).astype("f4"))
+                rs.randn(n_pages + 1, psz, heads * d).astype("f4"))
             if kv_dtype == "int8":
-                pages, scales = quantize_chunks(raw, jnp.int8, True)
+                pages, scales = quantize_chunks(raw, jnp.int8, True,
+                                                heads)
             else:
                 pages, scales = raw, None
             table = jnp.asarray(
@@ -1389,7 +1391,7 @@ def _configs_prefix_attach():
             W = m + t                       # clipped table width
             N, T = W * psz, t * psz         # full vs tail tokens
             pages = jnp.asarray(
-                rs.randn(W + 1, heads, psz, d).astype("f4"))
+                rs.randn(W + 1, psz, heads * d).astype("f4"))
             table = jnp.asarray(
                 rs.permutation(W).astype("i4").reshape(1, W))
             q_tail = jnp.asarray(rs.randn(1, heads, T, d).astype("f4"))
@@ -1490,7 +1492,7 @@ def _configs_join_donation():
                                           False)[0]
 
             def mk_pages():
-                return jnp.zeros((n_pages + 1, heads, psz, d),
+                return jnp.zeros((n_pages + 1, psz, heads * d),
                                  jnp.float32)
 
             fn_copy = jax.jit(splice)
