@@ -1748,11 +1748,7 @@ def _serving_radix(n_requests=28, d_model=128, nhead=2, ffn=256,
     request probes per fork depth (max_new_tokens=1, so TTFT is join
     cost with no queue wait, alternating sides per rep) — asserted:
     the deepest shared-preamble depth shows a strict median TTFT win.
-    Phase 3 (submit host time): the donated joins return a TRACED
-    first token and the engine defers the int() sync past the
-    admission loop — a paired probe times the 4-join admission
-    iteration with sync_tok0 on vs off and asserts deferral never
-    slows the submit path. Since PR 17 every join DONATES the pool
+    Since PR 17 every join DONATES the pool
     carry (the splice is in place, no whole-pool copy per join) and
     the default mid_page="round_down" policy serves mid-page forks
     from the page boundary instead of COWing the divergent page —
@@ -1911,34 +1907,6 @@ def _serving_radix(n_requests=28, d_model=128, nhead=2, ffn=256,
         if f % page_size:
             assert depth_win[f]["win"] > 0.9, depth_win
 
-    # ---- phase 3: submit-path host time, deferred vs eager tok0.
-    # sync_tok0=True restores the old behavior — block on int(tok0)
-    # inside the admission loop, serializing back-to-back joins; the
-    # default defers the sync past the loop so the 4 join dispatches
-    # pipeline. Paired + alternated like the TTFT probes; deferral
-    # must never slow the submit path (the ISSUE-17 satellite check).
-    hrs = np.random.RandomState(2)
-    host = {True: [], False: []}
-    with retrace_sentinel(radix):
-        for rep in range(probe_reps * 2):
-            order = (True, False) if rep % 2 else (False, True)
-            for flag in order:
-                radix.sync_tok0 = flag
-                sched = Scheduler(max_queue=8)
-                for _ in range(4):
-                    t = hrs.randint(2, vocab, (4,))
-                    sched.submit(Request(
-                        np.concatenate([base[:64], t]).astype("i4"),
-                        sys_mem, max_new_tokens=1, eos_id=1))
-                t0 = time.perf_counter()
-                radix.run_iteration(sched)   # the 4-join admission
-                host[flag].append(time.perf_counter() - t0)
-                radix.serve_until_idle(sched, max_iterations=200)
-    radix.sync_tok0 = False
-    sync_ms = float(np.median(host[True])) * 1e3
-    defer_ms = float(np.median(host[False])) * 1e3
-    assert defer_ms <= sync_ms * 1.15, (defer_ms, sync_ms)
-
     # leak-free after the drain on both pools
     for eng in (whole, radix):
         eng.flush_prefix_cache()
@@ -1957,10 +1925,6 @@ def _serving_radix(n_requests=28, d_model=128, nhead=2, ffn=256,
             "leak_free_asserted": True,
             "retrace_sentinel": "armed over batch drive + probes",
             "ttft_by_depth": {str(k): v for k, v in depth_win.items()},
-            "submit_host": {
-                "sync_tok0_ms": round(sync_ms, 2),
-                "deferred_ms": round(defer_ms, 2),
-                "win": round(sync_ms / max(defer_ms, 1e-9), 3)},
             **({} if trace_art[0] is None
                else {"trace_artifact": trace_art[0]}),
             "radix": {"ttft_p50_ms": pct(r_ttft, 50),

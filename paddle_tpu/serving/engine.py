@@ -56,6 +56,7 @@ _PT_SLOT_JOIN = faults.point("serving.slot_join")
 _PT_PREFILL = faults.point("serving.prefill")
 _PT_PATTACH = faults.point("serving.pattach")
 _PT_DECODE = faults.point("serving.decode_step")
+_PT_READBACK = faults.point("serving.step_readback")
 _PT_CHUNK = faults.point("serving.prefill_chunk")
 _PT_PREEMPT = faults.point("serving.preempt")
 
@@ -123,6 +124,24 @@ class PoolCarryLost(RuntimeError):
     _reset_pool) so the pool rebuilds and keeps serving."""
 
 
+class _Flight:
+    """A decode step between its enqueue and the delivery of its
+    tokens. `toks` is what the step returned: the unread device array
+    [S] of a step whose successor can be enqueued before it is read,
+    else host arrays ([S], or the speculative (emit [S, k], n_emit
+    [S])). `pairs` is taken at enqueue: a token in flight belongs to
+    the request that held the slot THEN, whoever holds the slot when
+    the token is read."""
+
+    __slots__ = ("toks", "pairs", "t0")
+
+    def __init__(self, toks, pairs, t0):
+        self.toks, self.pairs, self.t0 = toks, pairs, t0
+
+    def unread(self):
+        return not isinstance(self.toks, (np.ndarray, tuple))
+
+
 class _CachedProgram:
     """A program deserialized from the persistent AOT cache, with a
     rebuild escape hatch: a stale-but-CRC-valid entry whose argument
@@ -155,7 +174,8 @@ class _EngineBase:
     """Slot lifecycle + per-iteration orchestration shared by the
     model-backed and artifact-backed engines. Subclasses implement
     `_join(slot, request) -> first_token | None`, `_decode_step(active)
-    -> tokens [S]`, and optionally `_evict(slot)` / `admit_check`.
+    -> (tokens [S], active)`, and optionally `_evict(slot)` /
+    `admit_check` / `_series_reason`.
 
     One `run_iteration(scheduler)` is the continuous-batching unit:
     (1) fault harvest — cancelled / past-deadline requests leave their
@@ -217,13 +237,11 @@ class _EngineBase:
             self.metrics.watermark_frac = float(hbm_watermark)
         self._weights_bytes = None   # cached by memory_ledger()
         self._step_cost_cache = None  # (book, key, ProgramCost)
-        # token-0 delivery policy: joins return the TRACED first-token
-        # scalar and run_iteration resolves the whole admission
-        # round's tokens after the last join dispatched — k joins pay
-        # ~1 host sync instead of k blocking int(tok0) calls on the
-        # submit path. sync_tok0=True restores the per-join block (the
-        # bench's before/after host-time check flips it).
-        self.sync_tok0 = False
+        #: the decode step whose tokens are still unread (`_Flight`): a
+        #: loop that owns consecutive iterations (`run_ahead`) leaves
+        #: one behind and the next iteration reads it AFTER it has
+        #: enqueued its own step; `run_iteration` never leaves one
+        self._flight = None
 
     # ---- subclass surface ----
     def admit_check(self, request):
@@ -233,6 +251,9 @@ class _EngineBase:
         raise NotImplementedError
 
     def _decode_step(self, active):
+        """One batched step over `active`: (tokens, the mask it really
+        ran over). Tokens are host arrays, or the unread device array
+        where `_series_reason()` can be None (`_Flight`)."""
         raise NotImplementedError
 
     def _evict(self, slot):
@@ -310,6 +331,16 @@ class _EngineBase:
         s = pick(self, now)
         if s is None:
             return None
+        if self._flight is not None:
+            # preempt_slot snapshots the victim's tokens, so what is in
+            # flight lands first; that may free a slot by itself
+            self._land()
+            free = [i for i, r in enumerate(self.slots) if r is None]
+            if free:
+                return free[0]
+            s = pick(self, now)
+            if s is None:
+                return None
         try:
             r = self.preempt_slot(s, now)
         except Exception as e:
@@ -497,15 +528,16 @@ class _EngineBase:
         return report
 
     # ---- watchdog + retry/backoff ----
-    def _guarded(self, opname, fn, retry_tokens=0):
+    def _guarded(self, opname, fn, retry_tokens=0, failed=None):
         """Run one engine op with up to `max_attempts` tries, capped
         exponential backoff between them, and a wall-clock watchdog: an
         op that returns but took > `watchdog_s` is treated as failed
         (a hung compile/dispatch that eventually unwedges must not be
         trusted to have left the iteration on schedule). The final
-        failure propagates to the caller, which isolates it."""
-        last = None
-        for attempt in range(self.max_attempts):
+        failure propagates to the caller, which isolates it. `failed`
+        is the exception of a first attempt the caller made itself."""
+        last = failed
+        for attempt in range(failed is not None, self.max_attempts):
             if attempt:
                 self.metrics.record_retry(opname, retry_tokens)
                 self._sleep(min(self.backoff_cap_s,
@@ -552,24 +584,110 @@ class _EngineBase:
                 "dead buffers")
         return self._join(s, r)
 
-    def _decode_attempt(self, active):
+    def _step_attempt(self, active):
+        """One try at a decode step: the fault point, then the
+        subclass's step. Returns its `_Flight` (the tokens unread where
+        the stepper leaves them on the device)."""
         it = self._iter_trace
         if it is not None:
             it.unwind(it.step)    # a failed attempt's open spans
         _PT_DECODE()
-        return self._decode_step(active)
+        t0 = self.clock()
+        toks, active = self._decode_step(active)
+        return _Flight(toks, [(int(s), self.slots[s])
+                              for s in np.flatnonzero(active)], t0)
+
+    def _series_attempt(self, active):
+        """A step in series: enqueued and read inside one guarded try,
+        so the watchdog times the whole step."""
+        fl = self._step_attempt(active)
+        self._read(fl)
+        return fl
+
+    def _read(self, fl):
+        """Block until the flight's tokens are on the host."""
+        if not fl.unread():
+            return
+        it = self._iter_trace
+        if it is not None:
+            sp = it.begin("step.readback")
+        try:
+            _PT_READBACK()
+            fl.toks = np.asarray(fl.toks)
+        finally:
+            if it is not None:
+                it.end(sp)
+
+    def _deliver_flight(self, fl):
+        """Hand a read flight's tokens to the requests it was enqueued
+        for: (tokens delivered, slot-steps dropped). A request that
+        ended while its token was in flight (cancelled, past its
+        deadline, `eos_id` found a step late) gets nothing more."""
+        now = self.clock()
+        n = late = 0
+        if isinstance(fl.toks, tuple):
+            # speculative step: (emit [S, k], n_emit [S]) — up to k
+            # tokens per slot per iteration; delivery stops the moment
+            # the request finishes (eos / max_new_tokens), dropping the
+            # over-speculated tail exactly like the eager oracle would
+            emit, n_emit = fl.toks
+            for s, r in fl.pairs:
+                for j in range(int(n_emit[s])):
+                    if r.state == "DONE":
+                        break
+                    self._deliver(r, int(emit[s, j]), now)
+                    n += 1
+        else:
+            for s, r in fl.pairs:
+                if r.state == "DONE":
+                    late += 1
+                    continue
+                self._deliver(r, int(fl.toks[s]), now)
+                n += 1
+        dt = now - fl.t0
+        self.metrics.record_decode(n, dt, late)
+        # roofline gauges: one global read disarmed; when a costs
+        # session is armed, the step's flops/bytes (XLA or analytic)
+        # land in the MFU/bandwidth reservoirs
+        if _costs._BOOK is not None:
+            self._record_step_cost(dt)
+        # decode-step inter-arrival: the latency co-resident requests
+        # actually SEE between their tokens — inline prefill inflates
+        # it, disaggregated prefill doesn't
+        if self._last_step_done is not None:
+            self.metrics.record_step_gap(now - self._last_step_done)
+        self._last_step_done = now
+        return n, late
+
+    def _land(self):
+        """Read what is in flight and deliver it, out of turn: before a
+        preemption, before a step in series, at a drain. False when the
+        read failed (every request evicted, the pool rebuilt)."""
+        fl = self._flight
+        try:
+            self._read(fl)
+        except Exception as e:
+            self.metrics.record_error("decode_step", e)
+            self._fail_active(e)
+            return False
+        self._flight = None
+        self._deliver_flight(fl)
+        return True
 
     def _fail_active(self, exc):
-        """Decode-step failure that survived retries: every in-flight
-        request is poisoned (the batched step is all-or-nothing), so
-        evict them all with their partial tokens + the cause, rebuild
-        the pool state, and keep serving — the pool itself survives."""
+        """Decode-step failure that survived retries, or that surfaced
+        where a step's tokens were read: every in-flight request is
+        poisoned (the batched step is all-or-nothing, and a step
+        enqueued behind a failed one ran on its state), so evict them
+        all with their partial tokens + the cause, rebuild the pool
+        state, and keep serving — the pool itself survives."""
         now = self.clock()
+        doomed = self.running()
+        self._flight = None
         for s, r in enumerate(self.slots):
-            if r is None:
-                continue
-            self.slots[s] = None
-            self._evict(s)
+            if r is not None:
+                self._vacate(s)
+        for r in doomed:
             self.metrics.record_finish("error", len(r.tokens))
             self.metrics.record_eviction_on_error()
             r.finish("error", now, error=exc)
@@ -580,10 +698,30 @@ class _EngineBase:
     def occupancy(self):
         return sum(r is not None for r in self.slots)
 
-    def _finish_slot(self, s, reason, now):
-        r = self.slots[s]
+    def idle(self):
+        """No request in a slot and no step in flight: with an empty
+        queue there is nothing left to do."""
+        return self._flight is None and self.occupancy() == 0
+
+    def running(self):
+        """Every request the engine holds: in a slot, or out of it with
+        its last token still in flight."""
+        out = [r for r in self.slots if r is not None]
+        if self._flight is not None:
+            out += [r for _, r in self._flight.pairs
+                    if r.slot is None and r.state == "RUNNING"]
+        return out
+
+    def _vacate(self, s):
         self.slots[s] = None
         self._evict(s)
+
+    def _finish_slot(self, s, reason, now):
+        r = self.slots[s]
+        self._vacate(s)
+        self._finish_request(r, reason, now)
+
+    def _finish_request(self, r, reason, now):
         self.metrics.record_finish(reason, len(r.tokens))
         if reason in ("eos", "length"):
             # slo is an SLOClass once a ShapingScheduler admitted the
@@ -609,6 +747,12 @@ class _EngineBase:
         if self._apool is None:
             return None
         return getattr(r, "adapter", None) or "base"
+
+    @staticmethod
+    def _owed(r):
+        """Tokens `r` is still to be handed before it ends by count
+        (a resumed request first re-absorbs what its caller holds)."""
+        return r.max_new_tokens - len(r.tokens) + getattr(r, "_replay", 0)
 
     def _deliver(self, r, tok, now):
         if r.state == "DONE":
@@ -639,36 +783,75 @@ class _EngineBase:
                 # but the failure is recorded, never swallowed
                 self.metrics.record_error("stream_cb", e)
         if r.eos_id is not None and tok == r.eos_id:
-            self._finish_slot(r.slot, "eos", now)
+            reason = "eos"
         elif len(r.tokens) >= r.max_new_tokens:
-            self._finish_slot(r.slot, "length", now)
+            reason = "length"
+        else:
+            return
+        if r.slot is not None:    # None: it gave the slot up a step ago
+            self._vacate(r.slot)
+        self._finish_request(r, reason, now)
 
     # ---- the continuous-batching iteration ----
     def run_iteration(self, scheduler):
         """One iteration: harvest faults, admit new work, decode one
         token for every active slot. Returns True when any work was
-        done (False = idle: empty queue, empty pool). Under a tracer
-        session the iteration's phases are engine-track spans
-        (`serving/tracing.py` `IterationTrace`)."""
+        done (False = idle: empty queue, empty pool). What it computed
+        is delivered when it returns. Under a tracer session the
+        iteration's phases are engine-track spans (`serving/tracing.py`
+        `IterationTrace`)."""
+        return self._run(scheduler, False)
+
+    def run_ahead(self, scheduler):
+        """`run_iteration` for a loop that owns the NEXT iteration too
+        (`ServingServer._loop`, `serve_until_idle`): where the stepper
+        keeps the next step's inputs on the device, the step is
+        enqueued and its tokens are left unread; the next call enqueues
+        ITS step first and only then reads and delivers these, so the
+        host delivers, harvests and admits while the device computes. A
+        call that finds nothing to enqueue reads what is left: nothing
+        stays in flight past an idle call, and `abort_active` lands it
+        too."""
+        return self._run(scheduler, True)
+
+    def _run(self, scheduler, keep):
         tr = _trace._SESSION
         if tr is None:
-            return self._iterate(scheduler, None)
+            return self._iterate(scheduler, None, {}, keep)
         it = self._iter_trace = _rt.IterationTrace(tr)
         progress, attrs = False, {}
         try:
-            progress = self._iterate(scheduler, it, attrs)
+            progress = self._iterate(scheduler, it, attrs, keep)
             return progress
         finally:
             self._iter_trace = None
             it.close(progress, **attrs)
 
-    def _iterate(self, scheduler, it, it_attrs=None):
+    def _series_reason(self):
+        """Why the next decode step cannot be enqueued before the last
+        one's tokens are read; None where it can. Read after admission
+        from what the engine holds. Default: the step computes from
+        host values (`ArtifactServingEngine`)."""
+        return "host"
+
+    def _iterate(self, scheduler, it, it_attrs, keep):
         now = self.clock()
         progress = False
+        had_flight = self._flight is not None
         if it is not None:
             sp = it.begin("iter.harvest")
+        if had_flight:
+            # a request whose LAST token is in flight (it ends by
+            # count) gives its slot up now, so this round can admit
+            # into it; the token still reaches it, by `pairs`
+            for s, r in self._flight.pairs:
+                if self.slots[s] is r and self._owed(r) <= 1:
+                    self._vacate(s)
+                    r.slot = None
+            progress = True
         # 1. fault harvest: cancellation + deadline eviction happen at
-        # iteration boundaries — partial tokens are delivered
+        # iteration boundaries — partial tokens are delivered; a token
+        # in flight for such a request is dropped where it is read
         for s, r in enumerate(self.slots):
             if r is None:
                 continue
@@ -736,8 +919,7 @@ class _EngineBase:
                 # per-request isolation: the failed join kills THIS
                 # request's future (or degrades it to the eager path),
                 # frees the slot, and the pool keeps serving
-                self.slots[s] = None
-                self._evict(s)
+                self._vacate(s)
                 r.slot = None
                 if r._trace is not None:
                     _rt.on_join_end(r, ok=False, error=e)
@@ -766,24 +948,26 @@ class _EngineBase:
             self.metrics.record_join()
             self._cbs.emit("on_join", r, s)
             if tok is not None:   # prefill already produced token 0
-                if self.sync_tok0:
-                    self._deliver(r, int(tok), self.clock())
-                else:
-                    tok0s.append((r, tok))
-        # resolve the admission round's first tokens AFTER the last
-        # join dispatched: the traced scalars sync here (one natural
-        # host sync instead of a blocking int() per join). A request
-        # finishing at token 0 frees its slot an iteration late — the
-        # decode step's active mask already excludes DONE slots.
+                tok0s.append((r, tok))
         if it is not None:
             it.end(sp, joins=joins)
-            sp = it.begin("iter.tok0", n=len(tok0s))
-        for r, tok in tok0s:
-            self._deliver(r, int(tok), self.clock())
+        # 3. the decode step: ahead of the last one's tokens where the
+        # stepper and the slots allow it and the caller owns the next
+        # iteration, else in series. `why` is what a step that finds
+        # nothing unread will say of itself: a preemption landed the
+        # flight, the series reason, or the pool stood empty / the
+        # caller steps by hand ("idle").
+        reason = self._series_reason()
+        series = reason is not None or not keep
+        why = ("preempt" if had_flight and self._flight is None
+               else reason or "idle")
+        if series:
+            if self._flight is not None:
+                self._land()
+            self._resolve_tok0(tok0s, it)
         if it is not None:
-            it.end(sp)
             sp = it.begin("iter.chunks")
-        # 2b. chunked prefill: one chunk per mid-prefill slot, BEFORE
+        # 3a. chunked prefill: one chunk per mid-prefill slot, BEFORE
         # the decode step — a freshly chunk-joined slot's first chunk
         # must set the pool index past its pad hole before any masked
         # decode-step write can land inside the prompt region
@@ -791,71 +975,52 @@ class _EngineBase:
             progress = True
         if it is not None:
             it.end(sp)
-        # 3. one batched decode step over the active mask (slots with a
-        # disaggregated prefill still in flight stay masked out)
+        # slots with a disaggregated prefill still in flight stay
+        # masked out; so does a joiner whose only token is the unread
+        # token 0 (in series it is delivered by now and the slot free)
+        last = {id(r) for r, _ in tok0s if self._owed(r) <= 1}
         active = np.asarray(
             [r is not None and s not in self._pending
+             and id(r) not in last
              for s, r in enumerate(self.slots)], bool)
-        if active.any():
-            t0 = self.clock()
+        stepping = active.any()
+        if stepping or self._flight is not None:
+            progress = True
+            issued = read = error = None
             if it is not None:
                 it.begin_step()
             try:
-                toks = self._guarded(
-                    "decode_step", lambda: self._decode_attempt(active),
-                    retry_tokens=int(active.sum()))
+                if stepping:
+                    issued, why = self._issue(active, series, why)
+                if self._flight is not None:
+                    self._read(self._flight)    # the LAST step's tokens
+                    read, self._flight = self._flight, None
             except Exception as e:
-                if it is not None:
-                    it.end_step(self, active, scheduler,
-                                error=type(e).__name__)
+                error, issued = e, None
                 self.metrics.record_error("decode_step", e)
                 self._fail_active(e)
-                progress = True
-            else:
-                now2 = self.clock()
+            if issued is not None:
+                self.metrics.record_step(why)
+                it_attrs["step"] = why or "ahead"
+                if issued.unread():
+                    self._flight = issued
+                else:             # a step in series: read already
+                    read = issued
+            if it is not None:
+                it.end_step(issued.pairs if issued is not None else (),
+                            self.occupancy(), scheduler.depth(),
+                            **({} if error is None else
+                               {"error": type(error).__name__}))
+            if read is not None:
                 if it is not None:
-                    it.end_step(self, active, scheduler)
                     sp = it.begin("iter.deliver")
-                n = 0
-                if isinstance(toks, tuple):
-                    # speculative step: (emit [S, k], n_emit [S]) —
-                    # up to k tokens per slot per iteration; delivery
-                    # stops the moment the slot finishes (eos /
-                    # max_new_tokens), dropping the over-speculated
-                    # tail exactly like the eager oracle would
-                    emit, n_emit = toks
-                    for s, r in enumerate(list(self.slots)):
-                        if r is None or not active[s]:
-                            continue
-                        for j in range(int(n_emit[s])):
-                            if self.slots[s] is not r or \
-                                    r.state == "DONE":
-                                break
-                            self._deliver(r, int(emit[s, j]), now2)
-                            n += 1
-                else:
-                    for s, r in enumerate(list(self.slots)):
-                        if r is not None and active[s]:
-                            self._deliver(r, int(toks[s]), now2)
-                            n += 1
-                self.metrics.record_decode(n, now2 - t0)
-                # roofline gauges: one global read disarmed; when a
-                # costs session is armed, the step's flops/bytes (XLA
-                # or analytic) land in the MFU/bandwidth reservoirs
-                if _costs._BOOK is not None:
-                    self._record_step_cost(now2 - t0)
-                # decode-step inter-arrival: the latency co-resident
-                # requests actually SEE between their tokens — inline
-                # prefill inflates it, disaggregated prefill doesn't
-                if self._last_step_done is not None:
-                    self.metrics.record_step_gap(
-                        now2 - self._last_step_done)
-                self._last_step_done = now2
-                progress = True
+                n, it_attrs["late_slot_steps"] = self._deliver_flight(read)
                 if it is not None:
                     it.end(sp, tokens=n)
         else:
             self._last_step_done = None
+        if not series:
+            self._resolve_tok0(tok0s, it)
         if it is not None:
             sp = it.begin("iter.account")
         # computed ONCE an iteration: it has a side effect (the memory
@@ -875,13 +1040,57 @@ class _EngineBase:
                             queue_depth=depth, gauges=gauges)
         return progress
 
+    def _issue(self, active, series, why):
+        """Enqueue the decode step over `active`: (its `_Flight`, why
+        it found nothing unread — None where it went ahead of the last
+        step's tokens). In series the flight comes back read. An
+        attempt ahead that fails is the last one ahead: what is in
+        flight lands, and the retries run in series. (The watchdog
+        times whole steps, so steps in series only: ahead, the time
+        from enqueue to tokens holds the next iteration's host work.)"""
+        n = int(active.sum())
+        if series:
+            return self._guarded(
+                "decode_step", lambda: self._series_attempt(active),
+                retry_tokens=n), why
+        try:
+            return (self._step_attempt(active),
+                    None if self._flight is not None else why)
+        except Exception as e:
+            failed = e
+        if self._flight is not None:
+            self._land()
+        # the landing may have ended requests (eos; the pool rebuilt)
+        active = active & np.asarray([r is not None for r in self.slots])
+        if not active.any():
+            raise failed
+        return self._guarded(
+            "decode_step", lambda: self._series_attempt(active),
+            retry_tokens=n, failed=failed), "retry"
+
+    def _resolve_tok0(self, tok0s, it):
+        """Deliver the admission round's first tokens AFTER the last
+        join dispatched: the traced scalars sync here (one natural host
+        sync instead of a blocking int() per join) — in series before
+        the step, otherwise after it is enqueued. A request finishing
+        at token 0 by `eos_id` is then found a step late."""
+        if it is not None:
+            sp = it.begin("iter.tok0", n=len(tok0s))
+        for r, tok in tok0s:
+            if r.state != "DONE":     # evicted with a failed step
+                self._deliver(r, int(tok), self.clock())
+        del tok0s[:]
+        if it is not None:
+            it.end(sp)
+
     def serve_until_idle(self, scheduler, max_iterations=None):
-        """Synchronous drive: iterate until queue and pool are empty.
-        The offline path (Predictor.generate, benches, tests) — online
-        serving wraps run_iteration in a ServingServer thread."""
+        """Synchronous drive: iterate until queue and pool are empty
+        and nothing is in flight. The offline path (Predictor.generate,
+        benches, tests) — online serving wraps run_ahead in a
+        ServingServer thread."""
         it = 0
-        while scheduler.depth() > 0 or self.occupancy() > 0:
-            self.run_iteration(scheduler)
+        while scheduler.depth() > 0 or not self.idle():
+            self.run_ahead(scheduler)
             it += 1
             if max_iterations is not None and it >= max_iterations:
                 raise RuntimeError(
@@ -890,7 +1099,9 @@ class _EngineBase:
 
     def abort_active(self, reason, now=None):
         """Finalize every in-flight request (non-drain shutdown);
-        partial tokens are delivered."""
+        partial tokens are delivered, a step in flight included."""
+        if self._flight is not None:
+            self._land()
         if now is None:
             now = self.clock()
         for s, r in enumerate(self.slots):
@@ -1444,8 +1655,7 @@ class ServingEngine(_EngineBase):
                 # per-request isolation, mirroring the join failure
                 # path: the failed chunk kills THIS request's future
                 # and frees the slot; the pool keeps serving
-                self.slots[s] = None
-                self._evict(s)
+                self._vacate(s)
                 r.slot = None
                 self.metrics.record_error("prefill_chunk", e)
                 r.fail(e, self.clock())
@@ -1585,6 +1795,15 @@ class ServingEngine(_EngineBase):
         # batched step, or the draft + k-token-verify pair with the
         # adaptive effective-k controller
         return self.stepper.decode(active)
+
+    def _series_reason(self):
+        if not self.stepper.ahead:
+            return "spec"
+        if self._chunking:
+            return "chunk"
+        if self._pending:
+            return "pending"
+        return None
 
     def _build_step(self, key):
         return self.placement.build(key, self.layout.step_body(key),
@@ -2424,8 +2643,7 @@ class PagedServingEngine(ServingEngine):
                             int(r.tokens[0]))
         if r._trace is not None:
             _rt.on_preempt(r, s, len(r.tokens))
-        self.slots[s] = None
-        self._evict(s)
+        self._vacate(s)
         r.slot = None
         r.state = "QUEUED"
         # greedy decode is deterministic, so the resumed slot re-emits
@@ -2464,8 +2682,7 @@ class PagedServingEngine(ServingEngine):
     # steppers drive (layers.py) ----
     def _evict_oom(self, s, exc, now):
         r = self.slots[s]
-        self.slots[s] = None
-        self._evict(s)
+        self._vacate(s)
         self.metrics.record_oom_eviction()
         self.metrics.record_error("out_of_pages", exc)
         self.metrics.record_finish("error", len(r.tokens))
@@ -2627,4 +2844,4 @@ class ArtifactServingEngine(_EngineBase):
                 t = int(logits[s, n - 1].argmax(-1))
                 self._rows[s].append(t)
                 toks[s] = t
-        return toks
+        return toks, active

@@ -97,10 +97,10 @@ class CacheLayout:
 
     # ---- host hooks the steppers drive ----
     def map_step_pages(self, active, width):
-        """Make the next `width` write positions of every occupied
-        slot physically backed (paged: map pages, evicting a starved
-        slot under oversubscription). Returns the possibly-updated
-        active mask."""
+        """Make the next `width` write positions of every slot in
+        `active` physically backed (paged: map pages, evicting a
+        starved slot under oversubscription). Returns the
+        possibly-updated active mask."""
         return active
 
     def step_extra_args(self):
@@ -489,13 +489,13 @@ class PagedLayout(CacheLayout):
         # partial tokens (the pool itself keeps serving). Speculative
         # steps write the FULL fixed-k block (force-rejected tail
         # included), so every page the block touches must be mapped.
-        # Pending slots (mid chunked-prefill) are skipped: their index
-        # sits mid-PROMPT, the pages there are the chunk programs' to
-        # map, and a dry pool must never OOM-evict a half-prefilled
-        # slot on a decode step it does not even participate in.
-        for s, r in enumerate(list(eng.slots)):
-            if r is None or s in eng._pending:
-                continue
+        # Pending slots (mid chunked-prefill) are not in `active`:
+        # their index sits mid-PROMPT, the pages there are the chunk
+        # programs' to map, and a dry pool must never OOM-evict a
+        # half-prefilled slot on a decode step it does not even
+        # participate in.
+        active = active.copy()
+        for s in np.flatnonzero(active):
             i0 = int(eng._index[s])
             for pi in range(i0 // psz, (i0 + width - 1) // psz + 1):
                 if eng._table[s, pi] < 0:
@@ -503,10 +503,9 @@ class PagedLayout(CacheLayout):
                         eng._table[s, pi] = eng._alloc_pages(1)[0]
                     except OutOfPages as e:
                         eng._evict_oom(s, e, now)
+                        active[s] = False
                         break
-        return np.asarray(
-            [r is not None and s not in eng._pending
-             for s, r in enumerate(eng.slots)], bool)
+        return active
 
     def step_extra_args(self):
         import jax.numpy as jnp
@@ -1122,6 +1121,14 @@ class PlainStepper:
     """One token per slot per iteration: ONE batched program dispatch
     over the active mask."""
 
+    #: the next step's inputs are known without this step's tokens: the
+    #: token it consumes stays on the device (`state["tok"]`), every
+    #: active slot's write index advances by exactly one, and the page
+    #: it writes is mapped by the host from those indices. So `decode`
+    #: leaves its tokens unread and the engine may enqueue the next
+    #: step before it reads them.
+    ahead = True
+
     def __init__(self, eng):
         self.eng = eng
 
@@ -1137,7 +1144,7 @@ class PlainStepper:
         if it is not None:
             it.end(sp)
         if not active.any():
-            return np.zeros((eng.num_slots,), np.int64)
+            return np.zeros((eng.num_slots,), np.int64), active
         if it is not None:
             sp = it.begin("step.enqueue")
         key = lay.step_key()
@@ -1149,11 +1156,7 @@ class PlainStepper:
         lay.advance_rows(active.astype(np.int64))
         if it is not None:
             it.end(sp)
-            sp = it.begin("step.readback")
-        toks = np.asarray(toks)
-        if it is not None:
-            it.end(sp)
-        return toks
+        return toks, active
 
 
 class SpecStepper:
@@ -1164,6 +1167,10 @@ class SpecStepper:
     force-rejected in-program), so shrinking or regrowing k NEVER
     retraces; the retrace-sentinel soaks hold this with adaptation
     exercised."""
+
+    #: the next write indices depend on this step's `n_emit`, and the
+    #: draft is read before the verify is enqueued: always in series
+    ahead = False
 
     def __init__(self, eng):
         self.eng = eng
@@ -1223,7 +1230,8 @@ class SpecStepper:
             it.end(sp)
         if not active.any():
             S, k = eng.num_slots, eng.spec_k
-            return (np.zeros((S, k), np.int64), np.zeros((S,), np.int64))
+            return (np.zeros((S, k), np.int64),
+                    np.zeros((S,), np.int64)), active
         spec_on = np.asarray(
             [r is not None and getattr(r, "spec", True)
              for r in eng.slots], bool)
@@ -1273,4 +1281,4 @@ class SpecStepper:
             k_grows=self.k_grow_events)
         if it is not None:
             it.end(sp_verify, accepted=accepted)
-        return emit, n_emit
+        return (emit, n_emit), active

@@ -53,6 +53,22 @@ SNAPSHOT_DOCS = {
     "queue_depth": ("summary", "scheduler depth sampled per iteration"),
     "slot_occupancy": ("summary",
                        "occupied-slot fraction sampled per iteration"),
+    # the decode pipeline (PR 32): one step in flight where the loop
+    # owns consecutive iterations
+    "pipeline.decode_steps": ("counter", "batched decode steps enqueued"),
+    "pipeline.steps_ahead": (
+        "counter", "decode steps enqueued while the previous step's "
+                   "tokens were still unread: their host work hid "
+                   "behind the device"),
+    "pipeline.depth": ("gauge", "steps that may be in flight unread: 1"),
+    "pipeline.series_steps": (
+        "info", "steps that found nothing unread, by reason: idle "
+                "(the pool stood empty, or run_iteration by hand), "
+                "spec, chunk, pending, preempt, retry, host"),
+    "pipeline.late_slot_steps": (
+        "counter", "slot-steps whose token was dropped where it was "
+                   "read: the request had ended (eos found a step "
+                   "late, cancelled, past its deadline)"),
     # sharded pools (PR 7) — the section appears once any of these
     # record
     "sharding.prefill_step_ms": (
@@ -264,7 +280,7 @@ SNAPSHOT_DOCS = {
 }
 
 _SUMMARY_KEYS = {"n", "mean", "p50", "p99", "max"}
-_LEAF_DICTS = {"errors.last", "mfu.device",
+_LEAF_DICTS = {"errors.last", "mfu.device", "pipeline.series_steps",
                "speculation.step_ms_by_variant",
                "tenancy.active_slots_by_tenant",
                "tenancy.tokens_by_tenant",
@@ -418,6 +434,10 @@ class ServingMetrics:
         #                             prefill-produced first token)
         self.decode_tokens = 0      # tokens out of batched decode steps
         self.decode_time_s = 0.0
+        self.decode_steps = 0       # batched decode steps enqueued
+        self.steps_ahead = 0        # ... before the last one was read
+        self.series_steps = {}      # the others, by reason
+        self.late_slot_steps = 0    # tokens read for an ended request
         self.ttft_s = _Reservoir()
         self.token_latency_s = _Reservoir()
         self.queue_depth = _Reservoir(512)
@@ -578,14 +598,27 @@ class ServingMetrics:
                 self.tokens_by_tenant[tenant] = \
                     self.tokens_by_tenant.get(tenant, 0) + 1
 
-    def record_decode(self, n_tokens, dt_s):
-        """One engine iteration produced `n_tokens` across the active
-        slots in `dt_s` seconds of decode wall time."""
+    def record_decode(self, n_tokens, dt_s, late=0):
+        """One decode step delivered `n_tokens` across its slots,
+        `dt_s` seconds from its enqueue to its tokens read; `late` of
+        its tokens were dropped (their request had ended)."""
         with self._lock:
             self.decode_tokens += n_tokens
             self.decode_time_s += dt_s
+            self.late_slot_steps += late
             if n_tokens:
                 self.token_latency_s.add(dt_s)
+
+    def record_step(self, why):
+        """A decode step was enqueued: ahead of the last one's tokens
+        (`why` None), or with nothing unread, for the reason `why`."""
+        with self._lock:
+            self.decode_steps += 1
+            if why is None:
+                self.steps_ahead += 1
+            else:
+                self.series_steps[why] = \
+                    self.series_steps.get(why, 0) + 1
 
     def record_finish(self, reason, n_tokens=0):
         """Request finished with `reason`; `n_tokens` (the tokens it
@@ -1001,6 +1034,13 @@ class ServingMetrics:
                 "per_token_ms": self.token_latency_s.summary(scale=1e3),
                 "queue_depth": self.queue_depth.summary(digits=2),
                 "slot_occupancy": self.occupancy.summary(digits=3),
+                "pipeline": {
+                    "decode_steps": self.decode_steps,
+                    "steps_ahead": self.steps_ahead,
+                    "depth": 1,
+                    "series_steps": dict(self.series_steps),
+                    "late_slot_steps": self.late_slot_steps,
+                },
                 "goodput": {
                     "useful_tokens": self.useful_tokens,
                     "wasted_tokens": self.wasted_tokens,

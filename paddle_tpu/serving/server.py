@@ -111,15 +111,16 @@ class ServingServer:
 
     # ------------------------------------------------------------------
     def _idle(self):
-        return (self.scheduler.depth() == 0 and
-                self.engine.occupancy() == 0)
+        return self.scheduler.depth() == 0 and self.engine.idle()
 
     def _loop(self):
         try:
             while True:
                 if self._stop.is_set():
                     break
-                progress = self.engine.run_iteration(self.scheduler)
+                # this loop owns the next iteration too: the engine
+                # may leave a decode step's tokens unread until then
+                progress = self.engine.run_ahead(self.scheduler)
                 if self.scheduler.draining and self._idle():
                     break   # graceful drain complete
                 if not progress:
@@ -151,8 +152,7 @@ class ServingServer:
         exc.__cause__ = cause if isinstance(cause, BaseException) \
             else None
         now = self.clock()
-        doomed = self.scheduler.pop_all() + \
-            [r for r in self.engine.slots if r is not None]
+        doomed = self.scheduler.pop_all() + self.engine.running()
         for r in doomed:
             r.fail(exc, now)   # idempotent vs a racing finish()
             self.engine.metrics.record_finish("error", len(r.tokens))

@@ -479,8 +479,7 @@ class ShardedServingEngine(ServingEngine):
     def _fail_pending_splice(self, s, r, e):
         """Per-request isolation: the failed splice kills THIS
         request's future, frees the slot, pool keeps serving."""
-        self.slots[s] = None
-        self._evict(s)
+        self._vacate(s)
         r.slot = None
         if r._trace is not None:
             _rt.on_splice_end(r, ok=False, error=e)
@@ -527,7 +526,7 @@ class ShardedServingEngine(ServingEngine):
                 # all-or-nothing recovery rebuilds the pool
                 self._fail_active(e)
             return False
-        if deferred is None or self.sync_tok0:
+        if deferred is None:
             self._finish_splice(s, r, int(tok0))
         else:
             deferred.append((s, r, tok0))

@@ -46,28 +46,39 @@ Span catalog (exported Chrome-trace names):
                   frontier — and done on the final chunk)
   preempt         instant: the slot was evicted to the prefix cache to
                   free capacity (attrs: slot, tokens so far)
-  iteration       engine track root: one run_iteration that did work
-                  (an idle spin is dropped from the tracer's record;
+  iteration       engine track root: one run_iteration / run_ahead
+                  that did work (an idle spin is dropped from the tracer's record;
                   a jax.profiler trace running at the time has
                   already seen its annotations, so a reader of the
                   profiler's events leaves out an `iteration` that
                   holds no decode.step and no join; attrs: joins, n_active,
                   occupancy, queue_depth, the page-pool and shard
-                  gauges, t0_perf_ns)
+                  gauges, t0_perf_ns; step — "ahead" where the
+                  iteration's step was enqueued before the last one's
+                  tokens were read, else why not: idle, spec, chunk,
+                  pending, preempt, retry, host —; late_slot_steps)
   iter.harvest    cancellation / deadline sweep and the poll of
                   disaggregated prefills
   iter.admit      the admission loop; the admitted requests' join
                   spans are its children (attrs: joins)
   iter.tok0       resolving the round's first tokens: the host's wait
-                  for the join programs (attrs: n)
+                  for the join programs (attrs: n); before decode.step
+                  in series, after it where the step went ahead
   iter.chunks     one chunk for every slot mid chunked-prefill
-  decode.step     one batched decode step, all attempts (attrs:
-                  n_active, slots, occupancy, queue depth, page-pool
+  decode.step     the iteration's decode phase: its batched step
+                  enqueued, all attempts, and a step's tokens read —
+                  this one's in series, the LAST iteration's where
+                  steps go ahead (attrs: n_active, slots — of the step
+                  enqueued here —, occupancy, queue depth, page-pool
                   and shard gauges)
   step.map_pages  under decode.step: mapping the pages the step writes
   step.enqueue    under decode.step: building the arguments and
                   calling the compiled program
-  step.readback   under decode.step: the host's wait for the tokens
+  step.readback   the host's wait for a step's tokens: under
+                  decode.step after step.enqueue, where it waits for
+                  the step enqueued an iteration EARLIER when steps go
+                  ahead; under iter.admit or iteration where a flight
+                  lands out of turn (preemption, a series reason)
   decode.draft    under decode.step: a speculative draft proposal
                   dispatch, to its result (attrs: n_active, proposed)
   decode.verify   under decode.step: the k-token verify dispatch and
@@ -128,12 +139,15 @@ SPAN_CATALOG = (
                   "(the wait for the join programs)"),
     ("iter.chunks", "engine track: one chunk per slot mid "
                     "chunked-prefill"),
-    ("decode.step", "engine track: one batched decode step"),
+    ("decode.step", "engine track: the batched decode step enqueued "
+                    "and a step's tokens read"),
     ("step.map_pages", "under decode.step: mapping the pages the "
                        "step writes"),
     ("step.enqueue", "under decode.step: arguments built, program "
                      "called"),
-    ("step.readback", "under decode.step: the wait for the tokens"),
+    ("step.readback", "the wait for a step's tokens: this "
+                      "iteration's in series, the last one's where "
+                      "steps go ahead"),
     ("decode.draft", "under decode.step: speculative draft proposal"),
     ("decode.verify", "under decode.step: k-token speculative "
                       "verify"),
@@ -373,21 +387,21 @@ class IterationTrace:
         self.step = self.begin("decode.step")
         return self.step
 
-    def end_step(self, engine, active, scheduler, **attrs):
-        """Close `decode.step` with the co-resident requests' trace ids
-        in ``slots`` — every decode step a request co-resides in is
+    def end_step(self, pairs, occupancy, queue_depth, **attrs):
+        """Close `decode.step` with the trace ids of the requests the
+        step enqueued under it was issued for (`pairs`: (slot, request),
+        empty where the span only read an earlier step's tokens) in
+        ``slots`` — every decode step a request co-resides in is
         recoverable from the trace."""
         tids = []
-        for s, r in enumerate(engine.slots):
-            if r is not None and active[s]:
-                tids.append(r.id)
-                rt = r._trace
-                if rt is not None:
-                    _begin_decode(rt)
-                    rt.steps += 1
+        for _, r in pairs:
+            tids.append(r.id)
+            rt = r._trace
+            if rt is not None:
+                _begin_decode(rt)
+                rt.steps += 1
         self.end(self.step, n_active=len(tids), slots=tids,
-                 occupancy=engine.occupancy(),
-                 queue_depth=scheduler.depth(), **attrs)
+                 occupancy=occupancy, queue_depth=queue_depth, **attrs)
 
     def close(self, progress, gauges=None, **attrs):
         """End the iteration: `attrs` (joins, occupancy, queue depth)

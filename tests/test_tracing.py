@@ -693,7 +693,9 @@ def _profiled(tmp_path, fn):
 def test_engine_spans_reach_the_profiler_nested_and_linked(tmp_path):
     """One served request under a session AND a jax.profiler trace: the
     engine-track spans and the request's join are host-plane events of
-    the same names, nested in program order, each with its span_id;
+    the same names, nested in program order (a step's tokens are read
+    in the iteration AFTER the one that enqueued it), each with its
+    span_id;
     `iteration` carries the tracer clock at its start."""
     dec, embed, proj, D, V = _small_stack(seed=191)
     eng = ServingEngine(dec, embed, proj, num_slots=2, max_len=32)
@@ -714,28 +716,43 @@ def test_engine_spans_reach_the_profiler_nested_and_linked(tmp_path):
     tr = serve.tracer
     by_id = {s.span_id: s for s in tr.spans()}
     mine = [e for e in events if e[0] in _ENGINE_CHAIN]
-    # the first iteration admitted the request and decoded once
-    first = min((e for e in mine if e[0] == "iteration"),
-                key=lambda e: e[1])
-    inside = sorted((e for e in mine if first[1] <= e[1]
-                     and e[2] <= first[2]), key=lambda e: (e[1], -e[2]))
-    assert [e[0] for e in inside] == list(_ENGINE_CHAIN)
+    its = sorted((e for e in mine if e[0] == "iteration"),
+                 key=lambda e: e[1])
+
+    def within(root):
+        return sorted((e for e in mine if root[1] <= e[1]
+                       and e[2] <= root[2]), key=lambda e: (e[1], -e[2]))
+
+    # `serve_until_idle` owns consecutive iterations, so the first one
+    # admits the request and ENQUEUES a step; the second enqueues its
+    # own step first and then reads and delivers the first one's token
+    first, inside = within(its[0]), within(its[1])
+    assert [e[0] for e in first] == [
+        "iteration", "iter.admit", "join", "decode.step", "step.enqueue"]
+    assert [e[0] for e in inside] == [
+        "iteration", "iter.admit", "decode.step", "step.enqueue",
+        "step.readback", "iter.deliver"]
     ev = {e[0]: e for e in inside}
+    ev["join"] = first[2]
     for parent, child in (("iteration", "iter.admit"),
-                          ("iter.admit", "join"),
                           ("iteration", "decode.step"),
                           ("decode.step", "step.enqueue"),
                           ("decode.step", "step.readback"),
                           ("iteration", "iter.deliver")):
         assert ev[parent][1] <= ev[child][1] and \
             ev[child][2] <= ev[parent][2], (parent, child)
+    assert first[1][1] <= first[2][1] and first[2][2] <= first[1][2]
     assert ev["step.enqueue"][2] <= ev["step.readback"][1]
     assert ev["decode.step"][2] <= ev["iter.deliver"][1]
+    inside = first + inside
     for name, _, _, stats in inside:
         sp = by_id[stats["span_id"]]          # the SAME span, by id
         assert sp.name == name
     assert ev["join"][3]["trace_id"] == r.id
-    assert ev["iteration"][3]["joins"] == 1
+    assert first[0][3]["joins"] == 1
+    # (strings stay on the tracer's span: the profiler takes numbers)
+    assert by_id[first[0][3]["span_id"]].attrs["step"] == "idle"
+    assert by_id[ev["iteration"][3]["span_id"]].attrs["step"] == "ahead"
     assert ev["iter.deliver"][3]["tokens"] == 1
     # the clock pair: the annotation starts where the tracer's span does
     root = by_id[ev["iteration"][3]["span_id"]]
