@@ -17,7 +17,9 @@ from .layer.common import (  # noqa: F401
     Softsign, Swish, SyncBatchNorm, Tanh, Tanhshrink, Unfold, Upsample,
     UpsamplingBilinear2D, UpsamplingNearest2D)
 from .layer.moe import MoELayer, SparseMoELayer  # noqa: F401
-from .layer.ssm import Mamba2Mixer  # noqa: F401
+from .layer.ssm import Mamba1Mixer, Mamba2Mixer  # noqa: F401
+from .layer.diff_attention import (  # noqa: F401
+    DifferentialAttention, GatedMemoryUnit)
 from .layer.transformer import (  # noqa: F401
     GroupedQueryAttention, MultiHeadAttention, Transformer, TransformerDecoder,
     TransformerDecoderLayer, TransformerEncoder, TransformerEncoderLayer)

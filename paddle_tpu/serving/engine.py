@@ -1130,7 +1130,7 @@ class ServingEngine(_EngineBase):
             return object.__new__(PagedServingEngine)
         return object.__new__(cls)
 
-    def __init__(self, decoder, embed, project, *, num_slots=8,
+    def __init__(self, decoder, embed=None, project=None, *, num_slots=8,
                  max_len=128, max_joins_per_iter=2, metrics=None,
                  callbacks=(), clock=time.monotonic,
                  eager_fallback=False, paged=False, spec_k=None,
@@ -1143,9 +1143,28 @@ class ServingEngine(_EngineBase):
                          **kw)
         from ..parallel.functional import functionalize
         from ..text.generation import _StepNet
-        from .layers import (DenseLayout, PagedLayout, PlainStepper,
+        from .layers import (CausalLMDriver, DecoderStackDriver,
+                             DenseLayout, PagedLayout, PlainStepper,
                              SpecStepper)
 
+        # a decoder-only causal LM (it says what each block keeps of a
+        # sequence: `cache_kinds()`) is served whole, with no embed /
+        # project beside it and no `memory` on its requests; what its
+        # recurrent states and window rings rule out is refused here,
+        # by name, and never silently ignored
+        self.causal = hasattr(decoder, "cache_kinds")
+        if self.causal:
+            self._refuse_for_state(
+                decoder, embed, project,
+                paged=isinstance(self, PagedServingEngine), spec_k=spec_k,
+                adapters=adapters, quantize=quantize,
+                prefill_chunk=prefill_chunk,
+                eager_fallback=eager_fallback)
+        elif embed is None or project is None:
+            raise ValueError(
+                "ServingEngine(decoder, embed, project): a decoder stack "
+                "needs its embedding and its projection; only a causal "
+                "LM with cache_kinds() is served alone")
         # int8 base weights: quantize="int8" rewrites every large
         # dense weight of the stack (decoder projections + FFN, the
         # embedding vocab table, the logits projection) to symmetric
@@ -1230,8 +1249,16 @@ class ServingEngine(_EngineBase):
         self.placement = self._make_placement()
         self.stepper = (SpecStepper(self) if self.spec_k
                         else PlainStepper(self))
-        self._net = _StepNet(decoder, embed, project)
+        self._net = decoder if self.causal else \
+            _StepNet(decoder, embed, project)
         self._fm = functionalize(self._net)
+        #: what the layout and the engine ask of the served stack
+        #: (layers.py): its cache kinds, and what its requests carry
+        self.driver = (CausalLMDriver(self) if self.causal
+                       else DecoderStackDriver(self))
+        # this iteration's cache counts (`_iteration_gauges` hands them
+        # to the metrics and the `iteration` span, then clears them)
+        self._cache_iter = {}
         if not getattr(self, "_accepts_sharded_params", False):
             _reject_sharded_params(
                 self._fm.params(),
@@ -1249,6 +1276,56 @@ class ServingEngine(_EngineBase):
         # engine's weights + pool footprint (and the budget watermark
         # warns before the pool runs dry)
         self.metrics.set_memory_provider(self.memory_ledger)
+
+    @staticmethod
+    def _refuse_for_state(model, embed, project, *, paged, spec_k,
+                          adapters, quantize, prefill_chunk,
+                          eager_fallback):
+        """A stack with recurrent or ring state: every option that would
+        have to snapshot, roll back or replay such a state raises."""
+        name = type(model).__name__
+        stateful = {k for k in model.cache_kinds()
+                    if k in ("recurrent", "ring")}
+        if embed is not None or project is not None:
+            raise ValueError(
+                f"{name} is a whole causal LM: pass it alone, without "
+                f"embed / project")
+        if not paged:
+            raise ValueError(
+                f"{name} keeps full-attention K/V in pages: construct "
+                f"with paged=True (the dense pool has no ring or "
+                f"recurrent state)")
+        if not stateful:
+            return
+        what = f"{name} keeps {'/'.join(sorted(stateful))} state " \
+               f"a slot"
+        if spec_k is not None:
+            raise ValueError(
+                f"speculative decoding (spec_k={spec_k}): {what}, and a "
+                f"rejected draft is undone by moving a write index back, "
+                f"which no scan state or ring can follow")
+        if adapters is not None:
+            raise ValueError(
+                f"LoRA tenants (adapters=): {what}; the adapter scope "
+                f"rewrites nn.Linear projections, which these mixers do "
+                f"not go through, so a tenant's adapter would be ignored")
+        if quantize is not None:
+            raise ValueError(
+                f"int8 weights (quantize={quantize!r}): {what}; "
+                f"quantize_net rewrites the (decoder, embed, project) "
+                f"triple's nn.Linear weights and would leave this model "
+                f"as it is")
+        if prefill_chunk is not None:
+            raise ValueError(
+                f"chunked prefill (prefill_chunk={prefill_chunk}): "
+                f"{what}; a chunk resumes from K/V pages alone, and the "
+                f"scan state and rings of a half-read prompt are not "
+                f"carried from chunk to chunk")
+        if eager_fallback:
+            raise ValueError(
+                f"eager fallback (eager_fallback=True): {what}; the "
+                f"fallback runs generate_eager over a (decoder, embed, "
+                f"project) triple")
 
     # ------------------------------------------------------------------
     def _make_placement(self):
@@ -1453,6 +1530,8 @@ class ServingEngine(_EngineBase):
             else self.layout.spec_step_key()
 
     def cost_hint(self, key):
+        if self.causal:
+            return None   # the analytic formulas are the decoder stack's
         kind = key[0] if isinstance(key, tuple) and key else key
         n_params, n_layers, heads, hd, M = self._model_dims()
         pool = self.pool_bytes()
@@ -1523,25 +1602,17 @@ class ServingEngine(_EngineBase):
                 f"request needs bucket({P})={Pb} prompt slots + "
                 f"{r.max_new_tokens} decode slots > pool max_len "
                 f"{self.max_len}{self._max_len_detail()}")
-        if r.memory is None or r.memory.ndim != 2:
-            raise ValueError("ServingEngine requests need a 2-D "
-                             "cross-attention memory [M, D]")
-        if self._mem_shape is not None and \
-                tuple(r.memory.shape) != self._mem_shape:
-            raise ValueError(
-                f"memory shape {tuple(r.memory.shape)} != pool's "
-                f"{self._mem_shape} (fixed by the first join)")
+        self.driver.check_memory(r)
 
     def _ensure_state(self, memory):
         if self._state is not None:
             return
         from ..text.generation import NEG
 
-        memory = np.asarray(memory)
         self._neg = float(NEG)
+        memory, self._mem_shape, dtype = self.driver.pin_memory(memory)
+        self._np_dtype = np.dtype(str(dtype))
         self._state = self.layout.build_state(memory)
-        self._mem_shape = tuple(memory.shape)
-        self._np_dtype = np.dtype(str(self._state["mem"].dtype))
         self._pool_key = self.layout.pool_key(memory)
         self._post_state_build()
 
@@ -1826,7 +1897,7 @@ class ServingEngine(_EngineBase):
     # ------------------------------------------------------------------
     # zero-warmup startup: AOT precompile + persistent cache
     # ------------------------------------------------------------------
-    def precompile(self, memory, *, dtype="float32",
+    def precompile(self, memory=None, *, dtype="float32",
                    prompt_buckets=(8, 16, 32, 64), cache=None,
                    persist=True):
         """Ready EVERY serving program of this pool config before the
@@ -1841,12 +1912,7 @@ class ServingEngine(_EngineBase):
         the pool config exactly like the first join would, so
         admission semantics are unchanged. Returns the cold_start
         report (also recorded in `ServingMetrics.snapshot()`)."""
-        if hasattr(memory, "ndim") or isinstance(memory, np.ndarray):
-            mem = np.asarray(memory)
-        else:
-            M, Dm = memory
-            mem = np.zeros((int(M), int(Dm)), np.dtype(dtype))
-        self._ensure_state(mem)
+        self._ensure_state(self.driver.pin_memory(memory, dtype)[0])
         progs = self._startup_programs(prompt_buckets)
         return self._precompile_run(progs, cache, persist)
 
@@ -1978,9 +2044,9 @@ class PagedServingEngine(ServingEngine):
     (it is rounded up to one — a non-multiple would change the masked
     softmax width)."""
 
-    def __init__(self, decoder, embed, project, *, num_slots=8,
+    def __init__(self, decoder, embed=None, project=None, *, num_slots=8,
                  max_len=128, page_size=16, num_pages=None,
-                 kv_dtype=None, prefix_cache=True, prefix_capacity=64,
+                 kv_dtype=None, prefix_cache=None, prefix_capacity=64,
                  radix_mid_page="round_down",
                  reserve_decode_frac=1.0, paged=True, **kw):
         page_size = int(page_size)
@@ -2005,6 +2071,11 @@ class PagedServingEngine(ServingEngine):
         self.num_pages = (int(num_pages) if num_pages is not None
                           else self.num_slots * self.max_pages)
         self.kv_dtype = kv_dtype
+        # None, the default, means "on where the stack allows it": a
+        # stack that keeps a scan state or a ring is served with the
+        # prefix cache off, and raises for what would park such a state
+        self.prefix_cache = prefix_cache = \
+            self.driver.settle_page_options(prefix_cache, kv_dtype)
         self.reserve_decode_frac = float(reserve_decode_frac)
         self._alloc = PageAllocator(self.num_pages, page_size)
         self._prefix = (RadixPrefixCache(self._alloc, prefix_capacity,
@@ -2088,18 +2159,23 @@ class PagedServingEngine(ServingEngine):
 
         from .paging import resolve_kv_dtype
 
-        decoder = self._net.decoder
         storage, quantized = resolve_kv_dtype(
             self.kv_dtype, jnp.dtype(self._np_dtype))
-        h0 = decoder.layers[0].self_attn
-        per_buf = h0.num_heads * self.page_size * h0.head_dim \
-            * jnp.dtype(storage).itemsize
-        scale_b = h0.num_heads * 4 if quantized else 0
-        self._page_bytes = 2 * len(decoder.layers) * (per_buf + scale_b)
+        self._page_bytes = self.driver.page_row_bytes(storage, quantized)
         self._pool_total_bytes = self.pool_bytes()
+        self.metrics.set_cache_bytes({
+            kind: _tree_bytes(self._state.get(kind))
+            for kind in ("paged", "ring", "recurrent", "static")})
         if self.metrics.budget_bytes > 0:
             self.metrics.check_memory_watermark(
                 self.weights_bytes() + self.pool_in_use_bytes())
+
+    def _count_cache(self, **counts):
+        """Add to this iteration's cache counts (state_resets,
+        prefill_tokens, ring_wraps)."""
+        for k, v in counts.items():
+            if v:
+                self._cache_iter[k] = self._cache_iter.get(k, 0) + int(v)
 
     # ---- host page bookkeeping ----
     def _alloc_pages(self, n):
@@ -2204,6 +2280,8 @@ class PagedServingEngine(ServingEngine):
         gauges = dict(super()._iteration_gauges() or {})
         gauges.update({"pages_in_use": self._alloc.pages_in_use,
                        "pages_free": self._alloc.pages_free})
+        if self._cache_iter:
+            gauges["cache"], self._cache_iter = self._cache_iter, {}
         if self._prefix is not None:
             st = self._prefix.stats()
             gauges.update({"trie_nodes": st["nodes"],
@@ -2364,16 +2442,18 @@ class PagedServingEngine(ServingEngine):
             self._state, tok0 = fn(
                 self._params(), self._buffers(), self._state,
                 jnp.int32(s), jnp.asarray(prompt_b),
-                jnp.asarray([P0], jnp.int32),
-                jnp.asarray(np.asarray(r.memory, self._np_dtype)[None]),
+                jnp.asarray([P0], jnp.int32), self.driver.join_memory(r),
                 jnp.asarray(np.asarray(pages, np.int32)),
                 *self._join_adapter_args(row))
         except Exception:
             self._alloc.decref(pages)
             raise
         self._table[s, :n_pp] = pages
-        self._index[s] = Pb
+        self._index[s] = self.driver.start_index(P0, Pb)
         self.prefill_count += 1
+        self._count_cache(state_resets=1, prefill_tokens=P0)
+        if r._trace is not None:
+            _rt.on_join_attr(r, prefill_tokens=P0)
         # tok0 stays the traced scalar: the trie stores it raw and
         # resolves lazily at the first whole hit; the caller's
         # delivery resolves after the admission round's last dispatch
@@ -2696,8 +2776,7 @@ class PagedServingEngine(ServingEngine):
         S = self.num_slots
         params, buffers, state = self._params(), self._buffers(), \
             self._state
-        M, Dm = self._mem_shape
-        mem1 = jnp.zeros((1, M, Dm), jnp.dtype(self._np_dtype))
+        mem1 = self.driver.warm_memory()
         one = jnp.asarray([1], jnp.int32)
         active = jnp.zeros((S,), bool)
         table0 = jnp.zeros((S, self.max_pages), jnp.int32)

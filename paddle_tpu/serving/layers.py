@@ -43,6 +43,295 @@ __all__ = ["DenseLayout", "PagedLayout", "SinglePlacement",
 
 
 # --------------------------------------------------------------------------
+# stack drivers: what a pool layout asks of the model it serves
+# --------------------------------------------------------------------------
+
+class DecoderStackDriver:
+    """The (decoder, embed, project) triple of `nn.TransformerDecoder`
+    with a client-supplied cross-attention `memory`: every layer keeps
+    its self-attention K/V in pages and a static cross-attention K/V a
+    slot (computed from the memory at the join). A bucket's pad hole
+    stays masked for ever and generation writes on from the bucket's
+    end."""
+
+    def __init__(self, eng):
+        self.eng = eng
+
+    def cache_kinds(self):
+        return ["paged"] * len(self.eng._net.decoder.layers)
+
+    # ---- what the engine asks about a request's memory ----
+    def check_memory(self, r):
+        if r.memory is None or r.memory.ndim != 2:
+            raise ValueError("ServingEngine requests need a 2-D "
+                             "cross-attention memory [M, D]")
+        shape = self.eng._mem_shape
+        if shape is not None and tuple(r.memory.shape) != shape:
+            raise ValueError(
+                f"memory shape {tuple(r.memory.shape)} != pool's "
+                f"{shape} (fixed by the first join)")
+
+    def pin_memory(self, memory, dtype="float32"):
+        """-> (the example memory that fixes the pool, its shape, the
+        pool's dtype). `memory` is an [M, D] array or its shape."""
+        import jax.numpy as jnp
+
+        if not (hasattr(memory, "ndim") or isinstance(memory, np.ndarray)):
+            M, Dm = memory
+            memory = np.zeros((int(M), int(Dm)), np.dtype(dtype))
+        memory = np.asarray(memory)
+        return memory, tuple(memory.shape), jnp.asarray(memory).dtype
+
+    def join_memory(self, r):
+        import jax.numpy as jnp
+
+        return jnp.asarray(np.asarray(r.memory, self.eng._np_dtype)[None])
+
+    def warm_memory(self):
+        import jax.numpy as jnp
+
+        M, Dm = self.eng._mem_shape
+        return jnp.zeros((1, M, Dm), jnp.dtype(self.eng._np_dtype))
+
+    def start_index(self, P0, Pb):
+        return Pb
+
+    def settle_page_options(self, prefix_cache, kv_dtype):
+        """-> whether the radix prefix cache is on (None: the default)."""
+        return True if prefix_cache is None else bool(prefix_cache)
+
+    def count_advance(self, index, n_emit):
+        """Cache counts of a decode step that moved `index` by `n_emit`."""
+
+    # ---- the pool's state ----
+    def new_slot_state(self, memory, dtype):
+        """The per-slot kinds beside the pages."""
+        import jax.numpy as jnp
+
+        eng = self.eng
+        S, L = eng.num_slots, eng._pool_len
+        M, Dm = memory.shape
+        return {"bias": jnp.zeros((S, L), jnp.float32),
+                "mem": jnp.zeros((S, M, Dm), dtype),
+                "static": self.new_static(M, dtype)}
+
+    def new_paged(self, dtype):
+        eng = self.eng
+        out = []
+        for layer in eng._net.decoder.layers:
+            c = layer.self_attn.gen_paged_cache(
+                eng.num_pages, eng.page_size, eng.num_slots,
+                eng.max_pages, dtype, eng.kv_dtype)
+            out.append({"k": c.k, "v": c.v, "ks": c.k_scale,
+                        "vs": c.v_scale})
+        return out
+
+    def new_static(self, M, dtype):
+        import jax.numpy as jnp
+
+        out = []
+        for layer in self.eng._net.decoder.layers:
+            z = jnp.zeros((self.eng.num_slots, layer.cross_attn.num_heads,
+                           M, layer.cross_attn.head_dim), dtype)
+            out.append((z, z))
+        return out
+
+    def page_row_bytes(self, storage, quantized):
+        """(bytes of one page over all paged layers, K and V)."""
+        import jax.numpy as jnp
+
+        eng = self.eng
+        decoder = eng._net.decoder
+        h0 = decoder.layers[0].self_attn
+        per_buf = h0.num_heads * eng.page_size * h0.head_dim \
+            * jnp.dtype(storage).itemsize
+        scale_b = h0.num_heads * 4 if quantized else 0
+        return 2 * len(decoder.layers) * (per_buf + scale_b)
+
+    def prefill(self, params, buffers, prompt, length, memory, bias_row,
+                Pb, ad):
+        """-> (logits [1, Pb, V], {"paged": [(k, v) [1, H, Pb, D]],
+        "static": [(k, v)]}): what a join splices into the slot."""
+        import jax.numpy as jnp
+
+        eng = self.eng
+        decoder = eng._net.decoder
+        positions = jnp.arange(Pb, dtype=jnp.int32)[None]
+        inc0 = [layer.self_attn.gen_cache(
+            None, max_length=Pb, batch_size=1, dtype=memory.dtype)
+            for layer in decoder.layers]
+        with eng._lora_ctx(ad):
+            (lg, inc1, static1), _ = eng._fm.apply(
+                params, buffers, None, prompt, positions, memory,
+                training=False, tgt_mask=bias_row[:, :Pb],
+                memory_mask=None, inc=inc0, prefill=True)
+        last = jnp.take_along_axis(
+            lg, (length - 1)[:, None, None], axis=1)[:, 0]
+        return last, {"paged": [(c.k, c.v) for c in inc1],
+                      "static": static1}
+
+    def step(self, params, buffers, state, table, index, ad):
+        """One position a slot -> (logits [S, V], the state's lists that
+        changed)."""
+        from . import paging as PG
+
+        eng = self.eng
+        inc = [PG.PagedKVCache(pc["k"], pc["v"], pc["ks"], pc["vs"],
+                               table, index) for pc in state["paged"]]
+        posn = index[:, None]
+        with eng._lora_ctx(ad):
+            (lg, inc2), _ = eng._fm.apply(
+                params, buffers, None, state["tok"][:, None], posn,
+                state["mem"], training=False, tgt_mask=state["bias"],
+                memory_mask=None, inc=inc, static_kv=state["static"],
+                prefill=False)
+        return lg[:, 0], {"paged": [
+            {"k": c.k, "v": c.v, "ks": c.k_scale, "vs": c.v_scale}
+            for c in inc2]}
+
+
+class CausalLMDriver:
+    """A decoder-only causal LM that says what each of its blocks keeps
+    of a sequence (`cache_kinds()`: "recurrent", "ring", "paged" or None
+    a block) and runs `prefill` and `decode` over that state
+    (`text.models.Phi4FlashForCausalLM`). Requests carry no memory; a
+    prompt sits at positions [0, P0) and generation goes on from P0 (no
+    pad hole: a recurrent state cannot step over one)."""
+
+    def __init__(self, eng):
+        self.eng = eng
+        self.model = eng._net
+        self.cfg = self.model.cfg
+        self.n_ring = self.cache_kinds().count("ring")
+        #: a recurrent state or a window ring a slot: nothing parks,
+        #: snapshots or replays those
+        self.keeps_state = any(k in ("recurrent", "ring")
+                               for k in self.cache_kinds())
+
+    def cache_kinds(self):
+        return self.model.cache_kinds()
+
+    # ---- what the engine asks about a request's memory: it has none ----
+    def check_memory(self, r):
+        if r.memory is not None and r.memory.size:
+            raise ValueError(
+                f"{type(self.model).__name__} is a decoder without "
+                f"memory: its requests carry none (got an array of "
+                f"shape {tuple(r.memory.shape)})")
+
+    def pin_memory(self, memory, dtype=None):
+        return None, (), self.model.embed_tokens._data.dtype
+
+    def join_memory(self, r):
+        return None
+
+    def warm_memory(self):
+        return None
+
+    def start_index(self, P0, Pb):
+        return P0
+
+    def settle_page_options(self, prefix_cache, kv_dtype):
+        """The radix prefix cache and preemption park K/V pages by
+        refcount, which has no meaning for a scan state or a ring: a
+        stack that keeps either is served with the cache OFF, and asking
+        for it, for other page storage or for fewer pages than the slots
+        can fill raises."""
+        if not self.keeps_state:
+            return True if prefix_cache is None else bool(prefix_cache)
+        eng = self.eng
+        name = type(self.model).__name__
+        if prefix_cache:
+            raise ValueError(
+                f"prefix cache (prefix_cache=True): {name} keeps a "
+                f"scan state and window rings a slot; a shared "
+                f"prefix's pages do not hold them, so no prefix is "
+                f"reused")
+        if kv_dtype is not None:
+            raise ValueError(
+                f"page storage (kv_dtype={kv_dtype!r}): {name}'s "
+                f"pages hold its own dtype; nothing rescales them")
+        if eng.num_pages < eng.num_slots * eng.max_pages:
+            raise ValueError(
+                f"an oversubscribed page pool (num_pages="
+                f"{eng.num_pages} < {eng.num_slots} slots x "
+                f"{eng.max_pages} pages): a pool that runs dry "
+                f"evicts or preempts a slot mid-sequence, and "
+                f"{name}'s scan state and rings cannot be parked in "
+                f"pages and resumed")
+        return False
+
+    def count_advance(self, index, n_emit):
+        # a ring wraps where a slot writes row 0 again
+        at = index[n_emit > 0]
+        self.eng._count_cache(ring_wraps=self.n_ring * int(
+            ((at > 0) & (at % self.cfg.sliding_window == 0)).sum()))
+
+    # ---- the pool's state ----
+    def new_slot_state(self, memory, dtype):
+        return {"ring": self.new_ring(dtype),
+                "recurrent": self.new_recurrent(dtype)}
+
+    def new_paged(self, dtype):
+        import jax.numpy as jnp
+
+        eng, cfg = self.eng, self.cfg
+        width = cfg.num_key_value_heads * cfg.head_dim
+        buf = jnp.zeros((eng.num_pages + 1, eng.page_size, width), dtype)
+        return [{"k": buf, "v": buf, "ks": None, "vs": None}
+                for k in self.cache_kinds() if k == "paged"]
+
+    def new_ring(self, dtype):
+        import jax.numpy as jnp
+
+        cfg = self.cfg
+        z = jnp.zeros((self.eng.num_slots, cfg.sliding_window,
+                       cfg.num_key_value_heads * cfg.head_dim), dtype)
+        return [(z, z) for k in self.cache_kinds() if k == "ring"]
+
+    def new_recurrent(self, dtype):
+        import jax.numpy as jnp
+
+        cfg, S = self.cfg, self.eng.num_slots
+        return [(jnp.zeros((S, cfg.d_conv - 1, cfg.d_inner), dtype),
+                 jnp.zeros((S, cfg.d_state, cfg.d_inner), jnp.float32))
+                for k in self.cache_kinds() if k == "recurrent"]
+
+    def page_row_bytes(self, storage, quantized):
+        import jax.numpy as jnp
+
+        cfg = self.cfg
+        n = sum(k == "paged" for k in self.cache_kinds())
+        return 2 * n * self.eng.page_size * cfg.num_key_value_heads \
+            * cfg.head_dim * jnp.dtype(storage).itemsize
+
+    def prefill(self, params, buffers, prompt, length, memory, bias_row,
+                Pb, ad):
+        import jax.numpy as jnp
+
+        cfg = self.cfg
+        (lg, recurrent, ring, (k, v)), _ = self.eng._fm.apply(
+            params, buffers, None, training=False, op="prefill",
+            args=(prompt, length))
+
+        def heads(rows):   # [1, Pb, Hkv d] -> [1, Hkv, Pb, d]
+            return jnp.swapaxes(rows.reshape(
+                1, Pb, cfg.num_key_value_heads, cfg.head_dim), 1, 2)
+
+        return lg, {"paged": [(heads(k), heads(v))], "ring": ring,
+                    "recurrent": recurrent}
+
+    def step(self, params, buffers, state, table, index, ad):
+        pages = state["paged"][0]
+        (lg, recurrent, ring, (kp, vp)), _ = self.eng._fm.apply(
+            params, buffers, None, training=False, op="decode",
+            args=(state["tok"], index, state["recurrent"], state["ring"],
+                  (pages["k"], pages["v"]), table))
+        return lg, {"recurrent": recurrent, "ring": ring,
+                    "paged": [{"k": kp, "v": vp, "ks": None, "vs": None}]}
+
+
+# --------------------------------------------------------------------------
 # cache layouts: pool state + the traceable program bodies
 # --------------------------------------------------------------------------
 
@@ -515,37 +804,31 @@ class PagedLayout(CacheLayout):
                 jnp.asarray(eng._index.astype(np.int32)))
 
     def advance_rows(self, n_emit):
-        self.eng._index += np.asarray(n_emit, np.int64).astype(
-            self.eng._index.dtype)
+        eng = self.eng
+        n_emit = np.asarray(n_emit, np.int64)
+        eng.driver.count_advance(eng._index, n_emit)
+        eng._index += n_emit.astype(eng._index.dtype)
 
     # ---- state ----
     def build_state(self, memory):
+        """The pool's device state, by what each layer of the served
+        stack keeps of a sequence (`driver.cache_kinds()`): `paged` (K/V
+        page arrays through the engine's table and allocator), `ring`
+        (the last `window` K/V rows a slot), `recurrent` (a convolution
+        tail and a float32 scan state a slot), and, for a stack with a
+        client-supplied memory, `static` (its cross-attention K/V a
+        slot) with the memory and the pad-hole bias rows. A join writes
+        every kind whole for its slot, so nothing of the slot's last
+        request shows."""
         import jax.numpy as jnp
 
         eng = self.eng
-        decoder = eng._net.decoder
-        M, Dm = memory.shape
-        dtype = jnp.asarray(np.asarray(memory)).dtype
+        drv = eng.driver
         S, L = eng.num_slots, eng._pool_len
-        paged = []
-        for layer in decoder.layers:
-            c = layer.self_attn.gen_paged_cache(
-                eng.num_pages, eng.page_size, S, eng.max_pages,
-                dtype, eng.kv_dtype)
-            paged.append({"k": c.k, "v": c.v, "ks": c.k_scale,
-                          "vs": c.v_scale})
-        static = []
-        for layer in decoder.layers:
-            z = jnp.zeros((S, layer.cross_attn.num_heads, M,
-                           layer.cross_attn.head_dim), dtype)
-            static.append((z, z))
-        state = {
-            "tok": jnp.zeros((S,), jnp.int32),
-            "bias": jnp.zeros((S, L), jnp.float32),
-            "mem": jnp.zeros((S, M, Dm), dtype),
-            "static": static,
-            "paged": paged,
-        }
+        dtype = jnp.dtype(eng._np_dtype)
+        state = {"tok": jnp.zeros((S,), jnp.int32),
+                 **drv.new_slot_state(memory, dtype),
+                 "paged": drv.new_paged(dtype)}
         if eng.spec_k:
             state["hist"] = jnp.zeros((S, L), jnp.int32)
             state["plen"] = jnp.zeros((S,), jnp.int32)
@@ -556,8 +839,8 @@ class PagedLayout(CacheLayout):
         import jax.numpy as jnp
 
         eng = self.eng
-        M, Dm = memory.shape
-        dtype = jnp.asarray(np.asarray(memory)).dtype
+        M, Dm = eng._mem_shape or (0, 0)
+        dtype = jnp.dtype(eng._np_dtype)
         return (eng.num_slots, eng._pool_len, M, Dm, str(dtype),
                 eng.page_size, eng.num_pages, str(eng.kv_dtype)) + \
             ((("spec", eng.spec_k, eng.spec_ngram),)
@@ -572,8 +855,7 @@ class PagedLayout(CacheLayout):
         from . import paging as PG
 
         eng = self.eng
-        fm = eng._fm
-        decoder = eng._net.decoder
+        drv = eng.driver
         L = eng._pool_len
         spec = bool(eng.spec_k)
         ck = self.join_key(Pb)
@@ -587,39 +869,36 @@ class PagedLayout(CacheLayout):
                 (kpos[None, :] < jnp.int32(Pb))
             bias_row = jnp.where(hole, jnp.float32(neg),
                                  jnp.float32(0.0))           # [1, L]
-            positions = jnp.arange(Pb, dtype=jnp.int32)[None]
-            inc0 = [layer.self_attn.gen_cache(
-                None, max_length=Pb, batch_size=1, dtype=memory.dtype)
-                for layer in decoder.layers]
-            with eng._lora_ctx(ad):
-                (lg, inc1, static1), _ = fm.apply(
-                    params, buffers, None, prompt, positions, memory,
-                    training=False, tgt_mask=bias_row[:, :Pb],
-                    memory_mask=None, inc=inc0, prefill=True)
-            last = jnp.take_along_axis(
-                lg, (length - 1)[:, None, None], axis=1)[:, 0]
+            last, new = drv.prefill(params, buffers, prompt, length,
+                                    memory, bias_row, Pb, ad)
             tok0 = last.argmax(-1).astype(jnp.int32)[0]
             new_paged = []
-            for pc, c in zip(state["paged"], inc1):
+            for pc, (k, v) in zip(state["paged"], new["paged"]):
                 cache = PG.PagedKVCache(pc["k"], pc["v"], pc["ks"],
                                         pc["vs"], None, None)
-                cache = MHA.paged_prompt_splice(cache, page_ids,
-                                                c.k, c.v)
+                cache = MHA.paged_prompt_splice(cache, page_ids, k, v)
                 new_paged.append({"k": cache.k, "v": cache.v,
                                   "ks": cache.k_scale,
                                   "vs": cache.v_scale})
-            new_static = [(MHA.splice_rows(pk, slot, sk),
-                           MHA.splice_rows(pv, slot, sv))
-                          for (pk, pv), (sk, sv) in zip(state["static"],
-                                                        static1)]
             new_state = {
                 "tok": jax.lax.dynamic_update_slice(
                     state["tok"], tok0[None], (slot,)),
-                "bias": MHA.splice_rows(state["bias"], slot, bias_row),
-                "mem": MHA.splice_rows(state["mem"], slot, memory),
-                "static": new_static,
                 "paged": new_paged,
             }
+            # the per-slot kinds: every buffer of the slot's row written
+            # whole (a ring and a scan state start from this prompt
+            # alone, whatever the slot held)
+            for kind in ("static", "ring", "recurrent"):
+                if kind in state:
+                    new_state[kind] = [
+                        tuple(MHA.splice_rows(buf, slot, rows)
+                              for buf, rows in zip(pool, fresh))
+                        for pool, fresh in zip(state[kind], new[kind])]
+            if "bias" in state:
+                new_state["bias"] = MHA.splice_rows(state["bias"], slot,
+                                                    bias_row)
+                new_state["mem"] = MHA.splice_rows(state["mem"], slot,
+                                                   memory)
             if spec:
                 new_state = self._spec_join_rows(
                     jnp, MHA, jax, state, new_state, prompt, length,
@@ -883,29 +1162,16 @@ class PagedLayout(CacheLayout):
     def step_body(self, ck):
         import jax.numpy as jnp
 
-        from . import paging as PG
-
         eng = self.eng
-        fm = eng._fm
+        drv = eng.driver
 
         def step_fn(params, buffers, state, table, index, *rest):
             eng.trace_counts[ck] += 1  # one per trace = one compile
             *ad, active = rest          # ad = (ids, banks) | ()
-            inc = [PG.PagedKVCache(pc["k"], pc["v"], pc["ks"],
-                                   pc["vs"], table, index)
-                   for pc in state["paged"]]
-            posn = index[:, None]
-            with eng._lora_ctx(ad):
-                (lg, inc2), _ = fm.apply(
-                    params, buffers, None, state["tok"][:, None], posn,
-                    state["mem"], training=False,
-                    tgt_mask=state["bias"], memory_mask=None, inc=inc,
-                    static_kv=state["static"], prefill=False)
-            nxt = lg[:, 0].argmax(-1).astype(jnp.int32)
+            lg, parts = drv.step(params, buffers, state, table, index, ad)
+            nxt = lg.argmax(-1).astype(jnp.int32)
             nxt = jnp.where(active, nxt, state["tok"])
-            new_paged = [{"k": c.k, "v": c.v, "ks": c.k_scale,
-                          "vs": c.v_scale} for c in inc2]
-            return dict(state, tok=nxt, paged=new_paged), nxt
+            return dict(state, tok=nxt, **parts), nxt
 
         return step_fn
 

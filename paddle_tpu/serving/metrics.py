@@ -86,6 +86,19 @@ SNAPSHOT_DOCS = {
         "counter", "cross-slice transfers (splices, param placement)"),
     "sharding.collective_time_share": (
         "gauge", "collective / (collective + prefill + decode) time"),
+    # the pool's caches by kind (PR 33) — the section appears once a
+    # paged pool is built
+    "cache.bytes": ("gauge", "device state of the pool by kind: paged "
+                             "(K/V pages), ring (window K/V rows a slot), "
+                             "recurrent (convolution tails and scan "
+                             "states a slot), static (cross-attention "
+                             "K/V a slot)"),
+    "cache.ring_wraps": ("counter", "window rings that wrote their row 0 "
+                                    "again (slot-steps x ring layers)"),
+    "cache.state_resets": ("counter", "slots taken by a prefill: every "
+                                      "kind of the slot's state written "
+                                      "whole"),
+    "cache.prefill_tokens": ("counter", "prompt positions prefilled"),
     # paged pools (PR 6) — the section appears once a paged engine
     # records
     "paging.pages_in_use": ("gauge", "pages mapped at last iteration"),
@@ -281,6 +294,7 @@ SNAPSHOT_DOCS = {
 
 _SUMMARY_KEYS = {"n", "mean", "p50", "p99", "max"}
 _LEAF_DICTS = {"errors.last", "mfu.device", "pipeline.series_steps",
+               "cache.bytes",
                "speculation.step_ms_by_variant",
                "tenancy.active_slots_by_tenant",
                "tenancy.tokens_by_tenant",
@@ -454,6 +468,11 @@ class ServingMetrics:
         # snapshot only grows a "paging" section for paged pools)
         self.pages_in_use = None    # last-iteration gauge
         self.pages_free = None
+        # what the pool's caches did (PR 33): bytes by kind of state
+        # (set once the pool is built) and counts summed over iterations
+        self.cache_bytes = None
+        self.cache_counts = {"ring_wraps": 0, "state_resets": 0,
+                             "prefill_tokens": 0}
         self.page_iterations = 0    # sum over iterations of pages_in_use
         self.live_page_iterations = 0   # ... of the slots' WRITTEN pages
         self.table_entries_total = None     # slots x max pages a slot
@@ -582,6 +601,12 @@ class ServingMetrics:
     def record_join(self):
         with self._lock:
             self.joins += 1
+
+    def set_cache_bytes(self, by_kind):
+        """The pool's device state by kind (paged / ring / recurrent /
+        static), in bytes."""
+        with self._lock:
+            self.cache_bytes = {k: int(v) for k, v in by_kind.items()}
 
     def record_first_token(self, ttft_s):
         with self._lock:
@@ -948,9 +973,11 @@ class ServingMetrics:
                          pages_free=None, bytes_per_active_token=None,
                          shard_occupancy=None, tenant_slots=None,
                          trie_nodes=None, trie_pages=None,
-                         live_pages=None, table_entries=None):
+                         live_pages=None, table_entries=None, cache=None):
         with self._lock:
             self.iterations += 1
+            for k, v in (cache or {}).items():
+                self.cache_counts[k] = self.cache_counts.get(k, 0) + int(v)
             self.queue_depth.add(queue_depth)
             self.occupancy.add(occupancy)
             if tenant_slots is not None:
@@ -1127,6 +1154,12 @@ class ServingMetrics:
                         self.collective_s /
                         max(1e-9, self.collective_s + self.decode_time_s
                             + sum(self.prefill_step_s._buf)), 4),
+                }}),
+                **({} if self.cache_bytes is None else {"cache": {
+                    "bytes": dict(self.cache_bytes),
+                    "ring_wraps": self.cache_counts["ring_wraps"],
+                    "state_resets": self.cache_counts["state_resets"],
+                    "prefill_tokens": self.cache_counts["prefill_tokens"],
                 }}),
                 **({} if self.pages_in_use is None else {"paging": {
                     "pages_in_use": self.pages_in_use,

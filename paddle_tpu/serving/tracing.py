@@ -19,6 +19,13 @@ clock's reading at its start (`t0_perf_ns`), one (profiler ns,
 NOT forwarded (`request`, `queue`, `decode`, `pending_splice`, every
 `add_complete` span) on the same clock.
 
+Device-side names (a profiler trace, not spans): every pool program is
+`jit_<kind>` (`serving/layers.py` `named_program`); under `pstep` /
+`pjoin` a served causal LM scopes each block's mixer by its kind, `mamba`,
+`swa`, `full`, `xattn`, `gmu` (`text/models.py`), as the decoder stack's
+attention is `attn`; every Pallas kernel has a fixed name
+(`selective_scan` is the join's scan).
+
 Span catalog (exported Chrome-trace names):
 
   request         per-request root: submit() -> finish/fail
@@ -27,7 +34,7 @@ Span catalog (exported Chrome-trace names):
                   the queue head)
   join            slot join: prefill / prefix attach / disaggregated
                   dispatch -> return (attrs: slot, prompt bucket,
-                  prefix_hit)
+                  prefix_hit; a prefill also prefill_tokens)
   join.prefix_match  instant under the join: the radix prefix-cache
                   consult (attrs: kind whole/partial/miss,
                   matched_pages, matched_tokens)
@@ -53,7 +60,9 @@ Span catalog (exported Chrome-trace names):
                   profiler's events leaves out an `iteration` that
                   holds no decode.step and no join; attrs: joins, n_active,
                   occupancy, queue_depth, the page-pool and shard
-                  gauges, t0_perf_ns; step — "ahead" where the
+                  gauges, t0_perf_ns; cache — this iteration's
+                  state_resets, prefill_tokens and ring_wraps, where
+                  any —; step — "ahead" where the
                   iteration's step was enqueued before the last one's
                   tokens were read, else why not: idle, spec, chunk,
                   pending, preempt, retry, host —; late_slot_steps)

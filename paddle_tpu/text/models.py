@@ -304,3 +304,335 @@ class NemotronHForCausalLM(nn.Layer):
         for block in self.layers:
             x = run_block(block, x)
         return self.lm_head(self.norm_f(x))
+
+
+# --------------------------------------------------------------------------
+# Phi4Flash (`model_type` "phi4flash"; the SambaY decoder-hybrid-decoder of
+# arXiv:2507.06607). A self-decoder, blocks 0 .. L/2 + 1: Mamba-1 mixers at
+# the even blocks up to L/2, sliding-window differential attention at the
+# odd ones, full differential attention at block L/2 + 1; and a
+# cross-decoder after it: gated memory units at the even blocks (they read
+# block L/2's state-space output, "the memory") and cross attention at the
+# odd ones (they read block L/2 + 1's keys and values). No positional term
+# anywhere. Inference only: nothing here records a gradient.
+# --------------------------------------------------------------------------
+
+class Phi4FlashConfig:
+    """Keys as the source's `config.json` names them; `head_dim`, `d_state`,
+    `d_conv`, `expand`, `dt_rank` and `dtype` are not in it (the Mamba-1
+    conventions and hidden / heads)."""
+
+    def __init__(self, vocab_size=200064, hidden_size=2560,
+                 intermediate_size=10240, num_hidden_layers=32,
+                 num_attention_heads=40, num_key_value_heads=20,
+                 sliding_window=512, mb_per_layer=2, layer_norm_eps=1e-5,
+                 hidden_act="silu", tie_word_embeddings=True,
+                 mlp_bias=False, lm_head_bias=False, head_dim=None,
+                 d_state=16, d_conv=4, expand=2, dt_rank=None,
+                 dtype="bfloat16", **unused):
+        if hidden_act != "silu" or not tie_word_embeddings or mlp_bias \
+                or lm_head_bias or mb_per_layer != 2:
+            raise ValueError(
+                "Phi4Flash is built with silu, tied embeddings, no bias in "
+                "the feed-forward or the head and a Mamba layer every "
+                "second block only")
+        if num_hidden_layers % 4 or num_key_value_heads % 2 or \
+                num_attention_heads % num_key_value_heads:
+            raise ValueError(
+                f"{num_hidden_layers} layers, {num_attention_heads} query "
+                f"over {num_key_value_heads} key-value heads: the layout "
+                f"needs layers in fours and heads in pairs")
+        self.vocab_size, self.hidden_size = vocab_size, hidden_size
+        self.intermediate_size = intermediate_size
+        self.num_hidden_layers = num_hidden_layers
+        self.num_attention_heads = num_attention_heads
+        self.num_key_value_heads = num_key_value_heads
+        self.sliding_window, self.mb_per_layer = sliding_window, mb_per_layer
+        self.layer_norm_eps = layer_norm_eps
+        self.head_dim = head_dim or hidden_size // num_attention_heads
+        self.d_state, self.d_conv = d_state, d_conv
+        self.d_inner = expand * hidden_size
+        self.dt_rank = dt_rank or -(-hidden_size // 16)
+        self.dtype = dtype
+        #: the Mamba block whose state-space output the memory units read
+        self.memory_layer = num_hidden_layers // 2
+
+    def kind(self, i):
+        """Block i's mixer: mamba | swa | full | gmu | xattn."""
+        half = self.num_hidden_layers // 2
+        if i % 2 == 0:
+            return "mamba" if i <= half else "gmu"
+        return "swa" if i < half else "full" if i == half + 1 else "xattn"
+
+    @classmethod
+    def tiny(cls, **kw):
+        d = dict(vocab_size=96, hidden_size=32, intermediate_size=48,
+                 num_hidden_layers=8, num_attention_heads=4,
+                 num_key_value_heads=2, sliding_window=8, d_state=4,
+                 dt_rank=4, dtype="float32")
+        d.update(kw)
+        return cls(**d)
+
+
+class Phi4FlashBlock(nn.Layer):
+    """h = x + Mixer(LN(x)); y = h + W2 (u * silu(g)), [u; g] = W1 LN'(h)."""
+
+    def __init__(self, cfg: Phi4FlashConfig, idx):
+        super().__init__()
+        from ..nn.layer.ssm import _held
+        from ..nn import initializer as I
+
+        self.kind, self.idx = cfg.kind(idx), idx
+        d, dt = cfg.hidden_size, cfg.dtype
+        self.input_layernorm = nn.LayerNorm(d, cfg.layer_norm_eps)
+        self.post_attention_layernorm = nn.LayerNorm(d, cfg.layer_norm_eps)
+        if self.kind == "mamba":
+            self.mixer = nn.Mamba1Mixer(d, cfg.d_inner, cfg.d_state,
+                                        cfg.d_conv, cfg.dt_rank, dt)
+        elif self.kind == "gmu":
+            self.mixer = nn.GatedMemoryUnit(d, cfg.d_inner, dt)
+        else:
+            self.mixer = nn.DifferentialAttention(
+                d, cfg.num_attention_heads, cfg.num_key_value_heads,
+                cfg.head_dim, idx, cross=self.kind == "xattn", dtype=dt)
+        self.fc1 = _held(self, (d, 2 * cfg.intermediate_size),
+                         I.XavierUniform(), dt)
+        self.fc2 = _held(self, (cfg.intermediate_size, d),
+                         I.XavierUniform(), dt)
+
+    @staticmethod
+    def _ln(ln, x):
+        import jax
+        import jax.numpy as jnp
+
+        xf = x.astype(jnp.float32)
+        mu = xf.mean(-1, keepdims=True)
+        var = ((xf - mu) ** 2).mean(-1, keepdims=True)
+        y = (xf - mu) * jax.lax.rsqrt(var + jnp.float32(ln._epsilon))
+        return (y * ln.weight._data.astype(jnp.float32)
+                + ln.bias._data.astype(jnp.float32)).astype(x.dtype)
+
+    def pre(self, x):
+        return self._ln(self.input_layernorm, x)
+
+    def feed_forward(self, h):
+        import jax
+        import jax.numpy as jnp
+
+        ug = self._ln(self.post_attention_layernorm, h) @ self.fc1._data
+        w = ug.shape[-1] // 2
+        act = (ug[..., :w].astype(jnp.float32)
+               * jax.nn.silu(ug[..., w:].astype(jnp.float32))).astype(
+            h.dtype)
+        return h + act @ self.fc2._data
+
+
+class Phi4FlashForCausalLM(nn.Layer):
+    """ids -> logits, three ways over raw arrays, all inference:
+
+    - `forward(ids)`: every block over every position.
+    - `prefill(ids [b, s], length [b])`: a join. Blocks up to the full
+      attention layer run over the prompt and leave their state: a
+      convolution tail and a scan state a Mamba block, the last `window`
+      key and value rows a window block (row r holds the newest position
+      congruent to r), the full layer's key and value rows. The blocks
+      after it, the memory and the full layer's own output are computed
+      for position length - 1 alone: none of them leaves state.
+    - `decode(tok [S], index [S], recurrent, ring, pages, table)`: one
+      position a slot, each kind of state updated in place.
+
+    The logits are float32."""
+
+    SCOPES = ("mamba", "swa", "full", "xattn", "gmu")
+
+    def __init__(self, cfg: Phi4FlashConfig):
+        super().__init__()
+        from ..nn import initializer as I
+        from ..nn.layer.ssm import _held
+
+        self.cfg = cfg
+        self.embed_tokens = _held(
+            self, (cfg.vocab_size, cfg.hidden_size), I.Normal(0.0, 0.02),
+            cfg.dtype)
+        self.layers = nn.LayerList([
+            Phi4FlashBlock(cfg, i) for i in range(cfg.num_hidden_layers)])
+        self.final_layernorm = nn.LayerNorm(cfg.hidden_size,
+                                            cfg.layer_norm_eps)
+        for _, p in self.named_parameters():
+            # LayerNorm's parameters are made float32: hold them as the
+            # rest is held
+            if str(p._data.dtype) != cfg.dtype and \
+                    not p.optimize_attr.get("keep_float32"):
+                p._data = p._data.astype(cfg.dtype)
+
+    def cache_kinds(self):
+        """What each block keeps of a sequence, for the pool's layout."""
+        keeps = {"mamba": "recurrent", "swa": "ring", "full": "paged"}
+        return [keeps.get(b.kind) for b in self.layers]
+
+    # ---- shared pieces ----
+    def _logits(self, y):
+        import jax.numpy as jnp
+
+        h = Phi4FlashBlock._ln(self.final_layernorm, y)
+        return jnp.dot(h, self.embed_tokens._data.T,
+                       preferred_element_type=jnp.float32)
+
+    def forward(self, ids=None, op=None, args=()):
+        """`forward(ids)`, or `forward(op="prefill" | "decode", args=...)`:
+        how a functionalized copy (`FunctionalModule.apply` calls
+        `forward`) reaches the other two."""
+        import jax
+        import jax.numpy as jnp
+
+        from ..core.tensor import Tensor
+        from ..ops import diff_attention as DA
+
+        if op is not None:
+            return getattr(self, op)(*args)
+        cfg = self.cfg
+        ids = getattr(ids, "_data", ids)
+        b, s = ids.shape
+        length = jnp.full((b,), s, jnp.int32)
+        x = self.embed_tokens._data[ids]
+        memory = kv = None
+        for blk in self.layers:
+            a = blk.pre(x)
+            with jax.named_scope(blk.kind):
+                if blk.kind == "mamba":
+                    out, y, _ = blk.mixer.prefill(a, length)
+                    if blk.idx == cfg.memory_layer:
+                        memory = y
+                elif blk.kind == "gmu":
+                    out = blk.mixer.mix(a, memory)
+                elif blk.kind == "xattn":
+                    out = blk.mixer.finish(DA.causal(
+                        blk.mixer.project(a), kv[0], kv[1],
+                        cfg.num_key_value_heads))
+                else:
+                    q, k, v = blk.mixer.project(a)
+                    if blk.kind == "full":
+                        kv = (k, v)
+                    out = blk.mixer.finish(DA.causal(
+                        q, k, v, cfg.num_key_value_heads,
+                        cfg.sliding_window if blk.kind == "swa" else None))
+            x = blk.feed_forward(x + out)
+        return Tensor._wrap(self._logits(x))
+
+    def prefill(self, ids, length):
+        """-> (logits [b, vocab] of position length - 1, recurrent
+        [(tail, H)], ring [(k, v)] of [b, window, kv width] rows, (k, v)
+        [b, s, kv width] of the full layer, s [b, s, d_inner] ... )."""
+        import jax
+        import jax.numpy as jnp
+
+        from ..ops import diff_attention as DA
+
+        cfg = self.cfg
+        ids = getattr(ids, "_data", ids)
+        length = jnp.asarray(length, jnp.int32)
+        last = (length - 1)[:, None, None]
+        w = cfg.sliding_window
+        x = self.embed_tokens._data[ids]
+        recurrent, ring = [], []
+        memory = kv = None
+        # ring row r holds the newest position congruent to r mod window
+        r = jnp.arange(w, dtype=jnp.int32)[None]
+        newest = (length - 1)[:, None] - (length[:, None] - 1 - r) % w
+        newest = jnp.clip(newest, 0, ids.shape[1] - 1)[..., None]
+
+        def take_last(t):
+            return jnp.take_along_axis(t, last, 1)[:, 0]
+
+        for blk in self.layers:
+            a = blk.pre(x)
+            with jax.named_scope(blk.kind):
+                if blk.kind == "mamba":
+                    out, y, state = blk.mixer.prefill(a, length)
+                    recurrent.append(state)
+                    if blk.idx == cfg.memory_layer:
+                        memory = take_last(y)
+                elif blk.kind == "swa":
+                    q, k, v = blk.mixer.project(a)
+                    ring.append((jnp.take_along_axis(k, newest, 1),
+                                 jnp.take_along_axis(v, newest, 1)))
+                    out = blk.mixer.finish(DA.causal(
+                        q, k, v, cfg.num_key_value_heads, w))
+                elif blk.kind == "full":
+                    # from here on: position length - 1 alone
+                    _, k, v = blk.mixer.project(a)
+                    kv = (k, v)
+                    x, a = take_last(x), take_last(a)
+                    q = blk.mixer.project(a)[0]
+                    out = blk.mixer.finish(DA.dense(
+                        q, k, v, cfg.num_key_value_heads, length))
+                elif blk.kind == "gmu":
+                    out = blk.mixer.mix(a, memory)
+                else:
+                    out = blk.mixer.finish(DA.dense(
+                        blk.mixer.project(a), kv[0], kv[1],
+                        cfg.num_key_value_heads, length))
+            x = blk.feed_forward(x + out)
+        return self._logits(x), recurrent, ring, kv
+
+    @staticmethod
+    def _ring_step(ring, rows, at, k, v):
+        """This position's key and value rows written at row `at` of each
+        slot's ring: (what the slot keeps, what this position reads).
+        They are the same: a position reads its own row."""
+        rk, rv = ring
+        new = (rk.at[rows, at].set(k.astype(rk.dtype)),
+               rv.at[rows, at].set(v.astype(rv.dtype)))
+        return new, new
+
+    def decode(self, tok, index, recurrent, ring, pages, table):
+        """tok, index [S]: the token each slot feeds and the position it
+        stands at. recurrent [(tail, H)], ring [(k, v)] [S, window, kv
+        width], pages (k, v) [N + 1, psz, kv width] with `table` [S,
+        max_pages]. -> (logits [S, vocab], recurrent, ring, pages)."""
+        import jax
+        import jax.numpy as jnp
+
+        from ..ops import diff_attention as DA
+        from ..serving import paging as PG
+
+        cfg = self.cfg
+        hkv, w = cfg.num_key_value_heads, cfg.sliding_window
+        index = jnp.asarray(index, jnp.int32)
+        x = self.embed_tokens._data[tok]
+        rows = jnp.arange(tok.shape[0])
+        new_rec, new_ring = [], []
+        rec, rng = iter(recurrent), iter(ring)
+        memory = None
+        for blk in self.layers:
+            a = blk.pre(x)
+            with jax.named_scope(blk.kind):
+                if blk.kind == "mamba":
+                    out, y, state = blk.mixer.step(a, *next(rec))
+                    new_rec.append(state)
+                    if blk.idx == cfg.memory_layer:
+                        memory = y
+                elif blk.kind == "swa":
+                    q, k, v = blk.mixer.project(a)
+                    kept, (rk, rv) = self._ring_step(
+                        next(rng), rows, index % w, k, v)
+                    new_ring.append(kept)
+                    out = blk.mixer.finish(DA.dense(
+                        q, rk, rv, hkv, jnp.minimum(index + 1, w)))
+                elif blk.kind == "full":
+                    q, k, v = blk.mixer.project(a)
+                    d = cfg.head_dim
+                    pages = tuple(
+                        PG.write_token(pg, None, table, index,
+                                       t.reshape(-1, hkv, d))[0]
+                        for pg, t in zip(pages, (k, v)))
+                    read = DA.paged_reader(pages[0], pages[1], table,
+                                           hkv)
+                    out = blk.mixer.finish(read(q, index + 1))
+                elif blk.kind == "gmu":
+                    out = blk.mixer.mix(a, memory)
+                else:
+                    out = blk.mixer.finish(read(blk.mixer.project(a),
+                                                index + 1))
+            x = blk.feed_forward(x + out)
+        return self._logits(x), new_rec, new_ring, pages

@@ -566,3 +566,67 @@ def test_partitioned_trace_takes_the_xla_composition(monkeypatch):
     with A.partitioned_trace(False):        # a one-device mesh
         assert A._flash_usable()
     assert A._flash_usable()
+
+
+# ---------------------------------------------------------------------------
+# PR 33: the served Phi4Flash pool's widths (1280 lanes = 10 wide heads of
+# 128, four query heads a wide head, bfloat16 pages of 16 rows)
+# ---------------------------------------------------------------------------
+
+PHI = dict(S=64, h=10, d=128, psz=16, di=5120, n=16)
+
+
+def test_gathered_page_read_compiles_at_the_serving_cell_widths(one_chip):
+    """What a decode step's nine readers of block 17's pages run: the one
+    gather of a slot's 256-page table and `dense` over its rows, at the
+    widths of the serving cell. No page-table kernel is in it, and the
+    logits of a slot's 40 query heads (float32, 64 x 40 x 4096) stay far
+    under the gathered rows."""
+    from paddle_tpu.ops import diff_attention as DA
+
+    S, h, d, psz = (PHI[k] for k in ("S", "h", "d", "psz"))
+    mp = 256
+    pages = ((S * mp + 1, psz, h * d), jnp.bfloat16)
+
+    def read(q, kp, vp, table, length):
+        return DA.paged_reader(kp, vp, table, 2 * h)(q, length)
+
+    compiled = _compile_xla(read, one_chip, ((S, 4 * h, d // 2),
+                                             jnp.bfloat16), pages, pages,
+                            ((S, mp), jnp.int32), ((S,), jnp.int32))
+    assert "tpu_custom_call" not in compiled.as_text()
+    rows = 2 * S * mp * psz * h * d * 2          # K and V, gathered
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 * rows
+
+
+def test_selective_scan_compiles_at_the_serving_cell_shapes(one_chip):
+    """The Pallas scan a join runs (one row, 2048 positions, 5120
+    channels, 16 states) and the composition it replaces on the chip."""
+    from paddle_tpu.ops import ssm
+
+    di, n = PHI["di"], PHI["n"]
+    shapes = (((1, 2048, di), jnp.bfloat16), ((1, 2048, di), jnp.float32),
+              ((di, n), jnp.float32), ((1, 2048, n), jnp.bfloat16),
+              ((1, 2048, n), jnp.bfloat16), ((di,), jnp.float32),
+              ((1,), jnp.int32))
+
+    def scan(x, dt, a, bm, cm, d, length):
+        return ssm.selective_scan(x, dt, a, bm, cm, d, None, length)
+
+    # off the chip the gate takes the composition: a chunk's states at a
+    # time, not a state a position (2048 x 5120 x 16 float32 = 0.67 GB)
+    assert not ssm.selective_scan_kernel_chosen(di, n)
+    composed = _compile_xla(scan, one_chip, *shapes)
+    assert composed.memory_analysis().temp_size_in_bytes < 0.4 * 2**30
+    orig = A._on_tpu
+    A._on_tpu = lambda: True
+    try:
+        assert ssm.selective_scan_kernel_chosen(di, n)
+        assert not ssm.selective_scan_kernel_chosen(di + 64, n)
+        # another function object: jit keeps the composition's trace
+        compiled = _compile(lambda *a: scan(*a), one_chip, *shapes)
+    finally:
+        A._on_tpu = orig
+    assert "selective_scan" in compiled.as_text()
+    # the columns of B and C spread over the lanes, and nothing s x n x c
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.2 * 2**30

@@ -485,7 +485,11 @@ def _full_metrics():
                        bytes_per_active_token=128.0,
                        shard_occupancy=[0.5, 0.25],
                        tenant_slots={"base": 1, "t1": 1},
-                       trie_nodes=4, trie_pages=6)
+                       trie_nodes=4, trie_pages=6,
+                       cache={"state_resets": 1, "prefill_tokens": 9,
+                              "ring_wraps": 2})
+    m.set_cache_bytes({"paged": 4096, "ring": 1024, "recurrent": 512,
+                       "static": 0})
     m.set_memory_provider(
         lambda: {"weights_bytes": 1000, "pool_bytes": 500,
                  "adapter_bytes": 128, "in_use_bytes": 1200,
@@ -920,7 +924,7 @@ def test_every_pallas_kernel_has_a_fixed_name():
         "flash_fwd", "flash_bwd_dq", "flash_bwd_dkv", "flash_decode",
         "flash_verify", "paged_flash_decode", "paged_flash_verify",
         "int8_matmul", "lora_gather", "fused_conv_fwd",
-        "fused_conv_bwd"])
+        "fused_conv_bwd", "selective_scan"])
     assert all(not any(c.isdigit() for c in n.replace("int8", ""))
                for n in flat)                     # no shape in a name
 
