@@ -1713,22 +1713,70 @@ def paged_gather_kv(pages, scales, table, num_heads, compute_dtype):
 
 
 #: float32 bytes of one page ([psz, H * D]) the paged kernels take, and
-#: of the [T * psz, H * D] block the T query rows make of it: a grid
-#: step holds the K and V pages (two buffers each) and a handful of
-#: block-sized float32 temporaries in VMEM, 16 MiB scoped on a v5e core
+#: of the [T * P * psz, H * D] block the T query rows make of the P pages
+#: of a grid step: a step holds the K and V blocks (P pages each, two
+#: buffers each, at most `_PAGED_PAGE_BYTES` a buffer as stored) and a
+#: handful of block-sized float32 temporaries in VMEM, 16 MiB scoped on a
+#: v5e core
 _PAGED_PAGE_BYTES = 2**20
 _PAGED_BLOCK_BYTES = 2**22
+#: the most pages a grid step takes. The kernel alone on a v5e at the
+#: benchmark's pool (64 slots x 64 pages of 16 x 1,024 float32; chip
+#: runs of PR 34, ms a call; a page a step read 0.610 / 0.801 / 2.189):
+#:   pages a step    every slot empty    451 pages written    all 4,096
+#:        4               0.090               0.220              1.127
+#:        8               0.059               0.197              0.944
+#:       16               0.040               0.225              0.880
+#: A larger block halves the grid's own steps and doubles the rows that
+#: a slot's last block computes past its length: 8 is where pools of
+#: 3-16 written pages a slot read least. (The same blocks as index-
+#: mapped operands of the pool, 2 P + 2 block specs a step, read 0.80 /
+#: 0.91 / 1.45 at 8: every operand's index map and copy test is paid
+#: each step, written or not, so the kernel makes its own copies.)
+_PAGED_BLOCK_PAGES = 8
 
 
 def _paged_kernel_fits(psz, hd, T=1):
     """The pools the paged kernels take, decided here from the shapes
     alone: rows that tile (`psz` a sublane multiple, `hd` = heads x head
     size a lane multiple), a page and a block of `T` query rows against
-    it that the step's working set holds in VMEM. Every other pool takes
-    the XLA gather composition."""
+    it that the step's working set holds in VMEM (a step of one page is
+    the least `_paged_block_pages` gives). Every other pool takes the
+    XLA gather composition."""
     page = 4 * psz * hd
     return (psz % 8 == 0 and hd % 128 == 0 and page <= _PAGED_PAGE_BYTES
             and T * page <= _PAGED_BLOCK_BYTES)
+
+
+def _paged_block_pages(psz, hd, T, mp, dtype):
+    """`P`, the consecutive logical pages of a slot that one grid step of
+    the paged kernels takes, from the shapes and the page dtype alone:
+    the largest power of two that is no more than the slot's `mp` pages
+    nor `_PAGED_BLOCK_PAGES`, keeps the float32 block of the `T` query
+    rows against the `P` pages ([T * P * psz, hd]) within
+    `_PAGED_BLOCK_BYTES`, and keeps a K or V block as stored within
+    `_PAGED_PAGE_BYTES` a buffer. So a verify call of many rows takes
+    fewer pages a step than a decode call, and a pool of wider rows
+    fewer still; 1 is the kernel of one page a step. The call builder
+    and the engine's `paging.pages_per_block` both read it here."""
+    import numpy as np
+
+    stored = psz * hd * np.dtype(dtype).itemsize
+    p = 1
+    while (2 * p <= min(mp, _PAGED_BLOCK_PAGES)
+           and T * 2 * p * 4 * psz * hd <= _PAGED_BLOCK_BYTES
+           and 2 * p * stored <= _PAGED_PAGE_BYTES):
+        p *= 2
+    return p
+
+
+def paged_decode_block_pages(psz, hd, mp, dtype):
+    """The pages a grid step of the decode call takes over a pool of
+    these shapes (`_paged_block_pages` at one query row); 1 for a pool
+    whose shapes send it to the gather. What the serving engine reports
+    as `paging.pages_per_block`."""
+    return (_paged_block_pages(psz, hd, 1, mp, dtype)
+            if _paged_kernel_fits(psz, hd) else 1)
 
 
 def _dot_indicator(x, ind):
@@ -1751,151 +1799,256 @@ def _dot_indicator(x, ind):
 
 
 def _paged_flash_call(S, h, mp, psz, d, T, s, has_scale, has_bias,
-                      interpret):
-    """Both paged kernels: one grid step per (slot, logical page), a
-    step taking the whole page — `psz` token rows of all heads, one
-    contiguous DMA — against the slot's `T` query rows (one is
+                      interpret, dtype):
+    """Both paged kernels: one grid step per (slot, block of `P`
+    consecutive logical pages), `P` = `_paged_block_pages`: a grid of
+    (S, ceil(mp / P)). A step takes the block whole — `P x psz` token
+    rows of all heads — against the slot's `T` query rows (one is
     `paged_flash_decode`; more are `paged_flash_verify`: the pending
     token plus the drafts, causal within the block: key j stays visible
     to row t only while j <= n_valid - T + t). The page table and the
-    written lengths ride scalar prefetch: the K/V index maps
-    dereference table[slot, page], CLAMPED to the slot's last written
-    page, so the steps past it name the block already resident and cost
-    no copy; `pl.when` skips their arithmetic. The page axis is the
+    written lengths ride scalar prefetch; the pool stays in HBM
+    (`pl.ANY`) and the kernel copies a block's WRITTEN pages itself, a
+    page a DMA through table[slot, page], the loop's bound read from
+    the slot's length, into one of two VMEM buffers (a pool's scales,
+    a value a (page, head), and the key bias come in the slot's logical
+    coordinates, a block a step; XLA gathers the scales through the
+    table, [S, mp, H]): every step that works starts its successor's
+    copies (the slot's next written block, else block 0 of the next
+    slot that holds anything) before it waits for its own, so a fetch
+    runs under the step before it, across slots too; only the call's
+    first written block starts its own. So a block wholly past the
+    written pages neither fetches nor computes (`pl.when` skips it: such
+    a step costs the grid's own 0.14 us); in the slot's last written
+    block the pages past the last written one are not fetched, keep
+    what the buffer held (zeros at first, then older pages: finite) and
+    are masked by the causal test, adding exact zeros. An `mp` that `P`
+    does not divide needs nothing more: the rows past it are past every
+    length. The block axis is the
     reduction: running m and l ([T, H], a value a head) and acc
     ([T, H * d]) live in VMEM scratch and the normalised output is
-    written at the last page. The `T` rows go through every product as
-    ONE block ([T * psz, H * d] against the page), so the body traces to
-    the same equations at any `T`. With the heads on the lanes a head's
-    logit is a sum over its own `d` lanes: a product with a thin 0/1
-    matrix ([H * d, H]), and one back ([H, H * d]) spreads a head's
-    weight over its lanes — `_dot_indicator`, float32 sums of float32
-    products throughout."""
+    written at the last block. Both axes are "arbitrary": the buffers'
+    turn and the copies in flight pass from slot to slot (SMEM). The `T` rows
+    and the `P` pages go through every product as ONE block
+    ([T * P * psz, H * d]), so the body traces to the same equations at
+    any `T` and any `P`. With the heads on the lanes a head's logit is a
+    sum over its own `d` lanes: a product with a thin 0/1 matrix
+    ([H * d, H]), and one back ([H, H * d]) spreads a head's weight
+    over its lanes — `_dot_indicator`, float32 sums of float32 products
+    throughout. `interpret` runs the same body, copies and semaphores
+    under the TPU interpreter (`pltpu.InterpretParams`)."""
     import jax
     import jax.numpy as jnp
+    import numpy as np
 
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     hd = h * d
+    P = _paged_block_pages(psz, hd, T, mp, dtype)
+    rows = P * psz
+    nblk = -(-mp // P)
+    i32 = jnp.int32
 
-    def kernel(tbl_ref, len_ref, *refs):
+    def kernel(tbl_ref, len_ref, q_ref, *refs):
         refs = list(refs)
-        q_ref, k_ref, v_ref = refs[:3]
-        refs = refs[3:]
+        pools = refs[:2]
+        refs = refs[2:]
         if has_scale:
             ks_ref, vs_ref = refs[:2]
             refs = refs[2:]
         if has_bias:
             bias_ref = refs[0]
             refs = refs[1:]
-        o_ref, m_sc, l_sc, acc_sc, sum_sc, spread_sc = refs
-        pi = pl.program_id(1)
-        start = pi * jnp.int32(psz)
-        n_valid = len_ref[pl.program_id(0)]
+        (o_ref, m_sc, l_sc, acc_sc, sum_sc, spread_sc, *bufs, sem,
+         state) = refs
+        si = pl.program_id(0)
+        bi = pl.program_id(1)
+        start = bi * i32(rows)
+        n_valid = len_ref[si]
 
-        @pl.when(pi == 0)
+        def written_pages(slot):    # lengths are >= 0: one `div`
+            return jax.lax.div(len_ref[slot] + i32(psz - 1), i32(psz))
+
+        def next_written_slot(slot):            # S where none is left
+            return jax.lax.while_loop(
+                lambda t: (t < i32(S)) & (len_ref[jnp.minimum(
+                    t, i32(S - 1))] <= 0),
+                lambda t: t + i32(1), slot)
+
+        def copies(slot, blk, buf, go):
+            # the block's written pages, a page a copy: started where
+            # `go`, else waited for
+            first = blk * i32(P)
+
+            def page(j, carry):
+                # (a wait reads the copy's size alone, not its source)
+                row = tbl_ref[slot, first + j] if go else _z()
+                for c, (pool, vm) in enumerate(zip(pools, bufs)):
+                    cp = pltpu.make_async_copy(
+                        pool.at[row], vm.at[buf, j],
+                        sem.at[np.int32(c), buf])
+                    cp.start() if go else cp.wait()
+                return carry
+
+            jax.lax.fori_loop(
+                i32(0), jnp.minimum(written_pages(slot) - first, i32(P)),
+                page, i32(0))
+
+        @pl.when((si == 0) & (bi == 0))
+        def _first():
+            # the rows a short block leaves unfetched must hold numbers
+            for vm in bufs:
+                vm[...] = jnp.zeros(vm.shape, vm.dtype)
+            state[0] = i32(0)       # the buffer whose turn it is
+            state[1] = i32(0)       # are this block's copies in flight?
+
+        @pl.when(bi == 0)
         def _init():
             m_sc[...] = jnp.full((T, h), -1e30, jnp.float32)
             l_sc[...] = jnp.zeros((T, h), jnp.float32)
             acc_sc[...] = jnp.zeros((T, hd), jnp.float32)
             # which lanes are which head's: [hd, h] sums a head's lanes,
             # [h, hd] spreads a head's value back over them. Built once
-            # a slot, not once a page (128 vregs of compares)
+            # a slot, not once a block (128 vregs of compares)
             for ref in (sum_sc, spread_sc):
                 ax = ref.shape.index(hd)
                 lane = jax.lax.broadcasted_iota(jnp.int32, ref.shape, ax)
-                lo = jax.lax.broadcasted_iota(
-                    jnp.int32, ref.shape, 1 - ax) * jnp.int32(d)
-                ref[...] = ((lane >= lo) & (lane < lo + jnp.int32(d))
+                head = jax.lax.broadcasted_iota(jnp.int32, ref.shape,
+                                                1 - ax)
+                ref[...] = (jax.lax.div(lane, i32(d)) == head
                             ).astype(jnp.bfloat16)
 
         def over_lanes(x):                      # (n, h) -> (n, hd)
             return _dot_indicator(x, spread_sc[...])
 
-        # a page entirely past the written region adds an exact zero
+        def block(vm, buf, scale_ref=None):     # (rows, hd), float32
+            xb = vm[buf].astype(jnp.float32)
+            if scale_ref is not None:           # dequantize in-kernel
+                xb = xb * over_lanes(scale_ref[...])[:, None, :]
+            return xb.reshape(rows, hd)
+
+        # a block entirely past the written region adds an exact zero
         @pl.when(start < n_valid)
         def _compute():
-            kb = k_ref[...].astype(jnp.float32)           # (psz, hd)
-            vb = v_ref[...].astype(jnp.float32)
-            if has_scale:
-                kb = kb * over_lanes(ks_ref[...])         # dequantize
-                vb = vb * over_lanes(vs_ref[...])         # in-kernel
+            buf, ahead = state[0], state[1]
+            state[0] = i32(1) - buf
+            state[1] = i32(1)
+
+            def fetch(i, at):
+                # start the copies of the written block after `at`: the
+                # slot's next, else block 0 of the next slot that holds
+                # anything
+                slot, blk = at
+                more = (blk + i32(1)) * i32(P) < written_pages(slot)
+                slot = jax.lax.select(more, slot,
+                                      next_written_slot(slot + i32(1)))
+                blk = jax.lax.select(more, blk + i32(1), i32(0))
+
+                @pl.when(slot < i32(S))
+                def _():
+                    copies(slot, blk, (buf + i + ahead) & i32(1), True)
+
+                return jnp.minimum(slot, i32(S - 1)), blk
+
+            # the block after this one; before it this one itself, where
+            # no step started it (the call's first written block)
+            jax.lax.fori_loop(i32(0), i32(2) - ahead, fetch,
+                              (si, bi - i32(1) + ahead))
+            copies(si, bi, buf, False)
+            kb = block(bufs[0], buf, ks_ref if has_scale else None)
+            vb = block(bufs[1], buf, vs_ref if has_scale else None)
             qb = q_ref[...].astype(jnp.float32) * jnp.float32(s)
-            # per-head logits of every (row, key) pair: (T, psz, h)
+            # per-head logits of every (row, key) pair: (T, rows, h)
             logits = _dot_indicator(
-                (qb[:, None, :] * kb[None]).reshape(T * psz, hd),
-                sum_sc[...]).reshape(T, psz, h)
+                (qb[:, None, :] * kb[None]).reshape(T * rows, hd),
+                sum_sc[...]).reshape(T, rows, h)
             kpos = start + jax.lax.broadcasted_iota(
-                jnp.int32, (T, psz, h), 1)
-            qpos = (n_valid - jnp.int32(T)) + jax.lax.broadcasted_iota(
-                jnp.int32, (T, psz, h), 0)
+                jnp.int32, (T, rows, h), 1)
+            qpos = (n_valid - i32(T)) + jax.lax.broadcasted_iota(
+                jnp.int32, (T, rows, h), 0)
             logits = jnp.where(kpos <= qpos, logits, jnp.float32(-1e30))
             if has_bias:
                 logits = logits + bias_ref[...][None]
-            # a row whose own position precedes the page keeps m_prev
-            # (page 0 gave every row a finite one) and adds exact zeros
+            # a row whose own position precedes the block keeps m_prev
+            # (block 0 gave every row a finite one) and adds exact zeros
             m_prev = m_sc[...]                            # (T, h)
             m_new = jnp.maximum(m_prev, logits.max(axis=1))
             alpha = jnp.exp(m_prev - m_new)
-            p = jnp.exp(logits - m_new[:, None, :])       # (T, psz, h)
+            p = jnp.exp(logits - m_new[:, None, :])       # (T, rows, h)
             l_sc[...] = alpha * l_sc[...] + p.sum(axis=1)
-            pv = over_lanes(p.reshape(T * psz, h)).reshape(T, psz, hd)
-            acc_sc[...] = over_lanes(alpha) * acc_sc[...] + (
+            # p and alpha over their heads' lanes in one product
+            wide = over_lanes(jnp.concatenate(
+                [p.reshape(T * rows, h), alpha], axis=0))
+            pv = wide[:T * rows].reshape(T, rows, hd)
+            acc_sc[...] = wide[T * rows:] * acc_sc[...] + (
                 pv * vb[None]).sum(axis=1)
             m_sc[...] = m_new
 
-        @pl.when(pi == mp - 1)
+        @pl.when(bi == nblk - 1)
         def _finish():
             # a slot of length 0 (inactive, trash-mapped) never
             # computes: l = 0, and the floor keeps its output finite
             o_ref[...] = acc_sc[...] / over_lanes(
                 jnp.maximum(l_sc[...], jnp.float32(1e-30)))
 
-    def live_page(si, pi, lens):
-        # logical page, clamped to the slot's last written one
-        last = jnp.maximum(lens[si] - jnp.int32(1),
-                           jnp.int32(0)) // jnp.int32(psz)
-        return jnp.minimum(pi, last)
-
-    def page_ix(si, pi, tbl, lens):
-        return (tbl[si, live_page(si, pi, lens)], _z(), _z())
-
-    def q_ix(si, pi, *_):
+    def q_ix(si, bi, *_):
         return (si, _z(), _z())
 
-    in_specs = [
-        pl.BlockSpec((None, T, hd), q_ix),
-        pl.BlockSpec((None, psz, hd), page_ix),
-        pl.BlockSpec((None, psz, hd), page_ix),
-    ]
+    def logical_ix(si, bi, tbl, lens):
+        # scales [S, blocks, P, h] and bias [S, blocks x rows, 1] live
+        # in LOGICAL per-slot coordinates: block by (slot, logical
+        # block), no table dereference, clamped to the slot's last
+        # written block (resident: no copy); the heads and the query
+        # rows of a step share the bias
+        last = jnp.maximum(lens[si] - i32(1), i32(0)) // i32(rows)
+        return (si, jnp.minimum(bi, last), _z())
+
+    pool = pl.BlockSpec(memory_space=pl.ANY)
+    in_specs = [pl.BlockSpec((None, T, hd), q_ix), pool, pool]
     if has_scale:
-        in_specs.append(pl.BlockSpec((None, 1, h), page_ix))
-        in_specs.append(pl.BlockSpec((None, 1, h), page_ix))
+        in_specs += [pl.BlockSpec(
+            (None, None, P, h),
+            lambda *a: logical_ix(*a) + (_z(),))] * 2
     if has_bias:
-        # bias lives in LOGICAL per-slot coordinates [S, L, 1]: block
-        # by (slot, logical page), no table dereference; the heads and
-        # the query rows of a step share it
-        in_specs.append(pl.BlockSpec(
-            (None, psz, 1),
-            lambda si, pi, tbl, lens: (si, live_page(si, pi, lens),
-                                       _z())))
+        in_specs.append(pl.BlockSpec((None, rows, 1), logical_ix))
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2, grid=(S, mp),
+        num_scalar_prefetch=2, grid=(S, nblk),
         in_specs=in_specs,
         out_specs=pl.BlockSpec((None, T, hd), q_ix),
         scratch_shapes=[pltpu.VMEM((T, h), jnp.float32),
                         pltpu.VMEM((T, h), jnp.float32),
                         pltpu.VMEM((T, hd), jnp.float32),
                         pltpu.VMEM((hd, h), jnp.bfloat16),
-                        pltpu.VMEM((h, hd), jnp.bfloat16)])
-    return pl.pallas_call(
+                        pltpu.VMEM((h, hd), jnp.bfloat16),
+                        pltpu.VMEM((2, P, psz, hd), jnp.dtype(dtype)),
+                        pltpu.VMEM((2, P, psz, hd), jnp.dtype(dtype)),
+                        pltpu.SemaphoreType.DMA((2, 2)),
+                        pltpu.SMEM((2,), jnp.int32)])
+    call = pl.pallas_call(
         kernel, grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((S, T, hd), jnp.float32),
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary")),
-        interpret=interpret,
+            dimension_semantics=("arbitrary", "arbitrary")),
+        interpret=pltpu.InterpretParams() if interpret else False,
         name="paged_flash_decode" if T == 1 else "paged_flash_verify")
+
+    def whole_blocks(x, n):
+        # [S, mp * n, ...] -> [S, nblk * P * n, ...]: zeros past `mp`
+        # pages where `P` does not divide them
+        return jnp.pad(x, ((0, 0), (0, (nblk * P - mp) * n))
+                       + ((0, 0),) * (x.ndim - 2))
+
+    def paged_call(table, length, q, k_pages, v_pages, *rest):
+        args = [q, k_pages, v_pages]
+        if has_scale:
+            args += [whole_blocks(sc[table][:, :, 0], 1).reshape(
+                S, nblk, P, h) for sc in rest[:2]]
+        if has_bias:
+            args.append(whole_blocks(rest[-1], psz))
+        return call(table, length, *args)
+
+    return paged_call
 
 
 @functools.lru_cache(maxsize=None)
@@ -1916,7 +2069,7 @@ def _paged_flash_jit(scale, interpret):
         s = scale if scale is not None else 1.0 / math.sqrt(d)
         call = _paged_flash_call(S, h, mp, psz, d, T, s,
                                  k_scale is not None, bias is not None,
-                                 interpret)
+                                 interpret, k_pages.dtype)
         # the query and the output in the pages' row form, [S, T, H * d]
         args = [jnp.swapaxes(q, 1, 2).reshape(S, T, h * d), k_pages,
                 v_pages]
@@ -1965,9 +2118,9 @@ def paged_decode_attention(q, k_pages, v_pages, k_scale, v_scale, table,
     use_kernel = interpret or (
         _on_tpu() and _paged_kernel_fits(psz, h * d) and _flash_usable())
     if use_kernel and not interpret:
-        # dispatch-level tuning knob: the kernel's one block is the
-        # page, but a device tier can force the XLA gather path where
-        # the kernel loses
+        # dispatch-level tuning knob: the kernel's block of pages comes
+        # from the shapes, but a device tier can force the XLA gather
+        # path where the kernel loses
         cfg = _tuned("paged_flash_decode", (d, psz, str(k_pages.dtype)))
         if cfg is not None and not cfg.get("kernel", True):
             use_kernel = False

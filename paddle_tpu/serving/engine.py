@@ -2106,6 +2106,7 @@ class PagedServingEngine(ServingEngine):
         # reserve_decode_frac=1.0 is a no-OOM guarantee
         self._slot_pages_total = np.zeros(self.num_slots, np.int64)
         self._page_bytes = None
+        self._pages_per_block = 1    # set with the pool (grid step's pages)
         self._pool_total_bytes = None  # ledger cache (watermark path)
         self._prefix_params = None   # param identity the cache is
         #                              valid for (see _check_params)
@@ -2162,6 +2163,7 @@ class PagedServingEngine(ServingEngine):
         storage, quantized = resolve_kv_dtype(
             self.kv_dtype, jnp.dtype(self._np_dtype))
         self._page_bytes = self.driver.page_row_bytes(storage, quantized)
+        self._pages_per_block = self._decode_block_pages(storage)
         self._pool_total_bytes = self.pool_bytes()
         self.metrics.set_cache_bytes({
             kind: _tree_bytes(self._state.get(kind))
@@ -2169,6 +2171,11 @@ class PagedServingEngine(ServingEngine):
         if self.metrics.budget_bytes > 0:
             self.metrics.check_memory_watermark(
                 self.weights_bytes() + self.pool_in_use_bytes())
+
+    def _decode_block_pages(self, storage):
+        """Pages a grid step of the decode call takes over this pool
+        (1 where it takes the gather)."""
+        return self.driver.pages_per_block(storage)
 
     def _count_cache(self, **counts):
         """Add to this iteration's cache counts (state_resets,
@@ -2289,10 +2296,14 @@ class PagedServingEngine(ServingEngine):
         written = [int(self._index[s])
                    for s, r in enumerate(self.slots) if r is not None]
         active_toks = sum(written)
-        # the (slot, page) steps of a paged decode call that do work
+        # the written pages a paged decode call reads, and the grid
+        # steps (blocks of `pages_per_block` pages) that read them
+        live = [pages_for(n, self.page_size) for n in written]
         gauges.update({
-            "live_pages": sum(pages_for(n, self.page_size)
-                              for n in written),
+            "live_pages": sum(live),
+            "live_blocks": sum(-(-n // self._pages_per_block)
+                               for n in live),
+            "pages_per_block": self._pages_per_block,
             "table_entries": self.num_slots * self.max_pages})
         if active_toks and self._page_bytes:
             gauges["bytes_per_active_token"] = \

@@ -148,6 +148,18 @@ class DecoderStackDriver:
         scale_b = h0.num_heads * 4 if quantized else 0
         return 2 * len(decoder.layers) * (per_buf + scale_b)
 
+    def pages_per_block(self, storage):
+        """The pages a grid step of the stack's paged decode call takes
+        (`ops.attention.paged_decode_block_pages`: from the pool's
+        shapes and page dtype alone)."""
+        from ..ops import attention as A
+
+        eng = self.eng
+        h0 = eng._net.decoder.layers[0].self_attn
+        return A.paged_decode_block_pages(
+            eng.page_size, h0.num_heads * h0.head_dim, eng.max_pages,
+            storage)
+
     def prefill(self, params, buffers, prompt, length, memory, bias_row,
                 Pb, ad):
         """-> (logits [1, Pb, V], {"paged": [(k, v) [1, H, Pb, D]],
@@ -304,6 +316,11 @@ class CausalLMDriver:
         n = sum(k == "paged" for k in self.cache_kinds())
         return 2 * n * self.eng.page_size * cfg.num_key_value_heads \
             * cfg.head_dim * jnp.dtype(storage).itemsize
+
+    def pages_per_block(self, storage):
+        """1: the step gathers block 17's pages, no page-table kernel
+        reads grouped heads."""
+        return 1
 
     def prefill(self, params, buffers, prompt, length, memory, bias_row,
                 Pb, ad):

@@ -110,13 +110,26 @@ SNAPSHOT_DOCS = {
                    "is the mean share of the pool in use"),
     "paging.table_entries_total": (
         "gauge", "page-table entries: slots x max pages a slot, the "
-                 "(slot, page) grid steps of one paged decode call"),
+                 "pages one paged decode call could read (its grid is "
+                 "slots x ceil(max pages / pages_per_block) steps)"),
     "paging.live_page_iterations": (
         "counter", "written pages of the occupied slots, "
                    "sum of ceil(written / page_size), summed over "
                    "iterations: its growth / (iterations x "
-                   "table_entries_total) is the share of a paged "
-                   "decode call's grid steps that fetch and compute"),
+                   "table_entries_total) is the share of the table's "
+                   "pages that a paged decode call fetches and "
+                   "computes on"),
+    "paging.pages_per_block": (
+        "gauge", "consecutive logical pages one grid step of the "
+                 "paged decode call takes (from the pool's shapes and "
+                 "page dtype; 1 where the pool takes the gather)"),
+    "paging.live_block_iterations": (
+        "counter", "grid steps of a paged decode call that fetch and "
+                   "compute, sum over the occupied slots of "
+                   "ceil(written pages / pages_per_block), summed over "
+                   "iterations: live_page_iterations' growth over its "
+                   "growth x pages_per_block is the share of the page "
+                   "rows those steps take that hold written tokens"),
     "paging.prefix_hits": ("counter",
                            "joins served from the prefix cache"),
     "paging.prefix_misses": ("counter", "joins that ran a real prefill"),
@@ -475,6 +488,8 @@ class ServingMetrics:
                              "prefill_tokens": 0}
         self.page_iterations = 0    # sum over iterations of pages_in_use
         self.live_page_iterations = 0   # ... of the slots' WRITTEN pages
+        self.live_block_iterations = 0  # ... of the grid steps over them
+        self.pages_per_block = None     # pages a decode grid step takes
         self.table_entries_total = None     # slots x max pages a slot
         self.prefix_hits = 0        # joins served from the prefix cache
         self.prefix_misses = 0      # joins that ran a real prefill
@@ -973,7 +988,8 @@ class ServingMetrics:
                          pages_free=None, bytes_per_active_token=None,
                          shard_occupancy=None, tenant_slots=None,
                          trie_nodes=None, trie_pages=None,
-                         live_pages=None, table_entries=None, cache=None):
+                         live_pages=None, table_entries=None, cache=None,
+                         live_blocks=None, pages_per_block=None):
         with self._lock:
             self.iterations += 1
             for k, v in (cache or {}).items():
@@ -989,6 +1005,9 @@ class ServingMetrics:
             if live_pages is not None:
                 self.live_page_iterations += int(live_pages)
                 self.table_entries_total = int(table_entries)
+            if live_blocks is not None:
+                self.live_block_iterations += int(live_blocks)
+                self.pages_per_block = int(pages_per_block)
             if pages_free is not None:
                 self.pages_free = int(pages_free)
             if trie_nodes is not None:
@@ -1169,6 +1188,8 @@ class ServingMetrics:
                     "page_iterations": self.page_iterations,
                     "table_entries_total": self.table_entries_total,
                     "live_page_iterations": self.live_page_iterations,
+                    "pages_per_block": self.pages_per_block,
+                    "live_block_iterations": self.live_block_iterations,
                     "prefix_hits": self.prefix_hits,
                     "prefix_misses": self.prefix_misses,
                     "prefix_hit_rate": round(

@@ -756,6 +756,11 @@ class ShardedPagedServingEngine(ShardedServingEngine, PagedServingEngine):
         super().__init__(decoder, embed, project, prefill="inline",
                          **kw)
 
+    def _decode_block_pages(self, storage):
+        """1: a partitioned program holds no Pallas kernel; every page
+        read here is the gather."""
+        return 1
+
     def _cross_params(self):
         if getattr(self, "_scross", None) is None:
             import jax

@@ -60,7 +60,10 @@ Span catalog (exported Chrome-trace names):
                   profiler's events leaves out an `iteration` that
                   holds no decode.step and no join; attrs: joins, n_active,
                   occupancy, queue_depth, the page-pool and shard
-                  gauges, t0_perf_ns; cache — this iteration's
+                  gauges — live_pages and live_blocks among them: the
+                  written pages a paged decode call reads and its grid
+                  steps of pages_per_block pages that read them —,
+                  t0_perf_ns; cache — this iteration's
                   state_resets, prefill_tokens and ring_wraps, where
                   any —; step — "ahead" where the
                   iteration's step was enqueued before the last one's

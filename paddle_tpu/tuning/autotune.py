@@ -122,8 +122,8 @@ def candidates(kernel, key):
         return [{"split_k": n} for n in SPLIT_LADDER
                 if L % n == 0 and (L // n) % 128 == 0]
     if kernel == "paged_flash_decode":
-        # dispatch-level knob only: the grid is (slot, page), a step
-        # the whole page with all its heads
+        # dispatch-level knob only: the grid is (slot, block of pages),
+        # the block from the shapes (`_paged_block_pages`)
         return [{"kernel": True}, {"kernel": False}]
     if kernel == "paged_flash_verify":
         # the kernel grid is fixed by the pages, so kernel-on has no

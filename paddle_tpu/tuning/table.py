@@ -34,8 +34,8 @@ Kernels and their tunable knobs:
     paged_flash_decode      {"kernel": bool}  — dispatch-level: force
                             the XLA gather path on devices where the
                             scalar-prefetch kernel loses (the grid is
-                            (slot, page), a step the whole page with
-                            all its heads: no shape knob exists)
+                            (slot, block of pages), the block from
+                            the shapes alone: no shape knob exists)
     paged_flash_verify      {"kernel": bool, "split_k"} — the paged
                             speculative verify: kernel-on (grid fixed
                             by the pages) or gather + the dense verify

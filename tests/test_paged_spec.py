@@ -87,27 +87,36 @@ def _assert_leak_free(eng):
 # kernel layer: paged verify parity + k-wide page writes
 # ----------------------------------------------------------------------
 
-@pytest.mark.parametrize("kv_dtype,T,with_bias", [
-    ("f32", 4, True), ("f32", 2, False), ("int8", 4, True),
-    ("bf16", 4, True), ("bf16", 2, False), ("int8", 2, False),
-    ("f32", 16, True),          # a tail prefill's block: over two pages
+@pytest.mark.parametrize("kv_dtype,T,with_bias,mp", [
+    ("f32", 4, True, 4), ("f32", 2, False, 4), ("int8", 4, True, 4),
+    ("bf16", 4, True, 4), ("bf16", 2, False, 4), ("int8", 2, False, 4),
+    ("f32", 16, True, 4),       # a tail prefill's block: over two pages
+    # a table of 11 pages in grid steps of 8 (which do not divide it),
+    # the written lengths at the block's edges
+    ("f32", 4, True, 11), ("f32", 4, False, 11), ("int8", 4, True, 11),
+    ("int8", 4, False, 11), ("bf16", 4, True, 11), ("f32", 16, True, 11),
 ])
-def test_paged_flash_verify_interpret_parity(kv_dtype, T, with_bias):
+def test_paged_flash_verify_interpret_parity(kv_dtype, T, with_bias, mp):
     """The page-table verify kernel (interpret mode on CPU) must
     reproduce gather + the dense verify reference — fp32 exactly to
     float tolerance, bf16 and int8 pages through the same widening and
     per-(page, head) dequant. The written lengths (after the T-token
     write) cover the block alone, a page boundary, one past it, and the
-    whole table."""
+    whole table; over the long table, where a grid step takes a block
+    of P = 8 pages: one short of the block, the block, one past it."""
     import jax.numpy as jnp
 
     from paddle_tpu.ops import attention as A
     from paddle_tpu.serving.paging import quantize_chunks, resolve_kv_dtype
 
     rs = np.random.RandomState(0)
-    S, h, d, psz, mp = 4, 2, 8, 8, 4
-    n_pages = S * mp
+    h, d, psz = 2, 8, 8
     L = mp * psz
+    lens = ([T, 2 * psz, 2 * psz + 1, L] if mp == 4 else
+            [T, max(T, psz), 8 * psz - 1, 8 * psz, 8 * psz + 1, L])
+    assert A._paged_block_pages(psz, h * d, T, mp, "float32") == min(mp, 8)
+    S = len(lens)
+    n_pages = S * mp
     storage, quantized = resolve_kv_dtype(
         None if kv_dtype == "f32" else kv_dtype, jnp.float32)
     kp, ks = quantize_chunks(
@@ -118,7 +127,7 @@ def test_paged_flash_verify_interpret_parity(kv_dtype, T, with_bias):
         storage, quantized, h)
     table = jnp.asarray(
         rs.permutation(n_pages).reshape(S, mp), jnp.int32)
-    length = jnp.asarray([T, 2 * psz, 2 * psz + 1, L], jnp.int32)
+    length = jnp.asarray(lens, jnp.int32)
     q = jnp.asarray(rs.randn(S, h, T, d), jnp.float32)
     bias = (jnp.asarray(rs.randn(S, L), jnp.float32) * 0.1
             if with_bias else None)

@@ -342,12 +342,20 @@ def test_gather_pages_reproduces_dense_exactly():
 # table: two slots map ONE physical first page, and a third maps a
 # `copy_page` duplicate of it (the copy-on-write a joiner decodes into).
 # "cell" is the benchmark pool's page: 16 heads of 64 on 1,024 lanes.
+# A grid step takes a block of P pages (`_paged_block_pages`): "blocks"
+# is a table of 11 pages that a block of 8 does not divide, the written
+# lengths at the block's edges (nothing, one token, a page, one short of
+# the block, the block, one past it, the whole table); "wide" is two
+# blocks of 2 over 3 pages.
 _FLASH_SHAPES = {
     "pool": ((5, 2, 16, 4, 8), [0, 1, 16, 33, 64]),
     "shared": ((5, 2, 16, 4, 8), [0, 1, 16, 33, 64]),
     "wide": ((2, 4, 32, 3, 256), [40, 96]),
     "cell": ((3, 16, 16, 3, 64), [0, 17, 48]),
+    "blocks": ((7, 2, 8, 11, 64), [0, 1, 8, 63, 64, 65, 88]),
 }
+_FLASH_BLOCK_PAGES = {"pool": 4, "shared": 4, "wide": 2, "cell": 2,
+                      "blocks": 8}
 
 
 @pytest.mark.parametrize("shape", sorted(_FLASH_SHAPES))
@@ -364,7 +372,10 @@ def test_paged_flash_decode_interpret_parity(kv, with_bias, shape):
     from paddle_tpu.ops import attention as A
 
     (S, H, psz, mp, D), lens = _FLASH_SHAPES[shape]
-    assert A._paged_kernel_fits(psz, H * D) == (shape in ("wide", "cell"))
+    assert A._paged_kernel_fits(psz, H * D) == (
+        shape in ("wide", "cell", "blocks"))
+    assert A._paged_block_pages(psz, H * D, 1, mp, kv) == \
+        _FLASH_BLOCK_PAGES[shape]
     rs = np.random.RandomState(3)
     N = S * mp + 2
     table = rs.permutation(N)[:S * mp].reshape(S, mp).astype(np.int32)

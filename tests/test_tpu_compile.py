@@ -107,6 +107,12 @@ def test_paged_flash_kernels_compile(one_chip, kv_dtype, T):
     # the pool of the smoke must fit the chip with room for the model
     mem = compiled.memory_analysis()
     assert mem.temp_size_in_bytes < 2 * 2**30
+    # a grid step takes a block of P pages: (S, ceil(mp / P)) steps
+    P = A._paged_block_pages(psz, h * d, T, mp, kv_dtype)
+    assert P == 8 and _paged_grid(
+        fn, ((S, h, T, d), jnp.float32), pages, pages, scale, scale,
+        ((S, mp), jnp.int32), ((S,), jnp.int32),
+        ((S, L), jnp.float32)) == (S, -(-mp // P))
 
 
 # the benchmark's pool (`bart_large_dec`: 64 slots x 1024 positions,
@@ -118,14 +124,39 @@ BENCH_POOL = dict(S=64, h=16, L=1024, d=64, psz=16)
 BENCH_POOL_TEMP_LIMIT = 64 * 2**20
 
 
+def _paged_grid(fn, *shapes):
+    """The grid of the one pallas_call `fn` traces to at these (shape,
+    dtype) pairs."""
+    args = [None if s is None else jax.ShapeDtypeStruct(*s)
+            for s in shapes]
+    found = []
+
+    def walk(jaxpr):
+        for eq in jaxpr.eqns:
+            if eq.primitive.name == "pallas_call":
+                found.append(tuple(eq.params["grid_mapping"].grid))
+            for sub in _sub_programs(eq):
+                walk(sub)
+
+    walk(jax.make_jaxpr(fn)(*args).jaxpr)
+    assert len(found) == 1, found
+    return found[0]
+
+
 def test_paged_flash_decode_compiles_at_benchmark_shapes(one_chip):
     S, h, L, d, psz = (BENCH_POOL[k] for k in ("S", "h", "L", "d", "psz"))
     mp = L // psz
     pages = ((S * mp + 1, psz, h * d), jnp.float32)
-    compiled = _compile(A.paged_flash_decode, one_chip,
-                        ((S, h, 1, d), jnp.float32), pages, pages, None,
-                        None, ((S, mp), jnp.int32), ((S,), jnp.int32),
-                        ((S, L), jnp.float32))
+    shapes = (((S, h, 1, d), jnp.float32), pages, pages, None, None,
+              ((S, mp), jnp.int32), ((S,), jnp.int32),
+              ((S, L), jnp.float32))
+    compiled = _compile(A.paged_flash_decode, one_chip, *shapes)
+    # 512 grid steps a call, not one a (slot, page): a return to a
+    # page a step fails here and not only in the benchmark
+    P = A._paged_block_pages(psz, h * d, 1, mp, "float32")
+    assert P == A._PAGED_BLOCK_PAGES == 8
+    assert _paged_grid(A.paged_flash_decode, *shapes) == (S, mp // P) \
+        == (64, 8)
     calls = [ln for ln in compiled.as_text().splitlines()
              if "custom-call(" in ln and "tpu_custom_call" in ln]
     assert len(calls) == 1
@@ -259,6 +290,16 @@ def test_bench_pool_programs_copy_no_pool(bench_pool_programs, program):
             BENCH_PSTEP_TEMP_LIMIT
 
 
+def _sub_programs(eqn):
+    """The jaxprs an equation holds: a kernel body, an inner jit, the
+    bodies of a loop or a branch."""
+    for v in eqn.params.values():
+        for sub in (v if isinstance(v, (list, tuple)) else (v,)):
+            sub = getattr(sub, "jaxpr", sub)
+            if hasattr(sub, "eqns"):
+                yield sub
+
+
 def _traced_equations(jaxpr, seen):
     """Equations a trace paid for: every equation of the program and of
     each DISTINCT sub-program it holds (kernel bodies, inner jits, loop
@@ -266,14 +307,9 @@ def _traced_equations(jaxpr, seen):
     if id(jaxpr) in seen:
         return 0
     seen.add(id(jaxpr))
-    n = len(jaxpr.eqns)
-    for eqn in jaxpr.eqns:
-        for v in eqn.params.values():
-            for sub in (v if isinstance(v, (list, tuple)) else (v,)):
-                sub = getattr(sub, "jaxpr", sub)
-                if hasattr(sub, "eqns"):
-                    n += _traced_equations(sub, seen)
-    return n
+    return len(jaxpr.eqns) + sum(
+        _traced_equations(sub, seen)
+        for eqn in jaxpr.eqns for sub in _sub_programs(eqn))
 
 
 def test_pattach_traces_no_larger_than_pjoin(bench_pool_programs):
@@ -492,6 +528,41 @@ _PAGED_GATE = {
     "page_over_1MiB": ((16, 64, 512, 1), False),
     "rows_block_over_4MiB": ((16, 64, 16, 128), False),
 }
+
+
+# (page size, heads x head size, query rows, pages a slot, page dtype)
+# -> the pages a grid step takes. `_paged_block_pages` reads these and
+# nothing else: no tuning key, environment variable or argument.
+_PAGED_BLOCK = {
+    "bench_decode": ((16, 1024, 1, 64, "float32"), 8),
+    "bench_verify_8_rows": ((16, 1024, 8, 64, "float32"), 8),
+    "bench_tail_16_rows": ((16, 1024, 16, 64, "float32"), 4),
+    "bench_tail_64_rows": ((16, 1024, 64, 64, "float32"), 1),
+    "bench_int8": ((16, 1024, 1, 64, "int8"), 8),
+    "smoke_pool": ((16, 512, 4, 64, "float32"), 8),
+    "short_table": ((16, 1024, 1, 3, "float32"), 2),    # P <= mp
+    "one_page_slots": ((16, 1024, 1, 1, "float32"), 1),
+    "table_of_11": ((8, 128, 1, 11, "float32"), 8),     # not a divisor
+    "wide_rows": ((32, 4096, 1, 64, "float32"), 2),     # 512 KiB a page
+    "wide_rows_bf16": ((32, 4096, 1, 64, "bfloat16"), 4),
+    "page_of_1MiB": ((64, 4096, 1, 64, "float32"), 1),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_PAGED_BLOCK))
+def test_paged_block_pages_reads_shapes_and_dtype_only(case):
+    (psz, hd, T, mp, dtype), want = _PAGED_BLOCK[case]
+    P = A._paged_block_pages(psz, hd, T, mp, dtype)
+    assert P == want
+    assert P & (P - 1) == 0 and 1 <= P <= min(mp, A._PAGED_BLOCK_PAGES)
+    # the block of the T rows against the P pages, and a K or V block
+    # as stored, within what the constants give a grid step
+    assert T * P * 4 * psz * hd <= A._PAGED_BLOCK_BYTES or P == 1
+    assert P * psz * hd * np.dtype(dtype).itemsize <= A._PAGED_PAGE_BYTES
+    # the engine's gauge reads the same rule at one query row
+    assert A.paged_decode_block_pages(psz, hd, mp, dtype) == \
+        A._paged_block_pages(psz, hd, 1, mp, dtype)
+    assert A.paged_decode_block_pages(12, hd, mp, dtype) == 1   # gather
 
 
 @pytest.mark.parametrize("case", sorted(_PAGED_GATE))

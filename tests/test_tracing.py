@@ -1024,6 +1024,78 @@ def test_live_page_iterations_counts_the_written_pages():
         == want
 
 
+def test_live_block_iterations_counts_the_grid_steps_that_work():
+    """`paging.live_block_iterations` grows each iteration by the sum
+    over occupied slots of ceil(written pages / pages_per_block): the
+    grid steps of a paged decode call that fetch and compute.
+    `paging.pages_per_block` is the decode call's block from the pool's
+    shapes (2 heads of 64 on 128 lanes, 8-token pages, 4 pages a slot:
+    4), `live_blocks` rides the `iteration` span, and the benchmark's
+    `snapshot_ratio` over the three keys reads what the
+    `block_fill_share.*` files declare: written pages over the page
+    rows the working steps take."""
+    import os
+
+    from benchmark.readers import snapshot_ratio
+    from paddle_tpu.ops import attention as A
+    from paddle_tpu.serving.paging import pages_for
+
+    dec, embed, proj, D, V = _small_stack(seed=221, D=128)
+    eng = ServingEngine(dec, embed, proj, num_slots=3, max_len=32,
+                        paged=True, page_size=8)
+    sched = Scheduler(max_queue=4)
+    rs = np.random.RandomState(5)
+    for prompt, n_new in (([0, 3, 5], 14), ([0, 2, 4, 6, 7, 9, 3, 5, 8],
+                                            6)):
+        sched.submit(Request(np.asarray(prompt, np.int32),
+                             rs.randn(4, D).astype("f4"),
+                             max_new_tokens=n_new, eos_id=None))
+    blocks = pages = 0
+    opened = None
+    with T.session_scope() as tr:
+        while sched.depth() > 0 or eng.occupancy() > 0:
+            eng.run_iteration(sched)
+            if opened is None:
+                opened = eng.metrics.snapshot()
+            written = [pages_for(int(eng._index[s]), 8)
+                       for s, r in enumerate(eng.slots) if r is not None]
+            pages += sum(written)
+            blocks += sum(-(-n // 4) for n in written)
+    pg = eng.metrics.snapshot()["paging"]
+    assert pg["pages_per_block"] == 4 == A._paged_block_pages(
+        8, 128, 1, eng.max_pages, "float32")
+    assert pg["live_block_iterations"] == blocks > 0
+    assert pg["live_page_iterations"] == pages > blocks
+    roots = [sp for sp in tr.spans() if sp.name == "iteration"]
+    assert sum(sp.attrs["live_blocks"] for sp in roots) == blocks
+    assert all(sp.attrs["pages_per_block"] == 4 for sp in roots)
+    for suffix in ("sat", "chat", "steady"):
+        with open(os.path.join(
+                os.path.dirname(__file__), "..", "benchmark",
+                "layer_metrics", f"block_fill_share.{suffix}.json")) as f:
+            doc = json.load(f)
+        assert doc["reader"] == "snapshot_ratio"
+        got = snapshot_ratio.read(
+            {"snapshot_open": opened,
+             "snapshot_close": eng.metrics.snapshot()}, **doc["args"])
+        o = opened["paging"]
+        want = 100.0 * (pages - o["live_page_iterations"]) / (
+            (blocks - o["live_block_iterations"]) * 4)
+        assert got == pytest.approx(want) and 25 <= got <= 100
+    # a pool whose rows do not tile takes the gather: a block is a page
+    dec, embed, proj, D, V = _small_stack(seed=221)
+    eng = ServingEngine(dec, embed, proj, num_slots=2, max_len=32,
+                        paged=True, page_size=8)
+    sched = Scheduler(max_queue=2)
+    sched.submit(Request(np.asarray([0, 3, 5], np.int32),
+                         rs.randn(4, D).astype("f4"), max_new_tokens=4,
+                         eos_id=None))
+    eng.serve_until_idle(sched, max_iterations=50)
+    pg = eng.metrics.snapshot()["paging"]
+    assert pg["pages_per_block"] == 1
+    assert pg["live_block_iterations"] == pg["live_page_iterations"] > 0
+
+
 def test_one_function_builds_profiler_annotations():
     """`trace.annotation` is the only place in paddle_tpu that
     constructs a TraceAnnotation / StepTraceAnnotation."""
