@@ -16,6 +16,7 @@ from .layer.common import (  # noqa: F401
     Sequential, Sigmoid, Silu, SmoothL1Loss, Softmax, Softplus, Softshrink,
     Softsign, Swish, SyncBatchNorm, Tanh, Tanhshrink, Unfold, Upsample,
     UpsamplingBilinear2D, UpsamplingNearest2D)
+from .layer.mla import LatentAttention  # noqa: F401
 from .layer.moe import MoELayer, SparseMoELayer  # noqa: F401
 from .layer.ssm import Mamba1Mixer, Mamba2Mixer  # noqa: F401
 from .layer.diff_attention import (  # noqa: F401

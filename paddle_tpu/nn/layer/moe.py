@@ -19,18 +19,28 @@ from .layers import Layer
 
 class _Experts(Layer):
     """Parameter container whose PATH gives the `experts.weight_in/out`
-    names the sharding rules key on (parallel/sharding.py:59)."""
+    names the sharding rules key on (parallel/sharding.py:59). `gated`
+    adds `weight_gate` beside `weight_in`; `dtype` holds the matrices in
+    that dtype from the start (no float32 copy), each expert drawn as a
+    [d_model, d_ff] matrix of its own."""
 
-    def __init__(self, num_experts, d_model, d_ff):
+    def __init__(self, num_experts, d_model, d_ff, gated=False, dtype=None):
         super().__init__()
         import paddle_tpu.nn.initializer as I
 
+        own = dtype is not None
+        w_in = I.XavierUniform(d_model, d_ff) if own else I.XavierUniform()
+        w_out = I.XavierUniform(d_ff, d_model) if own else I.XavierUniform()
         self.weight_in = self.create_parameter(
-            [num_experts, d_model, d_ff],
-            default_initializer=I.XavierUniform())
+            [num_experts, d_model, d_ff], dtype=dtype,
+            default_initializer=w_in)
+        if gated:
+            self.weight_gate = self.create_parameter(
+                [num_experts, d_model, d_ff], dtype=dtype,
+                default_initializer=w_in)
         self.weight_out = self.create_parameter(
-            [num_experts, d_ff, d_model],
-            default_initializer=I.XavierUniform())
+            [num_experts, d_ff, d_model], dtype=dtype,
+            default_initializer=w_out)
 
 
 class MoELayer(Layer):
@@ -157,12 +167,28 @@ class _Router(Layer):
 
 
 class _SharedExpert(Layer):
-    def __init__(self, d_model, d_ff):
+    def __init__(self, d_model, d_ff, gated=False, dtype=None):
         super().__init__()
         from .common import Linear
 
         self.up_proj = Linear(d_model, d_ff, bias_attr=False)
+        if gated:
+            self.gate_proj = Linear(d_model, d_ff, bias_attr=False)
         self.down_proj = Linear(d_ff, d_model, bias_attr=False)
+        if dtype is not None:
+            for p in self.parameters():
+                p._data = p._data.astype(dtype)
+
+    def mix(self, x, act):
+        """x [..., d_model] raw -> the expert's output, raw."""
+        import jax.numpy as jnp
+
+        f32 = jnp.float32
+        pre = [jnp.dot(x, p.weight._data, preferred_element_type=f32)
+               for p in ((self.gate_proj, self.up_proj)
+                         if hasattr(self, "gate_proj")
+                         else (self.up_proj,))]
+        return act(*pre).astype(x.dtype) @ self.down_proj.weight._data
 
 
 class SparseMoELayer(Layer):
@@ -174,6 +200,13 @@ class SparseMoELayer(Layer):
         w = scaling * s[chosen] / sum(s[chosen])
         y = sum_{e chosen and held} w_e W_out[e] relu(W_in[e] u)^2
             + W_down relu(W_up u)^2           the shared expert, every token
+
+    `activation="swiglu"` makes every expert, the shared one too,
+    W_out (silu(W_gate u) * (W_in u)); `n_group` / `topk_group` limit the
+    choice to the best groups of experts (`ops.moe.route_top_k`); `dtype`
+    holds the matrices in that dtype from the start. `mix(x)` is the
+    same mathematics over raw arrays, for a serving program: it returns
+    the counters beside the result instead of leaving them in buffers.
 
     `experts_held = (first, count)` says which routed experts live here
     (default: all). The layer routes over all of them and leaves the
@@ -193,13 +226,28 @@ class SparseMoELayer(Layer):
                 "dropped_slots_val")
 
     def __init__(self, d_model, d_ff, num_experts, top_k, shared_d_ff=0,
-                 routed_scaling=1.0, experts_held=None):
+                 routed_scaling=1.0, experts_held=None, activation="relu2",
+                 n_group=1, topk_group=1, dtype=None):
         super().__init__()
         import numpy as np
 
         from ...core.tensor import Tensor
+        from ...ops import moe
 
+        if activation not in moe.ACTIVATIONS:
+            raise ValueError(f"activation {activation!r}: one of "
+                             f"{sorted(moe.ACTIVATIONS)}")
         self.num_experts, self.top_k = int(num_experts), int(top_k)
+        self.n_group, self.topk_group = int(n_group), int(topk_group)
+        if self.num_experts % self.n_group or \
+                not 1 <= self.topk_group <= self.n_group or \
+                self.topk_group * (self.num_experts // self.n_group) \
+                < self.top_k:
+            raise ValueError(
+                f"{topk_group} of {n_group} groups over {num_experts} "
+                f"experts cannot hold a token's {top_k}")
+        self.activation = activation
+        gated = moe.is_gated(activation)
         first, count = experts_held or (0, self.num_experts)
         if not 0 <= first <= first + count <= self.num_experts or count < 1:
             raise ValueError(f"experts_held {experts_held!r} is not a "
@@ -207,9 +255,10 @@ class SparseMoELayer(Layer):
         self.experts_held = (int(first), int(count))
         self.routed_scaling = float(routed_scaling)
         self.gate = _Router(self.num_experts, d_model)
-        self.experts = _Experts(int(count), d_model, d_ff)
-        self.shared_experts = (_SharedExpert(d_model, shared_d_ff)
-                               if shared_d_ff else None)
+        self.experts = _Experts(int(count), d_model, d_ff, gated, dtype)
+        self.shared_experts = (
+            _SharedExpert(d_model, shared_d_ff, gated, dtype)
+            if shared_d_ff else None)
         for name in self.COUNTERS:
             self.register_buffer(name, Tensor(np.zeros((), np.float32)),
                                  persistable=False)
@@ -218,49 +267,88 @@ class SparseMoELayer(Layer):
             Tensor(np.zeros((self.num_experts,), np.float32)),
             persistable=False)
 
+    def _routed(self, xr, gate_w, bias, w_in, w_out, w_gate=None,
+                valid=None):
+        """The routed experts over raw arrays: xr [..., d_model] ->
+        (their part of y, stats [4] float32 in `COUNTERS`' order, loads
+        [num_experts] float32). `valid` [...] bool leaves the other
+        tokens out of the routing, the experts' loops and the counts."""
+        import jax
+        import jax.numpy as jnp
+
+        from ...ops import moe
+
+        first, count = self.experts_held
+        f32 = jnp.float32
+        tokens = xr.reshape(-1, xr.shape[-1])
+        if valid is not None:
+            valid = valid.reshape(-1)
+        with jax.named_scope("router"):
+            scores = jax.nn.sigmoid(jnp.dot(
+                tokens.astype(f32), gate_w.astype(f32).T,
+                precision="highest"))
+            idx, weights = moe.route_top_k(
+                scores, bias.astype(f32), self.top_k, self.routed_scaling,
+                self.n_group, self.topk_group)
+            order, starts, counts = moe.plan_held(idx, first, count, valid)
+        y, visited = moe.routed_experts(
+            tokens, weights, w_in, w_out, order, starts, counts,
+            w_gate=w_gate, activation=self.activation)
+        held = counts.sum()
+        stats = jnp.stack([
+            held, counts.max(), held / count,
+            held - visited.sum()]).astype(f32)
+        chosen = idx[..., None] == jnp.arange(self.num_experts,
+                                              dtype=jnp.int32)
+        if valid is not None:
+            chosen = chosen & valid[:, None, None]
+        return y.reshape(xr.shape), stats, chosen.sum((0, 1)).astype(f32)
+
+    def mix(self, x, valid=None):
+        """x [..., d_model] raw -> (y raw, counts [4] int32: token-slots
+        routed (`valid` tokens x top_k), those on held experts, the
+        fullest held expert's, and held slots less the rows the experts'
+        loops counted: 0)."""
+        import jax.numpy as jnp
+
+        from ...ops import moe
+
+        e = self.experts
+        y, stats, loads = self._routed(
+            x, self.gate.weight._data,
+            self.gate.e_score_correction_bias._data, e.weight_in._data,
+            e.weight_out._data,
+            e.weight_gate._data if hasattr(e, "weight_gate") else None,
+            valid)
+        if self.shared_experts is not None:
+            y = y + self.shared_experts.mix(
+                x, moe.ACTIVATIONS[self.activation][0])
+        counts = jnp.stack([loads.sum(), stats[0], stats[1],
+                            stats[3]]).astype(jnp.int32)
+        return y, counts
+
     def forward(self, x):
         """x: [B, S, d_model] -> [B, S, d_model]."""
         from ...tensor.ops import _op
 
-        first, count = self.experts_held
-        k = self.top_k
-
-        def routed(xr, gate_w, bias, w_in, w_out):
-            import jax
-            import jax.numpy as jnp
-
-            from ...ops import moe
-
-            f32 = jnp.float32
-            tokens = xr.reshape(-1, xr.shape[-1])
-            with jax.named_scope("router"):
-                scores = jax.nn.sigmoid(jnp.dot(
-                    tokens.astype(f32), gate_w.astype(f32).T,
-                    precision="highest"))
-                idx, weights = moe.route_top_k(
-                    scores, bias.astype(f32), k, self.routed_scaling)
-                order, starts, counts = moe.plan_held(idx, first, count)
-            y, visited = moe.routed_experts(tokens, weights, w_in, w_out,
-                                            order, starts, counts)
-            held = counts.sum()
-            stats = jnp.stack([
-                held, counts.max(), held / count,
-                held - visited.sum()]).astype(f32)
-            loads = (idx[..., None] == jnp.arange(
-                self.num_experts, dtype=jnp.int32)).sum((0, 1)).astype(f32)
-            return y.reshape(xr.shape), stats, loads
-
-        y, stats, loads = _op("sparse_moe", routed, x, self.gate.weight,
+        e = self.experts
+        gate = (e.weight_gate,) if hasattr(e, "weight_gate") else ()
+        y, stats, loads = _op("sparse_moe", self._routed, x,
+                              self.gate.weight,
                               self.gate.e_score_correction_bias,
-                              self.experts.weight_in,
-                              self.experts.weight_out, n_outputs=3)
+                              e.weight_in, e.weight_out, *gate,
+                              n_outputs=3)
         for i, name in enumerate(self.COUNTERS):
             self._buffers[name]._data = stats._data[i]
         self._buffers["expert_load_val"]._data = loads._data
         if self.shared_experts is not None:
-            h = self.shared_experts.up_proj(x)
-            h = _op("relu2", _relu2, h)
-            y = y + self.shared_experts.down_proj(h)
+            sh = self.shared_experts
+            h = sh.up_proj(x)
+            if gate:
+                h = _op("swiglu", _swiglu, sh.gate_proj(x), h)
+            else:
+                h = _op("relu2", _relu2, h)
+            y = y + sh.down_proj(h)
         return y
 
 
@@ -271,3 +359,11 @@ def _relu2(t):
 
     return moe.relu2(t.astype(jnp.float32)).astype(t.dtype)
 
+
+def _swiglu(g, u):
+    import jax.numpy as jnp
+
+    from ...ops import moe
+
+    return moe.swiglu(g.astype(jnp.float32),
+                      u.astype(jnp.float32)).astype(u.dtype)

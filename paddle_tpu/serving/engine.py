@@ -613,10 +613,18 @@ class _EngineBase:
             sp = it.begin("step.readback")
         try:
             _PT_READBACK()
-            fl.toks = np.asarray(fl.toks)
+            toks = np.asarray(fl.toks)
         finally:
             if it is not None:
                 it.end(sp)
+        # what the program counted leaves it behind its tokens, in the
+        # one array this read brings to the host
+        fl.toks = toks[:self.num_slots]
+        self._take_counts(toks[self.num_slots:])
+
+    def _take_counts(self, counts):
+        """Counters a program returned behind its tokens (host array;
+        empty where the served stack counts nothing)."""
 
     def _deliver_flight(self, fl):
         """Hand a read flight's tokens to the requests it was enqueued
@@ -1077,8 +1085,10 @@ class _EngineBase:
         if it is not None:
             sp = it.begin("iter.tok0", n=len(tok0s))
         for r, tok in tok0s:
+            tok = np.asarray(tok).reshape(-1)   # the sync: [tok0, counts]
+            self._take_counts(tok[1:])
             if r.state != "DONE":     # evicted with a failed step
-                self._deliver(r, int(tok), self.clock())
+                self._deliver(r, int(tok[0]), self.clock())
         del tok0s[:]
         if it is not None:
             it.end(sp)
@@ -1159,7 +1169,8 @@ class ServingEngine(_EngineBase):
                 paged=isinstance(self, PagedServingEngine), spec_k=spec_k,
                 adapters=adapters, quantize=quantize,
                 prefill_chunk=prefill_chunk,
-                eager_fallback=eager_fallback)
+                eager_fallback=eager_fallback,
+                sharded=getattr(self, "_accepts_sharded_params", False))
         elif embed is None or project is None:
             raise ValueError(
                 "ServingEngine(decoder, embed, project): a decoder stack "
@@ -1257,8 +1268,10 @@ class ServingEngine(_EngineBase):
         self.driver = (CausalLMDriver(self) if self.causal
                        else DecoderStackDriver(self))
         # this iteration's cache counts (`_iteration_gauges` hands them
-        # to the metrics and the `iteration` span, then clears them)
+        # to the metrics and the `iteration` span, then clears them),
+        # and what its programs' expert layers counted
         self._cache_iter = {}
+        self._experts_iter = {}
         if not getattr(self, "_accepts_sharded_params", False):
             _reject_sharded_params(
                 self._fm.params(),
@@ -1280,30 +1293,42 @@ class ServingEngine(_EngineBase):
     @staticmethod
     def _refuse_for_state(model, embed, project, *, paged, spec_k,
                           adapters, quantize, prefill_chunk,
-                          eager_fallback):
-        """A stack with recurrent or ring state: every option that would
-        have to snapshot, roll back or replay such a state raises."""
+                          eager_fallback, sharded):
+        """A stack with recurrent or ring state, or with latent pages:
+        every option that would have to snapshot, roll back or replay
+        such a state, or read latent rows where a program reads K/V
+        pages, raises."""
         name = type(model).__name__
         stateful = {k for k in model.cache_kinds()
                     if k in ("recurrent", "ring")}
+        latent = "latent" in model.cache_kinds()
         if embed is not None or project is not None:
             raise ValueError(
                 f"{name} is a whole causal LM: pass it alone, without "
                 f"embed / project")
         if not paged:
             raise ValueError(
-                f"{name} keeps full-attention K/V in pages: construct "
-                f"with paged=True (the dense pool has no ring or "
+                f"{name} keeps its attention cache in pages: construct "
+                f"with paged=True (the dense pool has no latent, ring or "
                 f"recurrent state)")
-        if not stateful:
+        if sharded:
+            raise ValueError(
+                f"the sharded engine (ShardedServingEngine): it places a "
+                f"(decoder, embed, project) triple's parameters and K/V "
+                f"pools by rule, and has none for {name}'s state")
+        if not (stateful or latent):
             return
-        what = f"{name} keeps {'/'.join(sorted(stateful))} state " \
-               f"a slot"
+        what = (f"{name} keeps {'/'.join(sorted(stateful))} state a slot"
+                if stateful else
+                f"{name} keeps one latent row a token a block")
         if spec_k is not None:
             raise ValueError(
-                f"speculative decoding (spec_k={spec_k}): {what}, and a "
-                f"rejected draft is undone by moving a write index back, "
-                f"which no scan state or ring can follow")
+                f"speculative decoding (spec_k={spec_k}): {what}, and "
+                + ("a rejected draft is undone by moving a write index "
+                   "back, which no scan state or ring can follow"
+                   if stateful else
+                   "the k-token verify step reads K/V pages: no verify "
+                   "program reads latent rows"))
         if adapters is not None:
             raise ValueError(
                 f"LoRA tenants (adapters=): {what}; the adapter scope "
@@ -1318,9 +1343,13 @@ class ServingEngine(_EngineBase):
         if prefill_chunk is not None:
             raise ValueError(
                 f"chunked prefill (prefill_chunk={prefill_chunk}): "
-                f"{what}; a chunk resumes from K/V pages alone, and the "
-                f"scan state and rings of a half-read prompt are not "
-                f"carried from chunk to chunk")
+                f"{what}; "
+                + ("a chunk resumes from K/V pages alone, and the scan "
+                   "state and rings of a half-read prompt are not "
+                   "carried from chunk to chunk" if stateful else
+                   "a chunk's join would read the earlier chunks' latent "
+                   "rows through the page table, which no program does "
+                   "yet"))
         if eager_fallback:
             raise ValueError(
                 f"eager fallback (eager_fallback=True): {what}; the "
@@ -2167,7 +2196,10 @@ class PagedServingEngine(ServingEngine):
         self._pool_total_bytes = self.pool_bytes()
         self.metrics.set_cache_bytes({
             kind: _tree_bytes(self._state.get(kind))
-            for kind in ("paged", "ring", "recurrent", "static")})
+            for kind in ("paged", "latent", "ring", "recurrent",
+                         "static")})
+        if self.driver.counts:
+            self.metrics.set_expert_counters(self.driver.counts)
         if self.metrics.budget_bytes > 0:
             self.metrics.check_memory_watermark(
                 self.weights_bytes() + self.pool_in_use_bytes())
@@ -2176,6 +2208,14 @@ class PagedServingEngine(ServingEngine):
         """Pages a grid step of the decode call takes over this pool
         (1 where it takes the gather)."""
         return self.driver.pages_per_block(storage)
+
+    def _take_counts(self, counts):
+        """An expert stack's counters (`driver.counts`' order), read
+        with the tokens of the step or join that counted them: added to
+        this iteration's."""
+        for name, v in zip(self.driver.counts, counts):
+            self._experts_iter[name] = self._experts_iter.get(name, 0) \
+                + int(v)
 
     def _count_cache(self, **counts):
         """Add to this iteration's cache counts (state_resets,
@@ -2289,6 +2329,8 @@ class PagedServingEngine(ServingEngine):
                        "pages_free": self._alloc.pages_free})
         if self._cache_iter:
             gauges["cache"], self._cache_iter = self._cache_iter, {}
+        if self._experts_iter:
+            gauges["experts"], self._experts_iter = self._experts_iter, {}
         if self._prefix is not None:
             st = self._prefix.stats()
             gauges.update({"trie_nodes": st["nodes"],

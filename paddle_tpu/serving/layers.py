@@ -54,6 +54,9 @@ class DecoderStackDriver:
     stays masked for ever and generation writes on from the bucket's
     end."""
 
+    #: counters that leave a program behind its tokens: none
+    counts = ()
+
     def __init__(self, eng):
         self.eng = eng
 
@@ -163,7 +166,8 @@ class DecoderStackDriver:
     def prefill(self, params, buffers, prompt, length, memory, bias_row,
                 Pb, ad):
         """-> (logits [1, Pb, V], {"paged": [(k, v) [1, H, Pb, D]],
-        "static": [(k, v)]}): what a join splices into the slot."""
+        "static": [(k, v)]}: what a join splices into the slot, the
+        program's counters: None)."""
         import jax.numpy as jnp
 
         eng = self.eng
@@ -180,11 +184,11 @@ class DecoderStackDriver:
         last = jnp.take_along_axis(
             lg, (length - 1)[:, None, None], axis=1)[:, 0]
         return last, {"paged": [(c.k, c.v) for c in inc1],
-                      "static": static1}
+                      "static": static1}, None
 
-    def step(self, params, buffers, state, table, index, ad):
+    def step(self, params, buffers, state, table, index, ad, active):
         """One position a slot -> (logits [S, V], the state's lists that
-        changed)."""
+        changed, the program's counters: None)."""
         from . import paging as PG
 
         eng = self.eng
@@ -199,26 +203,35 @@ class DecoderStackDriver:
                 prefill=False)
         return lg[:, 0], {"paged": [
             {"k": c.k, "v": c.v, "ks": c.k_scale, "vs": c.v_scale}
-            for c in inc2]}
+            for c in inc2]}, None
 
 
 class CausalLMDriver:
     """A decoder-only causal LM that says what each of its blocks keeps
-    of a sequence (`cache_kinds()`: "recurrent", "ring", "paged" or None
-    a block) and runs `prefill` and `decode` over that state
-    (`text.models.Phi4FlashForCausalLM`). Requests carry no memory; a
-    prompt sits at positions [0, P0) and generation goes on from P0 (no
-    pad hole: a recurrent state cannot step over one)."""
+    of a sequence (`cache_kinds()`: "recurrent", "ring", "paged",
+    "latent" or None a block) and runs `prefill` and `decode` over that
+    state (`text.models.Phi4FlashForCausalLM`, `DeepseekV3ForCausalLM`).
+    Requests carry no memory; a prompt sits at positions [0, P0) and
+    generation goes on from P0 (no pad hole: a recurrent state cannot
+    step over one, and a rotary position counts from 0)."""
+
+    #: the kinds of state a model's `prefill` / `decode` hand over
+    KINDS = ("recurrent", "ring", "paged", "latent")
 
     def __init__(self, eng):
         self.eng = eng
         self.model = eng._net
         self.cfg = self.model.cfg
-        self.n_ring = self.cache_kinds().count("ring")
+        kinds = self.cache_kinds()
+        self.n_ring = kinds.count("ring")
         #: a recurrent state or a window ring a slot: nothing parks,
         #: snapshots or replays those
-        self.keeps_state = any(k in ("recurrent", "ring")
-                               for k in self.cache_kinds())
+        self.keeps_state = any(k in ("recurrent", "ring") for k in kinds)
+        #: one latent row a token a block (no K/V page program reads it)
+        self.latent = "latent" in kinds
+        #: names of the counters that leave a program behind its tokens
+        #: (an expert layer's; `snapshot()["experts"]`)
+        self.counts = tuple(getattr(self.model, "COUNTS", ()))
 
     def cache_kinds(self):
         return self.model.cache_kinds()
@@ -245,20 +258,30 @@ class CausalLMDriver:
 
     def settle_page_options(self, prefix_cache, kv_dtype):
         """The radix prefix cache and preemption park K/V pages by
-        refcount, which has no meaning for a scan state or a ring: a
-        stack that keeps either is served with the cache OFF, and asking
-        for it, for other page storage or for fewer pages than the slots
-        can fill raises."""
-        if not self.keeps_state:
+        refcount, which has no meaning for a scan state or a ring, and
+        no join reads a prefix's latent rows through the table yet: a
+        stack that keeps any of those is served with the cache OFF, and
+        asking for it, for other page storage or for fewer pages than
+        the slots can fill raises."""
+        if not (self.keeps_state or self.latent):
             return True if prefix_cache is None else bool(prefix_cache)
         eng = self.eng
         name = type(self.model).__name__
+        if self.keeps_state:
+            keeps = "a scan state and window rings a slot"
+            no_prefix = "a shared prefix's pages do not hold them"
+            no_park = (f"{name}'s scan state and rings cannot be parked "
+                       f"in pages and resumed")
+        else:
+            keeps = "one latent row a token a block"
+            no_prefix = ("no join reads a prefix's latent rows through "
+                         "the page table yet")
+            no_park = (f"resuming {name} needs a join over parked latent "
+                       f"pages, which no program does yet")
         if prefix_cache:
             raise ValueError(
-                f"prefix cache (prefix_cache=True): {name} keeps a "
-                f"scan state and window rings a slot; a shared "
-                f"prefix's pages do not hold them, so no prefix is "
-                f"reused")
+                f"prefix cache (prefix_cache=True): {name} keeps {keeps}; "
+                f"{no_prefix}, so no prefix is reused")
         if kv_dtype is not None:
             raise ValueError(
                 f"page storage (kv_dtype={kv_dtype!r}): {name}'s "
@@ -268,12 +291,12 @@ class CausalLMDriver:
                 f"an oversubscribed page pool (num_pages="
                 f"{eng.num_pages} < {eng.num_slots} slots x "
                 f"{eng.max_pages} pages): a pool that runs dry "
-                f"evicts or preempts a slot mid-sequence, and "
-                f"{name}'s scan state and rings cannot be parked in "
-                f"pages and resumed")
+                f"evicts or preempts a slot mid-sequence, and {no_park}")
         return False
 
     def count_advance(self, index, n_emit):
+        if not self.n_ring:
+            return
         # a ring wraps where a slot writes row 0 again
         at = index[n_emit > 0]
         self.eng._count_cache(ring_wraps=self.n_ring * int(
@@ -281,25 +304,44 @@ class CausalLMDriver:
 
     # ---- the pool's state ----
     def new_slot_state(self, memory, dtype):
-        return {"ring": self.new_ring(dtype),
-                "recurrent": self.new_recurrent(dtype)}
+        """The kinds beside the K/V pages that the stack keeps."""
+        kinds = {"ring": self.new_ring(dtype),
+                 "recurrent": self.new_recurrent(dtype),
+                 "latent": self.new_latent(dtype)}
+        return {k: v for k, v in kinds.items() if v}
 
     def new_paged(self, dtype):
         import jax.numpy as jnp
 
         eng, cfg = self.eng, self.cfg
+        n = self.cache_kinds().count("paged")
+        if not n:
+            return []
         width = cfg.num_key_value_heads * cfg.head_dim
         buf = jnp.zeros((eng.num_pages + 1, eng.page_size, width), dtype)
-        return [{"k": buf, "v": buf, "ks": None, "vs": None}
-                for k in self.cache_kinds() if k == "paged"]
+        return [{"k": buf, "v": buf, "ks": None, "vs": None}] * n
+
+    def new_latent(self, dtype):
+        """ONE page array a latent block, [pages + 1, page_size, row
+        width]: a token's row has no head axis and no separate V."""
+        import jax.numpy as jnp
+
+        eng = self.eng
+        n = self.cache_kinds().count("latent")
+        if not n:
+            return []
+        return [jnp.zeros((eng.num_pages + 1, eng.page_size,
+                           self.cfg.latent_row_width), dtype)] * n
 
     def new_ring(self, dtype):
         import jax.numpy as jnp
 
+        if not self.n_ring:
+            return []
         cfg = self.cfg
         z = jnp.zeros((self.eng.num_slots, cfg.sliding_window,
                        cfg.num_key_value_heads * cfg.head_dim), dtype)
-        return [(z, z) for k in self.cache_kinds() if k == "ring"]
+        return [(z, z)] * self.n_ring
 
     def new_recurrent(self, dtype):
         import jax.numpy as jnp
@@ -310,42 +352,38 @@ class CausalLMDriver:
                 for k in self.cache_kinds() if k == "recurrent"]
 
     def page_row_bytes(self, storage, quantized):
+        """Bytes of one page over every block that keeps pages: K and V
+        rows of a `paged` block, one row of a `latent` one."""
         import jax.numpy as jnp
 
-        cfg = self.cfg
-        n = sum(k == "paged" for k in self.cache_kinds())
-        return 2 * n * self.eng.page_size * cfg.num_key_value_heads \
-            * cfg.head_dim * jnp.dtype(storage).itemsize
+        cfg, kinds = self.cfg, self.cache_kinds()
+        values = 0
+        if "paged" in kinds:
+            values += 2 * kinds.count("paged") \
+                * cfg.num_key_value_heads * cfg.head_dim
+        if self.latent:
+            values += kinds.count("latent") * cfg.latent_row_width
+        return values * self.eng.page_size * jnp.dtype(storage).itemsize
 
     def pages_per_block(self, storage):
-        """1: the step gathers block 17's pages, no page-table kernel
-        reads grouped heads."""
+        """1: the step gathers its pages, no page-table kernel reads
+        grouped heads or latent rows."""
         return 1
 
     def prefill(self, params, buffers, prompt, length, memory, bias_row,
                 Pb, ad):
-        import jax.numpy as jnp
-
-        cfg = self.cfg
-        (lg, recurrent, ring, (k, v)), _ = self.eng._fm.apply(
+        (lg, new, counts), _ = self.eng._fm.apply(
             params, buffers, None, training=False, op="prefill",
             args=(prompt, length))
+        return lg, new, counts
 
-        def heads(rows):   # [1, Pb, Hkv d] -> [1, Hkv, Pb, d]
-            return jnp.swapaxes(rows.reshape(
-                1, Pb, cfg.num_key_value_heads, cfg.head_dim), 1, 2)
-
-        return lg, {"paged": [(heads(k), heads(v))], "ring": ring,
-                    "recurrent": recurrent}
-
-    def step(self, params, buffers, state, table, index, ad):
-        pages = state["paged"][0]
-        (lg, recurrent, ring, (kp, vp)), _ = self.eng._fm.apply(
+    def step(self, params, buffers, state, table, index, ad, active):
+        (lg, parts, counts), _ = self.eng._fm.apply(
             params, buffers, None, training=False, op="decode",
-            args=(state["tok"], index, state["recurrent"], state["ring"],
-                  (pages["k"], pages["v"]), table))
-        return lg, {"recurrent": recurrent, "ring": ring,
-                    "paged": [{"k": kp, "v": vp, "ks": None, "vs": None}]}
+            args=(state["tok"], index,
+                  {k: state[k] for k in self.KINDS if k in state}, table,
+                  active))
+        return lg, parts, counts
 
 
 # --------------------------------------------------------------------------
@@ -830,9 +868,11 @@ class PagedLayout(CacheLayout):
     def build_state(self, memory):
         """The pool's device state, by what each layer of the served
         stack keeps of a sequence (`driver.cache_kinds()`): `paged` (K/V
-        page arrays through the engine's table and allocator), `ring`
-        (the last `window` K/V rows a slot), `recurrent` (a convolution
-        tail and a float32 scan state a slot), and, for a stack with a
+        page arrays through the engine's table and allocator), `latent`
+        (ONE page array a block through the same table: a token's row is
+        `[c_kv | k_pe]`, no heads, no V), `ring` (the last `window` K/V
+        rows a slot), `recurrent` (a convolution tail and a float32 scan
+        state a slot), and, for a stack with a
         client-supplied memory, `static` (its cross-attention K/V a
         slot) with the memory and the pad-hole bias rows. A join writes
         every kind whole for its slot, so nothing of the slot's last
@@ -886,11 +926,11 @@ class PagedLayout(CacheLayout):
                 (kpos[None, :] < jnp.int32(Pb))
             bias_row = jnp.where(hole, jnp.float32(neg),
                                  jnp.float32(0.0))           # [1, L]
-            last, new = drv.prefill(params, buffers, prompt, length,
-                                    memory, bias_row, Pb, ad)
+            last, new, counts = drv.prefill(
+                params, buffers, prompt, length, memory, bias_row, Pb, ad)
             tok0 = last.argmax(-1).astype(jnp.int32)[0]
             new_paged = []
-            for pc, (k, v) in zip(state["paged"], new["paged"]):
+            for pc, (k, v) in zip(state["paged"], new.get("paged", ())):
                 cache = PG.PagedKVCache(pc["k"], pc["v"], pc["ks"],
                                         pc["vs"], None, None)
                 cache = MHA.paged_prompt_splice(cache, page_ids, k, v)
@@ -911,6 +951,13 @@ class PagedLayout(CacheLayout):
                         tuple(MHA.splice_rows(buf, slot, rows)
                               for buf, rows in zip(pool, fresh))
                         for pool, fresh in zip(state[kind], new[kind])]
+            if "latent" in state:
+                # a block's rows [1, Pb, W] into its one page array
+                new_state["latent"] = [
+                    PG.write_prompt_pages(pages, None, page_ids,
+                                          rows[:, None], False)[0]
+                    for pages, rows in zip(state["latent"],
+                                           new["latent"])]
             if "bias" in state:
                 new_state["bias"] = MHA.splice_rows(state["bias"], slot,
                                                     bias_row)
@@ -920,7 +967,10 @@ class PagedLayout(CacheLayout):
                 new_state = self._spec_join_rows(
                     jnp, MHA, jax, state, new_state, prompt, length,
                     Pb, slot, L)
-            return new_state, tok0
+            # an expert stack's counters leave behind token 0, to be
+            # read where it is read
+            return new_state, tok0 if counts is None else \
+                jnp.concatenate([tok0[None], counts])
 
         return join_fn
 
@@ -1185,10 +1235,14 @@ class PagedLayout(CacheLayout):
         def step_fn(params, buffers, state, table, index, *rest):
             eng.trace_counts[ck] += 1  # one per trace = one compile
             *ad, active = rest          # ad = (ids, banks) | ()
-            lg, parts = drv.step(params, buffers, state, table, index, ad)
+            lg, parts, counts = drv.step(params, buffers, state, table,
+                                         index, ad, active)
             nxt = lg.argmax(-1).astype(jnp.int32)
             nxt = jnp.where(active, nxt, state["tok"])
-            return dict(state, tok=nxt, **parts), nxt
+            # an expert stack's counters leave behind the tokens, in the
+            # one array the host reads
+            return dict(state, tok=nxt, **parts), nxt if counts is None \
+                else jnp.concatenate([nxt, counts])
 
         return step_fn
 

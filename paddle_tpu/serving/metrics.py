@@ -89,7 +89,9 @@ SNAPSHOT_DOCS = {
     # the pool's caches by kind (PR 33) — the section appears once a
     # paged pool is built
     "cache.bytes": ("gauge", "device state of the pool by kind: paged "
-                             "(K/V pages), ring (window K/V rows a slot), "
+                             "(K/V pages), latent (one row a token a "
+                             "block, no heads, no V), ring (window K/V "
+                             "rows a slot), "
                              "recurrent (convolution tails and scan "
                              "states a slot), static (cross-attention "
                              "K/V a slot)"),
@@ -99,6 +101,18 @@ SNAPSHOT_DOCS = {
                                       "kind of the slot's state written "
                                       "whole"),
     "cache.prefill_tokens": ("counter", "prompt positions prefilled"),
+    # an expert stack's counters (PR 35): summed over the expert layers
+    # of every step and join, from arrays that leave the program with
+    # its tokens — the section appears once such a pool is built
+    "experts.token_slots": ("counter", "token-slots routed (live tokens "
+                                       "x experts a token x expert "
+                                       "layers)"),
+    "experts.held_slots": ("counter", "token-slots that fell on experts "
+                                      "held here"),
+    "experts.load_max": ("counter", "the fullest held expert's "
+                                    "token-slots, a layer a program"),
+    "experts.dropped_slots": ("counter", "held slots less the rows the "
+                                         "experts' loops counted: 0"),
     # paged pools (PR 6) — the section appears once a paged engine
     # records
     "paging.pages_in_use": ("gauge", "pages mapped at last iteration"),
@@ -486,6 +500,7 @@ class ServingMetrics:
         self.cache_bytes = None
         self.cache_counts = {"ring_wraps": 0, "state_resets": 0,
                              "prefill_tokens": 0}
+        self.expert_counts = None   # {name: sum} once a pool has them
         self.page_iterations = 0    # sum over iterations of pages_in_use
         self.live_page_iterations = 0   # ... of the slots' WRITTEN pages
         self.live_block_iterations = 0  # ... of the grid steps over them
@@ -622,6 +637,13 @@ class ServingMetrics:
         static), in bytes."""
         with self._lock:
             self.cache_bytes = {k: int(v) for k, v in by_kind.items()}
+
+    def set_expert_counters(self, names):
+        """The served stack has expert layers: `names` are what its
+        programs count (the `experts` section appears)."""
+        with self._lock:
+            if self.expert_counts is None:
+                self.expert_counts = {n: 0 for n in names}
 
     def record_first_token(self, ttft_s):
         with self._lock:
@@ -989,11 +1011,15 @@ class ServingMetrics:
                          shard_occupancy=None, tenant_slots=None,
                          trie_nodes=None, trie_pages=None,
                          live_pages=None, table_entries=None, cache=None,
-                         live_blocks=None, pages_per_block=None):
+                         live_blocks=None, pages_per_block=None,
+                         experts=None):
         with self._lock:
             self.iterations += 1
             for k, v in (cache or {}).items():
                 self.cache_counts[k] = self.cache_counts.get(k, 0) + int(v)
+            for k, v in (experts or {}).items():
+                self.expert_counts[k] = self.expert_counts.get(k, 0) \
+                    + int(v)
             self.queue_depth.add(queue_depth)
             self.occupancy.add(occupancy)
             if tenant_slots is not None:
@@ -1179,6 +1205,12 @@ class ServingMetrics:
                     "ring_wraps": self.cache_counts["ring_wraps"],
                     "state_resets": self.cache_counts["state_resets"],
                     "prefill_tokens": self.cache_counts["prefill_tokens"],
+                }}),
+                **({} if self.expert_counts is None else {"experts": {
+                    "token_slots": self.expert_counts["token_slots"],
+                    "held_slots": self.expert_counts["held_slots"],
+                    "load_max": self.expert_counts["load_max"],
+                    "dropped_slots": self.expert_counts["dropped_slots"],
                 }}),
                 **({} if self.pages_in_use is None else {"paging": {
                     "pages_in_use": self.pages_in_use,

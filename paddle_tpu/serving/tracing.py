@@ -65,7 +65,10 @@ Span catalog (exported Chrome-trace names):
                   steps of pages_per_block pages that read them —,
                   t0_perf_ns; cache — this iteration's
                   state_resets, prefill_tokens and ring_wraps, where
-                  any —; step — "ahead" where the
+                  any —; experts — token_slots, held_slots, load_max
+                  and dropped_slots of the steps and joins whose
+                  tokens this iteration read, where the stack has
+                  expert layers —; step — "ahead" where the
                   iteration's step was enqueued before the last one's
                   tokens were read, else why not: idle, spec, chunk,
                   pending, preempt, retry, host —; late_slot_steps)

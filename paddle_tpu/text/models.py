@@ -438,8 +438,9 @@ class Phi4FlashForCausalLM(nn.Layer):
       congruent to r), the full layer's key and value rows. The blocks
       after it, the memory and the full layer's own output are computed
       for position length - 1 alone: none of them leaves state.
-    - `decode(tok [S], index [S], recurrent, ring, pages, table)`: one
-      position a slot, each kind of state updated in place.
+    - `decode(tok [S], index [S], state, table)`: one position a slot,
+      each kind of state (`state["recurrent" | "ring" | "paged"]`)
+      updated in place.
 
     The logits are float32."""
 
@@ -520,9 +521,10 @@ class Phi4FlashForCausalLM(nn.Layer):
         return Tensor._wrap(self._logits(x))
 
     def prefill(self, ids, length):
-        """-> (logits [b, vocab] of position length - 1, recurrent
-        [(tail, H)], ring [(k, v)] of [b, window, kv width] rows, (k, v)
-        [b, s, kv width] of the full layer, s [b, s, d_inner] ... )."""
+        """-> (logits [b, vocab] of position length - 1, {"recurrent":
+        [(tail, H)], "ring": [(k, v)] of [b, window, kv width] rows,
+        "paged": [(k, v)] of the full layer, [b, Hkv, s, d]}, the
+        program's counters: None)."""
         import jax
         import jax.numpy as jnp
 
@@ -573,7 +575,15 @@ class Phi4FlashForCausalLM(nn.Layer):
                         blk.mixer.project(a), kv[0], kv[1],
                         cfg.num_key_value_heads, length))
             x = blk.feed_forward(x + out)
-        return self._logits(x), recurrent, ring, kv
+
+        def heads(rows):   # [b, s, Hkv d] -> [b, Hkv, s, d]
+            return jnp.swapaxes(rows.reshape(
+                rows.shape[:2] + (cfg.num_key_value_heads, cfg.head_dim)),
+                1, 2)
+
+        return self._logits(x), {
+            "recurrent": recurrent, "ring": ring,
+            "paged": [(heads(kv[0]), heads(kv[1]))]}, None
 
     @staticmethod
     def _ring_step(ring, rows, at, k, v):
@@ -585,11 +595,12 @@ class Phi4FlashForCausalLM(nn.Layer):
                rv.at[rows, at].set(v.astype(rv.dtype)))
         return new, new
 
-    def decode(self, tok, index, recurrent, ring, pages, table):
+    def decode(self, tok, index, state, table, active=None):
         """tok, index [S]: the token each slot feeds and the position it
-        stands at. recurrent [(tail, H)], ring [(k, v)] [S, window, kv
-        width], pages (k, v) [N + 1, psz, kv width] with `table` [S,
-        max_pages]. -> (logits [S, vocab], recurrent, ring, pages)."""
+        stands at. state: "recurrent" [(tail, H)], "ring" [(k, v)] [S,
+        window, kv width], "paged" [{"k", "v"}] [N + 1, psz, kv width]
+        with `table` [S, max_pages]. -> (logits [S, vocab], the same
+        kinds as the step leaves them, None)."""
         import jax
         import jax.numpy as jnp
 
@@ -602,7 +613,8 @@ class Phi4FlashForCausalLM(nn.Layer):
         x = self.embed_tokens._data[tok]
         rows = jnp.arange(tok.shape[0])
         new_rec, new_ring = [], []
-        rec, rng = iter(recurrent), iter(ring)
+        rec, rng = iter(state["recurrent"]), iter(state["ring"])
+        pages = (state["paged"][0]["k"], state["paged"][0]["v"])
         memory = None
         for blk in self.layers:
             a = blk.pre(x)
@@ -635,4 +647,309 @@ class Phi4FlashForCausalLM(nn.Layer):
                     out = blk.mixer.finish(read(blk.mixer.project(a),
                                                 index + 1))
             x = blk.feed_forward(x + out)
-        return self._logits(x), new_rec, new_ring, pages
+        return self._logits(x), {
+            "recurrent": new_rec, "ring": new_ring,
+            "paged": [{"k": pages[0], "v": pages[1], "ks": None,
+                       "vs": None}]}, None
+
+
+# --------------------------------------------------------------------------
+# DeepSeek-V3 (`model_type` "deepseek_v3"; arXiv:2412.19437). Pre-norm
+# blocks, x = x + attn(RMSNorm(x)); x = x + ffn(RMSNorm(x)): multi-head
+# latent attention in every block (`nn.LatentAttention`: rotary positions
+# with YaRN on a slice of each head), a dense gated feed-forward in the
+# first `first_k_dense_replace` blocks and group-limited sigmoid-routed
+# gated experts with a shared expert in the rest (`nn.SparseMoELayer`). A
+# token leaves ONE latent row a block. Inference only; the multi-token
+# prediction module is not built (the report's section 2.2 discards it at
+# inference).
+# --------------------------------------------------------------------------
+
+class DeepseekV3Config:
+    """Keys as the source's `config.json` names them. Of this repo's own:
+    `experts_held` = (first, count), the routed experts this rank holds
+    (default: all `n_routed_experts`); `latent_row_pad`, zero values that
+    close a cached latent row (to a lane multiple); `dtype`."""
+
+    def __init__(self, vocab_size=129280, hidden_size=7168,
+                 intermediate_size=18432, moe_intermediate_size=2048,
+                 num_hidden_layers=61, num_attention_heads=128,
+                 n_shared_experts=1, n_routed_experts=256,
+                 routed_scaling_factor=2.5, kv_lora_rank=512,
+                 q_lora_rank=1536, qk_rope_head_dim=64, v_head_dim=128,
+                 qk_nope_head_dim=128, n_group=8, topk_group=4,
+                 num_experts_per_tok=8, first_k_dense_replace=3,
+                 norm_topk_prob=True, scoring_func="sigmoid",
+                 topk_method="noaux_tc", moe_layer_freq=1,
+                 hidden_act="silu", rms_norm_eps=1e-6, rope_theta=10000,
+                 rope_scaling=None, attention_bias=False,
+                 tie_word_embeddings=False, num_nextn_predict_layers=0,
+                 experts_held=None, latent_row_pad=0, dtype="bfloat16",
+                 **unused):
+        if hidden_act != "silu" or scoring_func != "sigmoid" or \
+                not norm_topk_prob or topk_method != "noaux_tc" or \
+                moe_layer_freq != 1 or attention_bias or \
+                tie_word_embeddings or n_shared_experts < 1:
+            raise ValueError(
+                "DeepseekV3 is built with silu-gated experts, sigmoid "
+                "scores normalised over the chosen experts, the "
+                "bias-steered group-limited choice (noaux_tc), an expert "
+                "layer in every block after the leading dense ones, a "
+                "shared expert, no attention bias and an untied head")
+        if num_nextn_predict_layers:
+            raise ValueError(
+                f"num_nextn_predict_layers={num_nextn_predict_layers}: "
+                f"the multi-token prediction module is not built")
+        rope = dict(rope_scaling or {})
+        if rope and (rope.get("type", rope.get("rope_type")) != "yarn"
+                     or rope.get("mscale") != rope.get("mscale_all_dim")):
+            raise ValueError(
+                f"rope_scaling {rope_scaling!r}: YaRN with mscale == "
+                f"mscale_all_dim (the cos / sin factor 1) is what is "
+                f"built")
+        if not 0 <= first_k_dense_replace <= num_hidden_layers:
+            raise ValueError("first_k_dense_replace past the depth")
+        self.vocab_size, self.hidden_size = vocab_size, hidden_size
+        self.intermediate_size = intermediate_size
+        self.moe_intermediate_size = moe_intermediate_size
+        self.num_hidden_layers = num_hidden_layers
+        self.num_attention_heads = num_attention_heads
+        self.n_shared_experts = n_shared_experts
+        self.n_routed_experts = n_routed_experts
+        self.routed_scaling_factor = routed_scaling_factor
+        self.kv_lora_rank, self.q_lora_rank = kv_lora_rank, q_lora_rank
+        self.qk_rope_head_dim = qk_rope_head_dim
+        self.qk_nope_head_dim, self.v_head_dim = qk_nope_head_dim, v_head_dim
+        self.n_group, self.topk_group = n_group, topk_group
+        self.num_experts_per_tok = num_experts_per_tok
+        self.first_k_dense_replace = first_k_dense_replace
+        self.rms_norm_eps, self.rope_theta = rms_norm_eps, rope_theta
+        self.rope_scaling = rope
+        self.experts_held = tuple(experts_held) if experts_held else (
+            0, n_routed_experts)
+        self.latent_row_pad, self.dtype = int(latent_row_pad), dtype
+
+    @property
+    def latent_row_width(self):
+        return self.kv_lora_rank + self.qk_rope_head_dim \
+            + self.latent_row_pad
+
+    @classmethod
+    def tiny(cls, **kw):
+        """One dense and two expert layers; 8 groups of 2 experts of which
+        4 are chosen; 4 of the 16 experts held; positions past `original`
+        within a short sequence."""
+        d = dict(vocab_size=96, hidden_size=32, intermediate_size=48,
+                 moe_intermediate_size=16, num_hidden_layers=3,
+                 num_attention_heads=4, n_routed_experts=16,
+                 kv_lora_rank=16, q_lora_rank=24, qk_rope_head_dim=8,
+                 v_head_dim=8, qk_nope_head_dim=8, n_group=8, topk_group=4,
+                 num_experts_per_tok=4, first_k_dense_replace=1,
+                 rope_scaling={"type": "yarn", "factor": 40,
+                               "original_max_position_embeddings": 8,
+                               "beta_fast": 32, "beta_slow": 1,
+                               "mscale": 1, "mscale_all_dim": 1},
+                 experts_held=(4, 4), dtype="float32")
+        d.update(kw)
+        return cls(**d)
+
+
+class DeepseekV3Block(nn.Layer):
+    """x + attn(RMSNorm(x)), then x + ffn(RMSNorm(x)): `dense` blocks
+    hold a gated feed-forward, the others an expert layer."""
+
+    def __init__(self, cfg: DeepseekV3Config, idx):
+        super().__init__()
+        from ..nn import initializer as I
+        from ..nn.layer.ssm import _held
+
+        self.idx, self.dense = idx, idx < cfg.first_k_dense_replace
+        d, dt, rope = cfg.hidden_size, cfg.dtype, cfg.rope_scaling
+        self.input_layernorm = nn.RMSNorm(d, cfg.rms_norm_eps)
+        self.post_attention_layernorm = nn.RMSNorm(d, cfg.rms_norm_eps)
+        self.self_attn = nn.LatentAttention(
+            d, cfg.num_attention_heads, cfg.q_lora_rank, cfg.kv_lora_rank,
+            cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim,
+            cfg.rms_norm_eps, rope=dict(
+                theta=cfg.rope_theta, factor=rope.get("factor", 1.0),
+                original=rope.get("original_max_position_embeddings",
+                                  4096),
+                beta_fast=rope.get("beta_fast", 32),
+                beta_slow=rope.get("beta_slow", 1),
+                mscale_all_dim=rope.get("mscale_all_dim", 0.0)),
+            row_pad=cfg.latent_row_pad, dtype=dt)
+        if self.dense:
+            f = cfg.intermediate_size
+            self.gate_proj = _held(self, (d, f), I.XavierUniform(), dt)
+            self.up_proj = _held(self, (d, f), I.XavierUniform(), dt)
+            self.down_proj = _held(self, (f, d), I.XavierUniform(), dt)
+        else:
+            self.mlp = nn.SparseMoELayer(
+                d, cfg.moe_intermediate_size, cfg.n_routed_experts,
+                cfg.num_experts_per_tok,
+                shared_d_ff=cfg.n_shared_experts
+                * cfg.moe_intermediate_size,
+                routed_scaling=cfg.routed_scaling_factor,
+                experts_held=cfg.experts_held, activation="swiglu",
+                n_group=cfg.n_group, topk_group=cfg.topk_group, dtype=dt)
+
+    @staticmethod
+    def norm(ln, x):
+        from ..nn.layer.mla import rms
+
+        return rms(x, ln.weight._data, ln._epsilon)
+
+    def feed_forward(self, h, valid=None):
+        """h [..., hidden] -> (h + ffn(RMSNorm(h)), the expert layer's
+        counts [4] int32 or None). `valid` [...] bool: the tokens an
+        expert layer routes."""
+        import jax
+        import jax.numpy as jnp
+
+        from ..ops import moe
+
+        a = self.norm(self.post_attention_layernorm, h)
+        if self.dense:
+            with jax.named_scope("dense_ffn"):
+                f32 = jnp.float32
+                act = moe.swiglu(
+                    jnp.dot(a, self.gate_proj._data,
+                            preferred_element_type=f32),
+                    jnp.dot(a, self.up_proj._data,
+                            preferred_element_type=f32)).astype(h.dtype)
+                return h + act @ self.down_proj._data, None
+        with jax.named_scope("moe"):
+            y, counts = self.mlp.mix(a, valid)
+            return h + y, counts
+
+
+class DeepseekV3ForCausalLM(nn.Layer):
+    """ids -> logits (float32), three ways over raw arrays, all inference:
+
+    - `forward(ids)`: every block over every position, attention
+      unabsorbed.
+    - `prefill(ids [b, s], length [b])`: a join, attention unabsorbed ->
+      (logits [b, vocab] of position length - 1, {"latent": a block's
+      rows [b, s, row width]}, the expert layers' counts).
+    - `decode(tok [S], index [S], state, table, active)`: one position a
+      slot, attention ABSORBED over the slot's written rows read through
+      its page table -> (logits [S, vocab], {"latent": pages}, counts).
+
+    The counts ([4] int32, summed over the expert layers: token-slots
+    routed, those on held experts, the fullest held expert's, held slots
+    the experts' loops did not reach: 0) leave a program with its tokens."""
+
+    SCOPES = ("mla", "dense_ffn", "moe", "router")
+    #: what a program's counts are, in order (`snapshot()["experts"]`)
+    COUNTS = ("token_slots", "held_slots", "load_max", "dropped_slots")
+
+    def __init__(self, cfg: DeepseekV3Config):
+        super().__init__()
+        from ..nn import initializer as I
+        from ..nn.layer.ssm import _held
+
+        self.cfg = cfg
+        self.embed_tokens = _held(
+            self, (cfg.vocab_size, cfg.hidden_size), I.Normal(0.0, 0.02),
+            cfg.dtype)
+        self.layers = nn.LayerList([
+            DeepseekV3Block(cfg, i) for i in range(cfg.num_hidden_layers)])
+        self.norm = nn.RMSNorm(cfg.hidden_size, cfg.rms_norm_eps)
+        self.lm_head = _held(self, (cfg.hidden_size, cfg.vocab_size),
+                             I.XavierUniform(), cfg.dtype)
+        for _, p in self.named_parameters():
+            # the norms' weights are made float32: hold them as the rest
+            # is held (the router stays float32)
+            if str(p._data.dtype) != cfg.dtype and \
+                    not p.optimize_attr.get("keep_float32"):
+                p._data = p._data.astype(cfg.dtype)
+
+    def cache_kinds(self):
+        """What each block keeps of a sequence, for the pool's layout."""
+        return ["latent"] * len(self.layers)
+
+    def _logits(self, y):
+        import jax.numpy as jnp
+
+        return jnp.dot(DeepseekV3Block.norm(self.norm, y),
+                       self.lm_head._data,
+                       preferred_element_type=jnp.float32)
+
+    @staticmethod
+    def _sum(counts):
+        import jax.numpy as jnp
+
+        counts = [c for c in counts if c is not None]
+        return sum(counts[1:], counts[0]) if counts else \
+            jnp.zeros((4,), jnp.int32)
+
+    def _blocks(self, x, positions, valid):
+        """Every block over every position: (x, rows a block, counts)."""
+        import jax
+
+        rows, counts = [], []
+        for blk in self.layers:
+            with jax.named_scope("mla"):
+                q, row = blk.self_attn.project(
+                    blk.norm(blk.input_layernorm, x), positions)
+                x = x + blk.self_attn.causal(q, row)
+            rows.append(row)
+            x, c = blk.feed_forward(x, valid)
+            counts.append(c)
+        return x, rows, self._sum(counts)
+
+    def forward(self, ids=None, op=None, args=()):
+        """`forward(ids)`, or `forward(op="prefill" | "decode", args=...)`:
+        how a functionalized copy (`FunctionalModule.apply` calls
+        `forward`) reaches the other two."""
+        import jax.numpy as jnp
+
+        from ..core.tensor import Tensor
+
+        if op is not None:
+            return getattr(self, op)(*args)
+        ids = getattr(ids, "_data", ids)
+        positions = jnp.broadcast_to(
+            jnp.arange(ids.shape[1], dtype=jnp.int32), ids.shape)
+        x, _, _ = self._blocks(self.embed_tokens._data[ids], positions,
+                               None)
+        return Tensor._wrap(self._logits(x))
+
+    def prefill(self, ids, length):
+        import jax.numpy as jnp
+
+        ids = getattr(ids, "_data", ids)
+        length = jnp.asarray(length, jnp.int32)
+        pos = jnp.arange(ids.shape[1], dtype=jnp.int32)
+        x, rows, counts = self._blocks(
+            self.embed_tokens._data[ids],
+            jnp.broadcast_to(pos, ids.shape), pos[None] < length[:, None])
+        last = jnp.take_along_axis(x, (length - 1)[:, None, None], 1)[:, 0]
+        return self._logits(last), {"latent": rows}, counts
+
+    def decode(self, tok, index, state, table, active=None):
+        """tok, index [S]: the token each slot feeds and the position it
+        stands at; state["latent"]: a block's pages [N + 1, psz, row
+        width] with `table` [S, max_pages]; `active` [S] bool: the slots
+        whose tokens the expert layers route (None: all)."""
+        import jax
+        import jax.numpy as jnp
+
+        from ..serving import paging as PG
+
+        index = jnp.asarray(index, jnp.int32)
+        x = self.embed_tokens._data[tok]
+        S = tok.shape[0]
+        pages, counts = [], []
+        for blk, pg in zip(self.layers, state["latent"]):
+            with jax.named_scope("mla"):
+                q, row = blk.self_attn.project(
+                    blk.norm(blk.input_layernorm, x), index)
+                pg = PG.write_token(pg, None, table, index,
+                                    row[:, None])[0]
+                rows = pg[table].reshape(S, -1, pg.shape[-1])
+                x = x + blk.self_attn.absorbed(q, rows, index + 1)
+            pages.append(pg)
+            x, c = blk.feed_forward(x, active)
+            counts.append(c)
+        return self._logits(x), {"latent": pages}, self._sum(counts)

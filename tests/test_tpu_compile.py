@@ -701,3 +701,75 @@ def test_selective_scan_compiles_at_the_serving_cell_shapes(one_chip):
     assert "selective_scan" in compiled.as_text()
     # the columns of B and C spread over the lanes, and nothing s x n x c
     assert compiled.memory_analysis().temp_size_in_bytes < 0.2 * 2**30
+
+
+# ---------------------------------------------------------------------------
+
+DSV3 = dict(S=64, L=6144, heads=128, hidden=7168, psz=16)
+
+
+def _latent_attention():
+    import paddle_tpu as paddle
+    from paddle_tpu import nn
+
+    paddle.seed(0)
+    # the cell's head slices and latent widths; the hidden side is cut
+    # (it is not in the products compiled here)
+    return nn.LatentAttention(
+        256, DSV3["heads"], 64, 512, 128, 64, 128,
+        rope=dict(factor=40, mscale_all_dim=1), row_pad=64,
+        dtype="bfloat16")
+
+
+def test_latent_decode_read_compiles_at_the_serving_cell_shapes(one_chip):
+    """A decode step's read of one block's latent pages at the cell's
+    sizes (64 slots x 6,144 positions, rows of 640, 128 heads): the
+    gather through the table and the absorbed products. The softmax
+    must not turn into a reduce-window as wide as the keys: normalising
+    the weights before the value product did, and took 13.5 of a
+    step's 21 ms a layer on the chip (PERF.md section 6, PR 35)."""
+    attn = _latent_attention()
+    S, L, psz = (DSV3[k] for k in ("S", "L", "psz"))
+    mp = L // psz
+
+    def read(q, pages, table, n_keys):
+        rows = pages[table].reshape(S, -1, pages.shape[-1])
+        return attn.absorbed(q, rows, n_keys)
+
+    compiled = _compile_xla(
+        read, one_chip, ((S, DSV3["heads"], 192), jnp.bfloat16),
+        ((S * mp + 1, psz, attn.row_width), jnp.bfloat16),
+        ((S, mp), jnp.int32), ((S,), jnp.int32))
+    text = compiled.as_text()
+    assert "tpu_custom_call" not in text
+    assert f"size=1x1x{2 * L - 1}" not in text
+    rows = S * L * attn.row_width * 2                  # gathered once
+    scores = S * DSV3["heads"] * L * 4
+    assert compiled.memory_analysis().temp_size_in_bytes \
+        < rows + 2 * scores
+
+
+@pytest.mark.parametrize("path", ["flash", "composed"])
+def test_latent_join_attention_compiles_at_the_serving_cell_shapes(
+        one_chip, path):
+    """A 4,096-position join's unabsorbed attention. On the chip: the
+    flash kernel over heads closed with zeros to 256 lanes (192-wide
+    queries and keys, 128-wide values: the kernel has one head size).
+    Elsewhere: a group of heads' scores at a time (all 128 heads' would
+    be 8.6 GB in float32)."""
+    attn = _latent_attention()
+    s = 4096
+    orig = A._on_tpu
+    A._on_tpu = lambda: path == "flash"
+    try:
+        # another function object a path: jit keeps a trace by function
+        compiled = _compile_xla(
+            lambda q, rows: attn.causal(q, rows), one_chip,
+            ((1, s, DSV3["heads"], 192), jnp.bfloat16),
+            ((1, s, attn.row_width), jnp.bfloat16))
+    finally:
+        A._on_tpu = orig
+    text = compiled.as_text()
+    assert ("tpu_custom_call" in text) == (path == "flash")
+    assert f"size=1x1x{2 * s - 1}" not in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 2.5 * 2**30

@@ -488,8 +488,13 @@ def _full_metrics():
                        trie_nodes=4, trie_pages=6,
                        cache={"state_resets": 1, "prefill_tokens": 9,
                               "ring_wraps": 2})
-    m.set_cache_bytes({"paged": 4096, "ring": 1024, "recurrent": 512,
-                       "static": 0})
+    m.set_cache_bytes({"paged": 4096, "latent": 2048, "ring": 1024,
+                       "recurrent": 512, "static": 0})
+    m.set_expert_counters(("token_slots", "held_slots", "load_max",
+                           "dropped_slots"))
+    m.record_iteration(queue_depth=0, occupancy=0.5,
+                       experts={"token_slots": 64, "held_slots": 5,
+                                "load_max": 2, "dropped_slots": 0})
     m.set_memory_provider(
         lambda: {"weights_bytes": 1000, "pool_bytes": 500,
                  "adapter_bytes": 128, "in_use_bytes": 1200,
