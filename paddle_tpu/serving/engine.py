@@ -2112,6 +2112,8 @@ class PagedServingEngine(ServingEngine):
                                          mid_page=radix_mid_page)
                         if prefix_cache else None)
         self._partial_ok = None   # resolved lazily (needs jnp)
+        self._trie_counted = dict.fromkeys(
+            ("digests", "evictions", "evict_visits"), 0)
         if self._prefix is not None and self._apool is not None:
             # eager tenant invalidation: an adapter re-register drops
             # the stale subtree immediately (the generation key would
@@ -2280,7 +2282,8 @@ class PagedServingEngine(ServingEngine):
             pad_id = int(r.eos_id) if r.eos_id is not None else 0
             row, P0, Pb = pad_prompt_row(r.prompt, pad_id)
             res = self._prefix.peek(
-                row[0, :P0], P0, Pb, r.memory, self._tenant_key(r),
+                row[0, :P0], P0, Pb, self._mem_scope(r),
+                self._tenant_key(r),
                 allow_partial=self._radix_partial_ok())
             if res is not None and res[0] == "whole":
                 # shared pages are free; only a COW of the partial
@@ -2333,8 +2336,14 @@ class PagedServingEngine(ServingEngine):
             gauges["experts"], self._experts_iter = self._experts_iter, {}
         if self._prefix is not None:
             st = self._prefix.stats()
+            # the trie's counters are its lifetime's: hand the metrics
+            # what this iteration added, as for the cache counts
+            counts = {k: st[k] - self._trie_counted[k]
+                      for k in self._trie_counted}
+            self._trie_counted = {k: st[k] for k in counts}
             gauges.update({"trie_nodes": st["nodes"],
-                           "trie_pages": st["pages"]})
+                           "trie_pages": st["pages"],
+                           "trie_counts": counts})
         written = [int(self._index[s])
                    for s, r in enumerate(self.slots) if r is not None]
         active_toks = sum(written)
@@ -2363,6 +2372,16 @@ class PagedServingEngine(ServingEngine):
         return gauges
 
     # ---- join: prefill into pages, or attach shared prefix pages ----
+    def _mem_scope(self, r):
+        """The radix trie's memory scope for `r`: its memory's digest,
+        taken ONCE a request at the first need (the admission gate's
+        peek) and carried on the Request for lookup, insert and a
+        preemption's re-insert. Never cached by array identity across
+        requests: a client sends a fresh memory with each one."""
+        if r._mem_scope is None:
+            r._mem_scope = self._prefix.mem_digest(r.memory)
+        return r._mem_scope
+
     def _tenant_key(self, r):
         """The radix trie's tenant scope. The prompt K/V depend on the
         adapter that prefilled them (LoRA on the K/V projections from
@@ -2443,7 +2462,7 @@ class PagedServingEngine(ServingEngine):
         res = None
         if self._prefix is not None:
             res = self._prefix.lookup(
-                prompt_b[0, :P0], P0, Pb, r.memory,
+                prompt_b[0, :P0], P0, Pb, self._mem_scope(r),
                 self._tenant_key(r),
                 allow_partial=self._radix_partial_ok())
             kind = res[0] if res is not None else "miss"
@@ -2511,8 +2530,9 @@ class PagedServingEngine(ServingEngine):
         # resolves lazily at the first whole hit; the caller's
         # delivery resolves after the admission round's last dispatch
         if self._prefix is not None:
-            self._prefix.insert(prompt_b[0, :P0], P0, Pb, r.memory,
-                                self._tenant_key(r), pages, tok0)
+            self._prefix.insert(prompt_b[0, :P0], P0, Pb,
+                                self._mem_scope(r), self._tenant_key(r),
+                                pages, tok0)
         self._cow_tail(s, Pb)
         return tok0
 
@@ -2589,7 +2609,7 @@ class PagedServingEngine(ServingEngine):
         # pages while they are still the canonical prompt pages — the
         # COW then sees the shared refcount and gives the slot its
         # private decode page (same ordering as the cold prefill path)
-        self._prefix.insert(prompt_b[0, :P0], P0, Pb, r.memory,
+        self._prefix.insert(prompt_b[0, :P0], P0, Pb, self._mem_scope(r),
                             self._tenant_key(r), full_pages, tok0)
         self._cow_tail(s, Pb)
         return tok0
@@ -2721,7 +2741,7 @@ class PagedServingEngine(ServingEngine):
             # work survives a later slot failure (and co-arrivals
             # partial-match the growing prefix immediately)
             self._prefix.insert_prefix(
-                info["prompt_b"][0, :end], r.memory,
+                info["prompt_b"][0, :end], self._mem_scope(r),
                 self._tenant_key(r),
                 [int(p) for p in self._table[s, :end // psz]])
         return tok0
@@ -2732,7 +2752,7 @@ class PagedServingEngine(ServingEngine):
         if self._prefix is not None:
             pages = [int(p) for p in self._table[s] if p >= 0]
             self._prefix.insert(
-                info["prompt_b"][0, :P0], P0, Pb, r.memory,
+                info["prompt_b"][0, :P0], P0, Pb, self._mem_scope(r),
                 self._tenant_key(r), pages, info["tok0"])
         self._cow_tail(s, Pb)
 
@@ -2771,7 +2791,7 @@ class PagedServingEngine(ServingEngine):
         # just refreshes its tick; a new one increfs the pages), THEN
         # release the slot: the pages survive via the trie's refs and
         # the resume join rides a zero-FLOP whole-prefix attach
-        self._prefix.insert(prompt_b[0, :P0], P0, Pb, r.memory,
+        self._prefix.insert(prompt_b[0, :P0], P0, Pb, self._mem_scope(r),
                             self._tenant_key(r), pages,
                             int(r.tokens[0]))
         if r._trace is not None:

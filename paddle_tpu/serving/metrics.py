@@ -172,6 +172,15 @@ SNAPSHOT_DOCS = {
                           "radix-trie page nodes at last iteration"),
     "prefix.trie_pages": ("gauge",
                           "physical pages referenced by the trie"),
+    "prefix.digests": ("counter",
+                       "memory digests the trie's scopes took: one a "
+                       "request that brought a memory"),
+    "prefix.evictions": ("counter",
+                         "trie items evicted (terminals and bare "
+                         "leaf pages)"),
+    "prefix.evict_visits": ("counter",
+                            "eviction-order entries those evictions "
+                            "examined, stale ones included"),
     # live HBM ledger (PR 9) — the section appears once the engine
     # registers its memory provider (model-backed engines always do)
     "memory.weights_bytes": (
@@ -518,6 +527,7 @@ class ServingMetrics:
         self.cow_copies = 0
         self.trie_nodes = None      # last-iteration gauges
         self.trie_pages = None
+        self.trie_counts = {}       # digests, evictions, evict_visits
         self.page_waits = 0         # admissions deferred on page headroom
         self.oom_evictions = 0      # mid-decode OutOfPages victims
         self.bytes_per_token = _Reservoir(512)  # bytes / active token
@@ -1010,6 +1020,7 @@ class ServingMetrics:
                          pages_free=None, bytes_per_active_token=None,
                          shard_occupancy=None, tenant_slots=None,
                          trie_nodes=None, trie_pages=None,
+                         trie_counts=None,
                          live_pages=None, table_entries=None, cache=None,
                          live_blocks=None, pages_per_block=None,
                          experts=None):
@@ -1040,6 +1051,8 @@ class ServingMetrics:
                 self.trie_nodes = int(trie_nodes)
             if trie_pages is not None:
                 self.trie_pages = int(trie_pages)
+            for k, v in (trie_counts or {}).items():
+                self.trie_counts[k] = self.trie_counts.get(k, 0) + int(v)
             if bytes_per_active_token is not None:
                 self.bytes_per_token.add(bytes_per_active_token)
             if shard_occupancy is not None:
@@ -1257,6 +1270,10 @@ class ServingMetrics:
                     "cow_copies": self.cow_copies,
                     "trie_nodes": self.trie_nodes or 0,
                     "trie_pages": self.trie_pages or 0,
+                    "digests": self.trie_counts.get("digests", 0),
+                    "evictions": self.trie_counts.get("evictions", 0),
+                    "evict_visits": self.trie_counts.get(
+                        "evict_visits", 0),
                 }}),
             }
 

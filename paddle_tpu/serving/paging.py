@@ -46,6 +46,7 @@ from __future__ import annotations
 
 import collections
 import hashlib
+import heapq
 
 import numpy as np
 
@@ -282,7 +283,7 @@ class _RadixNode:
                  "tick")
 
     def __init__(self, tokens, page, parent):
-        self.tokens = tokens          # page_size-tuple (None at roots)
+        self.tokens = tokens          # page_size-tuple (scope at roots)
         self.page = page              # physical page id (None at roots)
         self.parent = parent
         self.children = {}            # {page-token-tuple: _RadixNode}
@@ -314,11 +315,18 @@ class RadixPrefixCache:
     re-register) orphans the stale subtree lazily on next touch, or
     eagerly via `drop_tenant`.
 
+    Scopes are keyed by `scope=`, the memory digest `mem_digest` makes:
+    the engine takes it ONCE a request and hands it to every call, so a
+    join hashes its memory once, not at each of peek, lookup and insert.
+
     Eviction is leaf-first LRU over terminals and BARE leaf nodes (no
     children, no terminals): interior nodes keep serving partial
     matches until everything under them has aged out, and every drop
     releases exactly the references the trie took, so
-    `PageAllocator.check()` stays clean under chaos."""
+    `PageAllocator.check()` stays clean under chaos. The evictable
+    items sit in a heap ordered by tick, pushed whenever one is touched
+    or becomes bare and checked when popped (lazy invalidation), so an
+    eviction costs O(log n), not a scan of the trie."""
 
     def __init__(self, allocator, capacity=64, page_size=None,
                  mid_page="round_down"):
@@ -345,20 +353,29 @@ class RadixPrefixCache:
         self._n_nodes = 0
         self._n_terminals = 0
         self._n_pages = 0             # pages referenced by the trie
+        # eviction order: (tick, seq, node, terminal key, entry) with
+        # key and entry None for a bare node; entries go stale when the
+        # item is touched again, gains a child or is dropped
+        self._order = []
+        self._seq = 0
         self.whole_hits = 0
         self.partial_hits = 0
         self.misses = 0
         self.rounded_down = 0         # mid-page matches truncated
+        self.digests = 0              # memory digests computed
+        self.evictions = 0            # items evicted
+        self.evict_visits = 0         # heap entries evictions examined
 
     # -- keys ------------------------------------------------------------
 
-    @staticmethod
-    def mem_digest(memory):
-        """Cross-attention memory digest: decoder self-attn K/V depend
-        on the memory through the cross-attn residual stream, so pages
-        are only shareable within one memory scope."""
+    def mem_digest(self, memory):
+        """Cross-attention memory digest, the `scope=` of every call:
+        decoder self-attn K/V depend on the memory through the
+        cross-attn residual stream, so pages are only shareable within
+        one memory scope."""
         if memory is None:
             return ""
+        self.digests += 1
         mem = np.ascontiguousarray(memory)
         digest = hashlib.sha1()
         digest.update(str(mem.dtype).encode())
@@ -370,7 +387,7 @@ class RadixPrefixCache:
         self._tick += 1
         return self._tick
 
-    def _root_for(self, memory, tenant, create):
+    def _root_for(self, scope, tenant, create):
         """Scope root, handling tenant-generation invalidation: a
         stale-generation subtree is dropped before the fresh one is
         touched (tenant = None for base traffic, (name, gen) for
@@ -381,10 +398,10 @@ class RadixPrefixCache:
             if old is not None and old != gen:
                 self.drop_tenant(name)
             self._tenant_gen[name] = gen
-        key = (self.mem_digest(memory), tenant)
+        key = (scope, tenant)
         root = self._roots.get(key)
         if root is None and create:
-            root = _RadixNode(None, None, None)
+            root = _RadixNode(key, None, None)
             self._roots[key] = root
         return root
 
@@ -437,12 +454,12 @@ class RadixPrefixCache:
                     best_j, best_src = j, ent["pages"][0]
         return best_j, best_src
 
-    def _match(self, memory, tenant, tokens, P0, Pb, allow_partial,
+    def _match(self, scope, tenant, tokens, P0, Pb, allow_partial,
                mutate):
         psz = self.page_size
         tokens = tuple(int(t) for t in tokens)[:int(P0)]
         if mutate:
-            root = self._root_for(memory, tenant, create=False)
+            root = self._root_for(scope, tenant, create=False)
         else:
             # peek: read-only, even for generation bookkeeping
             if tenant is not None:
@@ -450,20 +467,22 @@ class RadixPrefixCache:
                 old = self._tenant_gen.get(name)
                 if old is not None and old != gen:
                     return None
-            root = self._roots.get((self.mem_digest(memory), tenant))
+            root = self._roots.get((scope, tenant))
         if root is None:
             return None
         node, path = self._walk(root, tokens, P0)
         m = len(path)
         n_full = P0 // psz
         if m == n_full:
-            ent = node.terminals.get((tokens[n_full * psz:P0], P0))
+            tkey = (tokens[n_full * psz:P0], P0)
+            ent = node.terminals.get(tkey)
             if ent is not None and ent["Pb"] == int(Pb):
                 if mutate:
                     t = self._touch()
                     for n in path:
                         n.tick = t
                     ent["tick"] = t
+                    self._push(t, node, tkey, ent)
                     if not isinstance(ent["tok0"], int):
                         # stored as the producing join's traced scalar
                         # (deferred sync); the first hit canonicalizes
@@ -497,17 +516,19 @@ class RadixPrefixCache:
             t = self._touch()
             for n in path:
                 n.tick = t
+            if path:
+                self._push_if_bare(path[-1])
         return ("partial", {
             "pages": [n.page for n in path], "j": int(j),
             "cow_src": cow_src, "seed_len": m * psz + int(j)})
 
-    def lookup(self, tokens, P0, Pb, memory=None, tenant=None,
+    def lookup(self, tokens, P0, Pb, scope="", tenant=None,
                allow_partial=True):
-        """Longest-prefix match for `tokens[:P0]` in the (memory,
-        tenant) scope. Returns None, ("whole", entry) or ("partial",
-        {pages, j, cow_src, seed_len}). The CALLER increfs any pages
-        it maps; matched nodes move to MRU."""
-        res = self._match(memory, tenant, tokens, P0, Pb, allow_partial,
+        """Longest-prefix match for `tokens[:P0]` in the (memory digest
+        `scope`, tenant) scope. Returns None, ("whole", entry) or
+        ("partial", {pages, j, cow_src, seed_len}). The CALLER increfs
+        any pages it maps; matched nodes move to MRU."""
+        res = self._match(scope, tenant, tokens, P0, Pb, allow_partial,
                           mutate=True)
         if res is None:
             self.misses += 1
@@ -517,17 +538,17 @@ class RadixPrefixCache:
             self.partial_hits += 1
         return res
 
-    def peek(self, tokens, P0, Pb, memory=None, tenant=None,
+    def peek(self, tokens, P0, Pb, scope="", tenant=None,
              allow_partial=True):
         """Like lookup, but side-effect free (no accounting, no MRU
         move, no generation invalidation) — the admission gate's
         headroom estimate uses it."""
-        return self._match(memory, tenant, tokens, P0, Pb,
+        return self._match(scope, tenant, tokens, P0, Pb,
                            allow_partial, mutate=False)
 
     # -- insert ----------------------------------------------------------
 
-    def insert(self, tokens, P0, Pb, memory, tenant, pages, tok0):
+    def insert(self, tokens, P0, Pb, scope, tenant, pages, tok0):
         """Extend the trie with a completed prompt's pages (already
         refcounted by their slot; the trie takes its own references).
         Full pages become (or refresh) trie nodes one by one — a
@@ -537,7 +558,7 @@ class RadixPrefixCache:
         psz = self.page_size
         tokens = tuple(int(t) for t in tokens)[:int(P0)]
         P0, Pb = int(P0), int(Pb)
-        root = self._root_for(memory, tenant, create=True)
+        root = self._root_for(scope, tenant, create=True)
         n_full = P0 // psz
         t = self._touch()
         node = root
@@ -557,21 +578,24 @@ class RadixPrefixCache:
         ent = node.terminals.get(tkey)
         if ent is not None:
             ent["tick"] = t               # hot terminal: refresh LRU
+            self._push(t, node, tkey, ent)
             return
         tail = [int(p) for p in pages[n_full:]]
         self.allocator.incref(tail)
         # tok0 may still be the producing join's traced scalar: store
         # it raw (the submit path never blocks on it) — the first
         # whole hit canonicalizes it to a host int
-        node.terminals[tkey] = {"pages": tail, "tok0": tok0,
-                                "n_prompt": P0, "Pb": Pb, "tick": t}
+        ent = {"pages": tail, "tok0": tok0, "n_prompt": P0, "Pb": Pb,
+               "tick": t}
+        node.terminals[tkey] = ent
+        self._push(t, node, tkey, ent)
         self._n_terminals += 1
         self._n_pages += len(tail)
         while self._n_terminals > self.capacity:
             if not self._evict_one():
                 break
 
-    def insert_prefix(self, tokens, memory, tenant, pages):
+    def insert_prefix(self, tokens, scope, tenant, pages):
         """Extend the trie with FULL PAGES only — no terminal: the
         chunked-prefill path calls this after every completed chunk,
         so the pages a long prompt has prefilled SO FAR are already
@@ -586,7 +610,7 @@ class RadixPrefixCache:
         if n_full == 0:
             return
         tokens = tuple(int(t) for t in tokens)[:n_full * psz]
-        root = self._root_for(memory, tenant, create=True)
+        root = self._root_for(scope, tenant, create=True)
         t = self._touch()
         node = root
         for i in range(n_full):
@@ -601,50 +625,77 @@ class RadixPrefixCache:
                 self._n_pages += 1
             child.tick = t
             node = child
+        self._push_if_bare(node)
 
     # -- eviction --------------------------------------------------------
 
-    def _iter_nodes(self):
-        stack = [(key, root) for key, root in self._roots.items()]
-        while stack:
-            key, node = stack.pop()
-            yield key, node
-            for child in node.children.values():
-                stack.append((key, child))
+    @staticmethod
+    def _bare(node):
+        """An evictable node: a non-root leaf with no terminals."""
+        return (node.parent is not None and not node.children
+                and not node.terminals)
+
+    def _push(self, tick, node, tkey=None, ent=None):
+        """Enter an item in the eviction order at its new tick (its
+        older entries go stale). Stale entries are only dropped when
+        popped, so once they outnumber the live items the heap is
+        rebuilt from a walk of the trie: amortized O(1) a push."""
+        self._seq += 1
+        heapq.heappush(self._order, (tick, self._seq, node, tkey, ent))
+        if len(self._order) > 2 * (self._n_nodes + self._n_terminals) \
+                + 64:
+            live, stack = [], list(self._roots.values())
+            while stack:
+                n = stack.pop()
+                stack.extend(n.children.values())
+                live.extend((e["tick"], len(live), n, k, e)
+                            for k, e in n.terminals.items())
+                if self._bare(n):
+                    live.append((n.tick, len(live), n, None, None))
+            heapq.heapify(live)
+            self._order, self._seq = live, len(live)
+
+    def _push_if_bare(self, node):
+        if self._bare(node):
+            self._push(node.tick, node)
 
     def _evict_one(self):
         """Drop the least-recently-used evictable item: a terminal, or
         a BARE leaf node (no children, no terminals). Interior nodes
         are never dropped while anything hangs below them — they still
         serve partial matches — but become bare (and evictable) as
-        their subtrees age out. Returns False when nothing is left."""
-        best = None                   # (tick, kind, ...)
-        for key, node in self._iter_nodes():
-            for tkey, ent in node.terminals.items():
-                if best is None or ent["tick"] < best[0]:
-                    best = (ent["tick"], "terminal", node, tkey)
-            if (node.parent is not None and not node.children
-                    and not node.terminals):
-                if best is None or node.tick < best[0]:
-                    best = (node.tick, "node", node, key)
-        if best is None:
+        their subtrees age out. Returns False when nothing is left.
+        Live items' ticks are distinct (an operation ticks one
+        root-to-node chain and at most the terminal at its end), so the
+        least tick names the victim alone."""
+        while self._order:
+            tick, _, node, tkey, ent = heapq.heappop(self._order)
+            self.evict_visits += 1
+            if ent is None:
+                if self._bare(node) and node.tick == tick:
+                    break
+            elif node.terminals.get(tkey) is ent and ent["tick"] == tick:
+                break
+        else:
             return False
-        if best[1] == "terminal":
-            _, _, node, tkey = best
-            ent = node.terminals.pop(tkey)
+        self.evictions += 1
+        if ent is not None:
+            del node.terminals[tkey]
             self.allocator.decref(ent["pages"])
             self._n_terminals -= 1
             self._n_pages -= len(ent["pages"])
-        else:
-            _, _, node, key = best
-            del node.parent.children[node.tokens]
-            self.allocator.decref([node.page])
-            self._n_nodes -= 1
-            self._n_pages -= 1
-            parent = node.parent
-            if (parent.parent is None and not parent.children
-                    and not parent.terminals):
-                self._roots.pop(key, None)
+            self._push_if_bare(node)
+            return True
+        parent = node.parent
+        del parent.children[node.tokens]
+        node.parent = None                # dead: its entries go stale
+        self.allocator.decref([node.page])
+        self._n_nodes -= 1
+        self._n_pages -= 1
+        if parent.parent is not None:
+            self._push_if_bare(parent)
+        elif not parent.children and not parent.terminals:
+            self._roots.pop(parent.tokens, None)
         return True
 
     def reclaim(self, n_needed):
@@ -658,6 +709,7 @@ class RadixPrefixCache:
         return self.allocator.pages_free >= n_needed
 
     def _drop_subtree(self, node):
+        node.parent = None                # dead: its entries go stale
         for child in list(node.children.values()):
             self._drop_subtree(child)
         for ent in node.terminals.values():
@@ -682,16 +734,21 @@ class RadixPrefixCache:
     def flush(self):
         for key in list(self._roots):
             self._drop_subtree(self._roots.pop(key))
+        self._order = []
 
     # -- introspection ---------------------------------------------------
 
     def stats(self):
         """Gauges for the metrics snapshot: trie size in nodes (full
         prompt pages on edges), terminals, and total pages referenced
-        (node pages + terminal tails)."""
+        (node pages + terminal tails); counters of the memory digests
+        taken, the items evicted and the heap entries those evictions
+        examined (stale ones included)."""
         return {"nodes": self._n_nodes, "terminals": self._n_terminals,
                 "pages": self._n_pages, "scopes": len(self._roots),
-                "rounded_down": self.rounded_down}
+                "rounded_down": self.rounded_down,
+                "digests": self.digests, "evictions": self.evictions,
+                "evict_visits": self.evict_visits}
 
     def __len__(self):
         return self._n_terminals

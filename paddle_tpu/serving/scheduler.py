@@ -89,6 +89,7 @@ class Request:
         self.id = next(Request._ids)
         self.prompt = prompt
         self.memory = None if memory is None else np.asarray(memory)
+        self._mem_scope = None        # the prefix trie's memory digest
         self.max_new_tokens = int(max_new_tokens)
         if self.max_new_tokens < 1:
             raise ValueError("max_new_tokens must be >= 1")

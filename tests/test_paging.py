@@ -134,7 +134,8 @@ def _radix_insert(a, trie, tokens, P0, Pb, memory=None, tenant=None,
     """Alloc the prompt bucket's pages, insert, drop the caller refs —
     the trie then holds the only references (like a drained slot)."""
     pages = a.alloc(PG.pages_for(Pb, trie.page_size))
-    trie.insert(tokens, P0, Pb, memory, tenant, pages, tok0)
+    trie.insert(tokens, P0, Pb, trie.mem_digest(memory), tenant, pages,
+                tok0)
     a.decref(pages)
     return pages
 
@@ -269,10 +270,192 @@ def test_radix_trie_tenant_scopes_and_generation_bump():
     m1 = np.ones((2, 4), "f4")
     m2 = np.zeros((2, 4), "f4")
     _radix_insert(a, trie, toks, 6, 8, memory=m1)
-    assert trie.lookup(toks, 6, 8, memory=m1)[0] == "whole"
-    assert trie.lookup(toks, 6, 8, memory=m2) is None
+    assert trie.lookup(toks, 6, 8, scope=trie.mem_digest(m1))[0] == \
+        "whole"
+    assert trie.lookup(toks, 6, 8, scope=trie.mem_digest(m2)) is None
     trie.flush()
     a.check()
+
+
+class _LoggingAllocator(PageAllocator):
+    """Logs every decref: two tries driven alike in lockstep hand out
+    the same page ids, so equal logs mean the same victims in the same
+    order."""
+
+    def __init__(self, *a):
+        super().__init__(*a)
+        self.log = []
+
+    def decref(self, pages):
+        self.log.append(tuple(int(p) for p in pages))
+        super().decref(pages)
+
+
+class _ScanEvictionTrie(PG.RadixPrefixCache):
+    """The trie with PR 35's eviction: a scan of every node for each
+    victim, least tick first, first met on a tie. The oracle the kept
+    eviction order must agree with, victim for victim."""
+
+    def _evict_one(self):
+        best = None                   # (tick, kind, ...)
+        stack = list(self._roots.items())
+        while stack:
+            key, node = stack.pop()
+            stack.extend((key, c) for c in node.children.values())
+            for tkey, ent in node.terminals.items():
+                if best is None or ent["tick"] < best[0]:
+                    best = (ent["tick"], "terminal", node, tkey)
+            if (node.parent is not None and not node.children
+                    and not node.terminals):
+                if best is None or node.tick < best[0]:
+                    best = (node.tick, "node", node, key)
+        if best is None:
+            return False
+        self.evictions += 1
+        if best[1] == "terminal":
+            _, _, node, tkey = best
+            ent = node.terminals.pop(tkey)
+            self.allocator.decref(ent["pages"])
+            self._n_terminals -= 1
+            self._n_pages -= len(ent["pages"])
+        else:
+            _, _, node, key = best
+            del node.parent.children[node.tokens]
+            self.allocator.decref([node.page])
+            self._n_nodes -= 1
+            self._n_pages -= 1
+            parent = node.parent
+            if (parent.parent is None and not parent.children
+                    and not parent.terminals):
+                self._roots.pop(key, None)
+        return True
+
+
+@pytest.mark.parametrize("mid_page", ["round_down", "cow"])
+@pytest.mark.parametrize("seed", [0, 1, 2])
+def test_radix_trie_eviction_order_matches_full_scan(seed, mid_page):
+    """A seeded trace of inserts (short prompts and 17-32-page ones,
+    sharing preambles), chunk-style prefix inserts, lookups (bursts of
+    them too), peeks, reclaims, tenant drops, generation bumps and
+    flushes, over two
+    memory scopes, drives the trie and the full-scan oracle in
+    lockstep: every result, every gauge and every page released
+    (victims and their order) agree, and both allocators stay
+    clean."""
+    psz, n_pages = 4, 640
+    rs = np.random.RandomState(seed)
+    sides = []
+    for cls in (PG.RadixPrefixCache, _ScanEvictionTrie):
+        a = _LoggingAllocator(n_pages, psz)
+        sides.append((a, cls(a, capacity=12, page_size=psz,
+                             mid_page=mid_page)))
+    scopes = [sides[0][1].mem_digest(m) for m in
+              (None, np.arange(8, dtype="f4").reshape(2, 4))]
+    preambles = [tuple(int(t) for t in rs.randint(0, 3, 32 * psz))
+                 for _ in range(3)]
+    gen, seen = 0, []
+
+    def prompt():
+        long = rs.rand() < 0.3
+        n_pp = int(rs.randint(17, 33) if long else rs.randint(1, 5))
+        P0 = int(rs.randint((n_pp - 1) * psz + 1, n_pp * psz + 1))
+        keep = int(rs.randint(0, P0 + 1))
+        pre = preambles[rs.randint(len(preambles))]
+        toks = pre[:keep] + tuple(
+            int(t) for t in rs.randint(0, 3, P0 - keep))
+        Pb = (n_pp + int(rs.randint(0, 2))) * psz
+        return toks, P0, Pb
+
+    def both(fn):
+        out = [fn(a, trie) for a, trie in sides]
+        assert out[0] == out[1]
+        return out[0]
+
+    def insert(a, trie, toks, P0, Pb, scope, tenant, prefix_only):
+        n = PG.pages_for(Pb, psz)
+        if a.pages_free < n:
+            trie.reclaim(n)
+        if a.pages_free < n:
+            return None
+        pages = a.alloc(n)
+        if prefix_only:
+            trie.insert_prefix(toks, scope, tenant,
+                               pages[:P0 // psz])
+        else:
+            trie.insert(toks, P0, Pb, scope, tenant, pages, tok0=P0)
+        a.decref(pages)
+        return pages
+
+    for step in range(500):
+        op = rs.rand()
+        scope = scopes[rs.randint(2)]
+        tenant = None if rs.rand() < 0.7 else ("lora", gen)
+        if op < 0.4 or not seen:
+            toks, P0, Pb = prompt()
+            prefix_only = rs.rand() < 0.15
+            both(lambda a, t: insert(a, t, toks, P0, Pb, scope, tenant,
+                                     prefix_only))
+            seen.append((toks, P0, Pb, scope, tenant))
+        elif op < 0.7:
+            toks, P0, Pb, scope, tenant = seen[rs.randint(len(seen))]
+            if rs.rand() < 0.3:
+                toks = toks[:-1] + ((toks[-1] + 1) % 3,)
+            fn = "lookup" if rs.rand() < 0.8 else "peek"
+            both(lambda a, t: getattr(t, fn)(toks, P0, Pb, scope=scope,
+                                             tenant=tenant))
+        elif op < 0.75:
+            # a hot prompt: a burst of hits leaves stale order entries
+            # enough to make the trie rebuild its heap
+            toks, P0, Pb, scope, tenant = seen[-1]
+            for _ in range(100):
+                both(lambda a, t: t.lookup(toks, P0, Pb, scope=scope,
+                                           tenant=tenant))
+        elif op < 0.9:
+            n = int(rs.randint(1, 200))
+            both(lambda a, t: (t.reclaim(a.pages_free + n), a.pages_free))
+        elif op < 0.95:
+            both(lambda a, t: t.drop_tenant("lora"))
+        elif op < 0.97:
+            gen += 1
+        else:
+            both(lambda a, t: t.flush())
+        both(lambda a, t: ({k: v for k, v in t.stats().items()
+                            if k not in ("digests", "evict_visits")},
+                           a.pages_free, len(a.log)))
+    both(lambda a, t: t.reclaim(n_pages))
+    assert sides[0][0].log == sides[1][0].log
+    assert sides[0][1].evictions > 50
+    for a, trie in sides:
+        a.check()
+        trie.flush()
+        assert a.pages_free == n_pages
+
+
+def test_radix_trie_eviction_visits_stay_bounded():
+    """Evicting from a trie of 3,000 items (1,000 two-page prompts,
+    each looked up again, so stale order entries abound) examines a
+    handful of order entries per victim, never the trie's size."""
+    psz, n = 4, 1000
+    a = PageAllocator(3 * n, psz)
+    trie = PG.RadixPrefixCache(a, capacity=2 * n, page_size=psz)
+    rs = np.random.RandomState(5)
+    prompts = [(i,) + tuple(int(t) for t in rs.randint(0, 9, 2 * psz - 1))
+               for i in range(n)]
+    for toks in prompts:
+        pages = a.alloc(2)
+        trie.insert(toks, 2 * psz, 2 * psz, "", None, pages, tok0=0)
+        a.decref(pages)
+    for i in rs.permutation(n):
+        assert trie.lookup(prompts[i], 2 * psz, 2 * psz)[0] == "whole"
+    st = trie.stats()
+    assert st["nodes"] + st["terminals"] == 3 * n
+    assert trie.reclaim(a.n_pages // 2)
+    st = trie.stats()
+    assert st["evictions"] >= n // 2
+    assert st["evict_visits"] <= 3 * st["evictions"]
+    trie.flush()
+    a.check()
+    assert trie.stats()["nodes"] == 0 and a.pages_free == a.n_pages
 
 
 # ----------------------------------------------------------------------
@@ -646,6 +829,28 @@ def test_paging_metrics_gauges_in_snapshot():
     assert 0.0 <= pg["prefix_hit_rate"] <= 1.0
     assert pg["bytes_per_active_token"]["n"] >= 1
     assert pg["bytes_per_active_token"]["max"] > 0
+
+
+def test_one_memory_digest_per_request():
+    """The memory's scope key is taken once a request, at the admission
+    gate's peek, and carried through lookup and insert; two requests
+    that bring the SAME memory array still take one digest each (the
+    key is per request, never cached by array identity)."""
+    dec, embed, proj, D, V = stack = _small_stack(seed=93)
+    eng = _paged_radix_engine(stack)
+    rs = np.random.RandomState(94)
+    r1 = _mk_request(rs, D, V)
+    assert eng._admission_gate(r1)
+    assert eng._prefix.stats()["digests"] == 1
+    assert _drive(eng, [r1])[0].ok
+    assert eng._prefix.stats()["digests"] == 1
+    r2 = Request(r1.prompt.copy(), r1.memory, max_new_tokens=3, eos_id=1)
+    assert _drive(eng, [r2])[0].ok
+    st = eng._prefix.stats()
+    assert st["digests"] == 2 and eng._prefix.whole_hits == 1
+    pf = eng.metrics.snapshot()["prefix"]
+    assert (pf["digests"], pf["evictions"], pf["evict_visits"]) == \
+        (2, st["evictions"], st["evict_visits"])
 
 
 def test_weight_update_invalidates_prefix_cache():
