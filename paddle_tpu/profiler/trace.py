@@ -28,6 +28,11 @@ Three cooperating pieces:
     tracer every instrumented call site reports into;
     `session_scope()` is the context-manager form. `Tracer.
     export_chrome_trace(path)` writes the Perfetto-loadable artifact.
+    A session also watches the garbage collector (`gc.callbacks`):
+    every collection's pause, on any thread, goes into the tracer's
+    totals by generation (`Tracer.gc_totals()`), and a collection of
+    generation 1 or 2 is a `gc` profiler annotation on the thread it ran
+    on and a `gc` span (trace id 0) in the tracer's ring.
   * **Compile observer** — the engines' jit caches (`_compiled` dicts
     keyed identically to their `trace_counts` Counters) are `JitCache`
     instances: every stored program is wrapped so a call that bumps its
@@ -44,6 +49,7 @@ from __future__ import annotations
 
 import collections
 import contextlib
+import gc
 import itertools
 import json
 import logging
@@ -60,6 +66,10 @@ __all__ = [
 ]
 
 _LOG = logging.getLogger("paddle_tpu.trace")
+
+#: spans a session's ring holds: about two minutes of a saturated 64-slot
+#: pool (some 2,100 spans a second), 110-170 MB of host memory when full
+CAPACITY = 262144
 
 _LOCK = threading.RLock()
 #: the ONE global every instrumented hot path reads; None = disabled
@@ -78,6 +88,8 @@ _COMPILE_HOOKS = []
 #: armed while the cost layer re-lowers a program to extract XLA
 #: analyses: the re-trace's counter bump must not look like a retrace
 _SUPPRESS = False
+#: the active session's `gc.callbacks` entry, or None
+_GC_HOOK = None
 
 
 def _recompute_watch():
@@ -174,13 +186,20 @@ class Tracer:
     finished spans are overwritten past `capacity` — `dropped` counts
     them) plus a plain counter surface for scalar telemetry."""
 
-    def __init__(self, capacity=65536, clock=time.perf_counter,
+    def __init__(self, capacity=CAPACITY, clock=time.perf_counter,
                  sample=None):
         self.capacity = int(capacity)
         self._clock = clock
         self._lock = threading.Lock()
         self._spans = collections.deque(maxlen=self.capacity)
         self._open = {}                 # span_id -> Span (not ended)
+        # what the collector hook leaves while this tracer's session is
+        # on: it takes no lock, so its `gc` spans reach the ring at the
+        # next commit() or spans(), and its pauses (ns and collections
+        # by generation) only grow
+        self._gc_spans = collections.deque()
+        self.gc_ns = [0, 0, 0]
+        self.gc_count = [0, 0, 0]
         self._ids = itertools.count(1)
         self.counters = collections.Counter()
         self.dropped = 0
@@ -196,6 +215,12 @@ class Tracer:
                 raise ValueError(
                     f"sample must be in (0, 1], got {sample}")
         self.sample = sample
+
+    def gc_totals(self):
+        """(ns of generation 0, 1, 2, collections of generation 0, 1,
+        2) of the collections, on any thread, while this tracer's
+        session was on; a reader differences two readings."""
+        return (*self.gc_ns, *self.gc_count)
 
     def should_sample(self, trace_id):
         """Deterministic per-request sampling decision (Knuth
@@ -256,9 +281,16 @@ class Tracer:
             self.dropped += 1
         self._spans.append(span)
 
+    # callers (commit, spans) hold self._lock
+    def _drain_gc(self):  # analysis: single-threaded
+        q = self._gc_spans
+        while q:
+            self._append(q.popleft())
+
     def commit(self, spans):
         """Put spans closed with `_record=False` in the ring."""
         with self._lock:
+            self._drain_gc()
             for sp in spans:
                 self._append(sp)
 
@@ -295,6 +327,7 @@ class Tracer:
     # ---- reading ----
     def spans(self, include_open=False):
         with self._lock:
+            self._drain_gc()
             out = list(self._spans)
             if include_open:
                 out.extend(self._open.values())
@@ -355,14 +388,73 @@ class Tracer:
 
 
 # ----------------------------------------------------------------------
+# the garbage collector
+# ----------------------------------------------------------------------
+
+class _GcHook:
+    """A session's `gc.callbacks` entry. It takes NO lock: a collection
+    can start on a thread that holds `Tracer._lock` (any span
+    allocates), and that lock is not re-entrant. The collector runs one
+    collection at a time, and its callbacks run inside it, so one
+    `start` slot serves every thread; the spans it leaves wait in a
+    deque, which appends without a lock."""
+
+    __slots__ = ("tr", "t0_ns", "t0", "ann")
+
+    def __init__(self, tr):
+        self.tr = tr
+        self.t0_ns = 0
+        self.t0 = 0.0
+        self.ann = None
+
+    def __call__(self, phase, info):
+        gen = info["generation"]
+        if phase == "start":
+            if gen:
+                self.ann = annotation("gc", generation=gen)
+                self.ann.__enter__()
+                self.t0 = self.tr._clock()
+            self.t0_ns = time.perf_counter_ns()
+            return
+        if not self.t0_ns:       # installed while a collection ran
+            return
+        tr = self.tr
+        tr.gc_ns[gen] += time.perf_counter_ns() - self.t0_ns
+        tr.gc_count[gen] += 1
+        self.t0_ns = 0
+        ann, self.ann = self.ann, None
+        if ann is None:
+            return
+        n = info["collected"]
+        ann.set_metadata(collected=n)
+        ann.__exit__(None, None, None)
+        sp = Span("gc", "gc", 0, next(tr._ids), None, self.t0,
+                  {"generation": gen, "collected": n})
+        sp.t1 = tr._clock()
+        tr._gc_spans.append(sp)
+
+
+def _set_gc_hook(tr):
+    """Replace the session's collector hook with one for `tr` (None:
+    none). Callers hold _LOCK."""
+    global _GC_HOOK
+    if _GC_HOOK is not None and _GC_HOOK in gc.callbacks:
+        gc.callbacks.remove(_GC_HOOK)
+    _GC_HOOK = None if tr is None else _GcHook(tr)
+    if _GC_HOOK is not None:
+        gc.callbacks.append(_GC_HOOK)
+
+
+# ----------------------------------------------------------------------
 # session management
 # ----------------------------------------------------------------------
 
-def start_session(capacity=65536, tracer=None, sample=None):
+def start_session(capacity=CAPACITY, tracer=None, sample=None):
     """Install the module-wide tracer session every instrumented call
-    site reports into. Raises if a session is already active.
-    `sample` (float in (0, 1], e.g. 1/16) traces only that fraction of
-    requests — the always-on mode for multi-hour sessions."""
+    site reports into, and its collector hook. Raises if a session is
+    already active. `sample` (float in (0, 1], e.g. 1/16) traces only
+    that fraction of requests — the always-on mode for multi-hour
+    sessions."""
     global _SESSION
     with _LOCK:
         if _SESSION is not None:
@@ -370,17 +462,19 @@ def start_session(capacity=65536, tracer=None, sample=None):
                                "end_session() it first")
         _SESSION = tracer if tracer is not None else \
             Tracer(capacity, sample=sample)
+        _set_gc_hook(_SESSION)
         _recompute_watch()
         return _SESSION
 
 
 def end_session():
-    """Tear down the active session; returns the Tracer (export it
-    afterwards) or None when no session was active."""
+    """Tear down the active session and its collector hook; returns the
+    Tracer (export it afterwards) or None when no session was active."""
     global _SESSION
     with _LOCK:
         tr = _SESSION
         _SESSION = None
+        _set_gc_hook(None)
         _recompute_watch()
         return tr
 
@@ -392,7 +486,7 @@ def session():
 
 
 @contextlib.contextmanager
-def session_scope(capacity=65536, sample=None):
+def session_scope(capacity=CAPACITY, sample=None):
     tr = start_session(capacity, sample=sample)
     try:
         yield tr
@@ -623,6 +717,7 @@ def reset():
     global _SESSION, _SENTINEL_COUNT, _SUPPRESS
     with _LOCK:
         _SESSION = None
+        _set_gc_hook(None)
         _GLOBAL_SENTINELS.clear()
         _COMPILE_HOOKS.clear()
         _SENTINEL_COUNT = 0

@@ -236,7 +236,6 @@ class _EngineBase:
             self.metrics.budget_bytes = int(hbm_budget_bytes)
             self.metrics.watermark_frac = float(hbm_watermark)
         self._weights_bytes = None   # cached by memory_ledger()
-        self._step_cost_cache = None  # (book, key, ProgramCost)
         #: the decode step whose tokens are still unread (`_Flight`): a
         #: loop that owns consecutive iterations (`run_ahead`) leaves
         #: one behind and the next iteration reads it AFTER it has
@@ -411,7 +410,7 @@ class _EngineBase:
     # ---- cost/memory accounting (profiler.costs) ----
     def _step_cost_key(self):
         """The jit-cache key of this engine's batched decode step (the
-        identity the MFU gauges, the compile observer, and the retrace
+        identity the cost book, the compile observer, and the retrace
         sentinel all share). None = no compiled step (Artifact pool)."""
         return None
 
@@ -421,28 +420,6 @@ class _EngineBase:
         the program compiled before accounting armed). None = no
         estimate for this key."""
         return None
-
-    def _record_step_cost(self, dt_s):
-        """Armed-only (caller guards on the costs session): one decode
-        step's roofline position into the MFU/bandwidth gauges. The
-        ProgramCost lookup is cached per (book, key) so a steady pool
-        pays one dict hit + two reservoir adds per armed step."""
-        key = self._step_cost_key()
-        if key is None:
-            return
-        bk = _costs._BOOK
-        if bk is None:
-            return
-        cached = self._step_cost_cache
-        if cached is not None and cached[0] is bk and cached[1] == key:
-            c = cached[2]
-        else:
-            c = _costs.cost_for(self, key)
-            if c is None:
-                return
-            self._step_cost_cache = (bk, key, c)
-        self.metrics.record_step_utilization(
-            c.flops, c.bytes_accessed, dt_s, bk.spec, c.source)
 
     # ---- zero-warmup startup: AOT precompile + persistent cache ----
     def _startup_programs(self, prompt_buckets):
@@ -610,13 +587,13 @@ class _EngineBase:
             return
         it = self._iter_trace
         if it is not None:
-            sp = it.begin("step.readback")
+            sp = it.begin_wait("step.readback")
         try:
             _PT_READBACK()
             toks = np.asarray(fl.toks)
         finally:
             if it is not None:
-                it.end(sp)
+                it.end_wait(sp)
         # what the program counted leaves it behind its tokens, in the
         # one array this read brings to the host
         fl.toks = toks[:self.num_slots]
@@ -652,13 +629,7 @@ class _EngineBase:
                     continue
                 self._deliver(r, int(fl.toks[s]), now)
                 n += 1
-        dt = now - fl.t0
-        self.metrics.record_decode(n, dt, late)
-        # roofline gauges: one global read disarmed; when a costs
-        # session is armed, the step's flops/bytes (XLA or analytic)
-        # land in the MFU/bandwidth reservoirs
-        if _costs._BOOK is not None:
-            self._record_step_cost(dt)
+        self.metrics.record_decode(n, now - fl.t0, late)
         # decode-step inter-arrival: the latency co-resident requests
         # actually SEE between their tokens — inline prefill inflates
         # it, disaggregated prefill doesn't
@@ -826,7 +797,7 @@ class _EngineBase:
         tr = _trace._SESSION
         if tr is None:
             return self._iterate(scheduler, None, {}, keep)
-        it = self._iter_trace = _rt.IterationTrace(tr)
+        it = self._iter_trace = _rt.IterationTrace(tr, self.metrics)
         progress, attrs = False, {}
         try:
             progress = self._iterate(scheduler, it, attrs, keep)
@@ -1083,7 +1054,7 @@ class _EngineBase:
         the step, otherwise after it is enqueued. A request finishing
         at token 0 by `eos_id` is then found a step late."""
         if it is not None:
-            sp = it.begin("iter.tok0", n=len(tok0s))
+            sp = it.begin_wait("iter.tok0", n=len(tok0s))
         for r, tok in tok0s:
             tok = np.asarray(tok).reshape(-1)   # the sync: [tok0, counts]
             self._take_counts(tok[1:])
@@ -1091,7 +1062,7 @@ class _EngineBase:
                 self._deliver(r, int(tok[0]), self.clock())
         del tok0s[:]
         if it is not None:
-            it.end(sp)
+            it.end_wait(sp)
 
     def serve_until_idle(self, scheduler, max_iterations=None):
         """Synchronous drive: iterate until queue and pool are empty

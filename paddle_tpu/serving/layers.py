@@ -1601,12 +1601,12 @@ class SpecStepper:
             jnp.int32(self.k_eff))
         if it is not None:
             it.end(sp)
-            sp = it.begin("step.readback")
+            sp = it.begin_wait("step.readback")
         emit = np.asarray(emit)
         n_emit = np.asarray(n_emit)
         t2 = time.perf_counter()
         if it is not None:
-            it.end(sp)
+            it.end_wait(sp)
         lay.advance_rows(n_emit)
         accepted = int(np.maximum(n_emit[on] - 1, 0).sum()) \
             if on_count else 0

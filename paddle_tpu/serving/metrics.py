@@ -201,18 +201,32 @@ SNAPSHOT_DOCS = {
         "gauge", "XLA temp-buffer high-water across compiled programs"),
     "memory.watermark_warnings": (
         "counter", "budget-watermark crossings (warns BEFORE OOM)"),
-    # MFU / bandwidth gauges (PR 9) — the section appears while a
-    # profiler.costs accounting session records per-step utilization
-    "mfu.device": ("info",
-                   "roofline spec {name, peak_tflops, peak_gbps, ...}"),
-    "mfu.cost_source": ("info",
-                        "{source}: xla cost_analysis or analytic hint"),
-    "mfu.flops_per_step": ("gauge", "compiled decode-step flops"),
-    "mfu.bytes_per_step": ("gauge", "decode-step bytes accessed"),
-    "mfu.model_flops_util": (
-        "summary", "per-step achieved flops / DeviceSpec peak"),
-    "mfu.bandwidth_util": (
-        "summary", "per-step bytes accessed / DeviceSpec peak BW"),
+    # the engine thread's host time by cause — the section
+    # appears once an iteration under a tracer session records; every
+    # count is over the iterations that did work
+    "host.iterations": ("counter", "iterations that did work, accounted "
+                                   "under a tracer session"),
+    "host.host_ns": ("counter", "their host part: wall less the "
+                                "step.readback and iter.tok0 waits"),
+    "host.cpu_ns": ("counter", "the engine thread's CPU time in the "
+                               "host part"),
+    "host.offcpu_ns": ("counter", "host part less cpu: waiting for the "
+                                  "GIL, descheduled, page faults"),
+    "host.wait_ns": ("counter", "wall time in the step.readback and "
+                                "iter.tok0 waits"),
+    "host.gc_ns": ("counter", "collector time, on any thread, inside "
+                              "those iterations"),
+    "host.gc_collections": ("counter", "[generation 0, 1, 2] collections "
+                                       "inside those iterations"),
+    "host.gc_ns_by_gen": ("counter", "[generation 0, 1, 2] gc_ns"),
+    "host.stalls": ("counter", "iterations whose host part passed "
+                               "HOST_STALL_NS (20 ms)"),
+    "host.stall_host_ns": ("counter", "host_ns of the stalls"),
+    "host.stall_cpu_ns": ("counter", "cpu_ns of the stalls"),
+    "host.stall_gc_ns": ("counter", "gc_ns of the stalls"),
+    "host.spans_dropped": ("counter", "the tracer session's spans "
+                                      "overwritten past its ring's "
+                                      "capacity, at the last iteration"),
     # goodput (PR 9): how much of the produced work reached callers
     "goodput.useful_tokens": (
         "counter", "tokens of requests that completed (eos/length/drain)"),
@@ -329,7 +343,12 @@ SNAPSHOT_DOCS = {
 }
 
 _SUMMARY_KEYS = {"n", "mean", "p50", "p99", "max"}
-_LEAF_DICTS = {"errors.last", "mfu.device", "pipeline.series_steps",
+
+#: a working iteration whose host part passes this is a stall: three
+#: iterations of a saturated 64-slot pool, above any iteration of five
+#: joins
+HOST_STALL_NS = 20_000_000
+_LEAF_DICTS = {"errors.last", "pipeline.series_steps",
                "cache.bytes",
                "speculation.step_ms_by_variant",
                "tenancy.active_slots_by_tenant",
@@ -599,15 +618,20 @@ class ServingMetrics:
         # first request's TTFT (what a restart actually costs callers)
         self._cold_start = None
         self.first_ttft_s = None
-        # MFU / bandwidth gauges: recorded per decode step only while
-        # a profiler.costs accounting session is armed
-        self._mfu = False
-        self._spec = None             # DeviceSpec (dict at snapshot)
-        self.cost_source = None       # "xla" | "analytic"
-        self.flops_per_step = 0.0
-        self.bytes_per_step = 0.0
-        self.mfu_util = _Reservoir(512)
-        self.bw_util = _Reservoir(512)
+        # the engine thread's host time by cause: recorded per working
+        # iteration only while a tracer session is on
+        self._host = False
+        self.host_iterations = 0
+        self.host_ns = 0
+        self.host_cpu_ns = 0
+        self.host_wait_ns = 0
+        self.gc_ns_by_gen = [0, 0, 0]
+        self.gc_collections = [0, 0, 0]
+        self.stalls = 0
+        self.stall_host_ns = 0
+        self.stall_cpu_ns = 0
+        self.stall_gc_ns = 0
+        self.spans_dropped = 0
         # traffic shaping (PR 19): chunked-prefill and preemption
         # counters plus per-SLO-class attainment and the WFQ lag the
         # ShapingScheduler publishes each iteration — the snapshot
@@ -919,22 +943,29 @@ class ServingMetrics:
         with self._lock:
             return self._above_watermark
 
-    def record_step_utilization(self, flops, bytes_accessed, dt_s,
-                                spec, source):
-        """One decode step's roofline position: the compiled program's
-        flops / bytes against the DeviceSpec peaks. Armed-only (the
-        engine guards on the costs session), so the disarmed hot path
-        never reaches here."""
+    def record_host(self, host_ns, cpu_ns, wait_ns, gc_ns, gc_count,
+                    spans_dropped):
+        """One working iteration's host time by cause
+        (`tracing.IterationTrace.close`, under a tracer session only):
+        its host part, the thread's CPU time in it, its device waits,
+        the collector's nanoseconds and collections by generation, and
+        the session's dropped spans so far."""
+        gc_total = gc_ns[0] + gc_ns[1] + gc_ns[2]
         with self._lock:
-            self._mfu = True
-            self._spec = spec
-            self.cost_source = source
-            self.flops_per_step = float(flops)
-            self.bytes_per_step = float(bytes_accessed)
-            if dt_s > 0:
-                self.mfu_util.add(flops / dt_s / spec.peak_flops)
-                self.bw_util.add(
-                    bytes_accessed / dt_s / spec.peak_bytes_per_s)
+            self._host = True
+            self.host_iterations += 1
+            self.host_ns += host_ns
+            self.host_cpu_ns += cpu_ns
+            self.host_wait_ns += wait_ns
+            for g in range(3):
+                self.gc_ns_by_gen[g] += gc_ns[g]
+                self.gc_collections[g] += gc_count[g]
+            if host_ns > HOST_STALL_NS:
+                self.stalls += 1
+                self.stall_host_ns += host_ns
+                self.stall_cpu_ns += cpu_ns
+                self.stall_gc_ns += gc_total
+            self.spans_dropped = int(spans_dropped)
 
     # ---- cold-start accounting (PR 11) ----
     def record_cold_start(self, report):
@@ -1189,13 +1220,20 @@ class ServingMetrics:
                         for v, r in self._spec_by_variant.items()},
                 }}),
                 **({} if mem is None else {"memory": mem}),
-                **({} if not self._mfu else {"mfu": {
-                    "device": self._spec.as_dict(),
-                    "cost_source": self.cost_source,
-                    "flops_per_step": self.flops_per_step,
-                    "bytes_per_step": self.bytes_per_step,
-                    "model_flops_util": self.mfu_util.summary(digits=5),
-                    "bandwidth_util": self.bw_util.summary(digits=5),
+                **({} if not self._host else {"host": {
+                    "iterations": self.host_iterations,
+                    "host_ns": self.host_ns,
+                    "cpu_ns": self.host_cpu_ns,
+                    "offcpu_ns": self.host_ns - self.host_cpu_ns,
+                    "wait_ns": self.host_wait_ns,
+                    "gc_ns": sum(self.gc_ns_by_gen),
+                    "gc_collections": list(self.gc_collections),
+                    "gc_ns_by_gen": list(self.gc_ns_by_gen),
+                    "stalls": self.stalls,
+                    "stall_host_ns": self.stall_host_ns,
+                    "stall_cpu_ns": self.stall_cpu_ns,
+                    "stall_gc_ns": self.stall_gc_ns,
+                    "spans_dropped": self.spans_dropped,
                 }}),
                 **({} if not self._sharded else {"sharding": {
                     # prefill-slice vs decode-slice step latency: the

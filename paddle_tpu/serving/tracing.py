@@ -71,7 +71,11 @@ Span catalog (exported Chrome-trace names):
                   expert layers —; step — "ahead" where the
                   iteration's step was enqueued before the last one's
                   tokens were read, else why not: idle, spec, chunk,
-                  pending, preempt, retry, host —; late_slot_steps)
+                  pending, preempt, retry, host —; late_slot_steps;
+                  cpu_ms, offcpu_ms — the host part, the iteration
+                  less its step.readback and iter.tok0 waits, split
+                  into the engine thread's CPU time and the rest —
+                  and gc_ms, the collector's time on any thread)
   iter.harvest    cancellation / deadline sweep and the poll of
                   disaggregated prefills
   iter.admit      the admission loop; the admitted requests' join
@@ -105,8 +109,16 @@ Span catalog (exported Chrome-trace names):
   compile         engine track: one jit trace+compile (attrs: cache
                   key, duration, count)
   retrace         engine track instant: a retrace-sentinel violation
+  gc              a collection of generation 1 or 2, on any thread
+                  (attrs: generation, collected; trace id 0): a
+                  profiler annotation on the thread it ran on, so a
+                  device gap it caused is labelled `gc`, and a span in
+                  the ring from the next commit on (`profiler/trace.py`
+                  `_GcHook`; generation 0 only counts)
 """
 from __future__ import annotations
+
+import time
 
 import numpy as np
 
@@ -145,7 +157,8 @@ SPAN_CATALOG = (
     ("finish", "terminal instant: finish_reason"),
     ("error", "terminal instant: failure cause"),
     ("iteration", "engine track root: one run_iteration that did "
-                  "work (joins, occupancy, gauges, t0_perf_ns)"),
+                  "work (joins, occupancy, gauges, t0_perf_ns; cpu_ms, "
+                  "offcpu_ms, gc_ms of its host part)"),
     ("iter.harvest", "engine track: cancellation / deadline sweep, "
                      "poll of disaggregated prefills"),
     ("iter.admit", "engine track: the admission loop; parent of the "
@@ -174,6 +187,8 @@ SPAN_CATALOG = (
     ("precompile", "engine track: one startup program readied "
                    "(source: cache deserialize | AOT compile)"),
     ("retrace", "engine track: retrace-sentinel violation"),
+    ("gc", "a collection of generation 1 or 2 on any thread "
+           "(generation, collected)"),
 )
 
 
@@ -365,12 +380,35 @@ class IterationTrace:
     thread: the root `iteration` and whatever is opened under it,
     properly nested and forwarded to the profiler. The engine keeps it
     as `engine._iter_trace` while the iteration runs (None with no
-    session), which is what the steppers' call sites test."""
+    session), which is what the steppers' call sites test.
 
-    __slots__ = ("tr", "stack", "step", "held")
+    It also splits the host's time by cause. The thread's CPU clock and
+    the collector's totals are read at the root's begin and at close;
+    the device waits (`begin_wait` / `end_wait`: `step.readback`,
+    `iter.tok0`) read the wall and CPU clocks around themselves. An
+    iteration that did work then has a host part (wall less the waits),
+    its `cpu` (the thread's CPU time in it) and `offcpu` (the rest:
+    waiting for the GIL, descheduled, page faults), and `gc` (collector
+    time on any thread, a cause inside one of the two). They go on the
+    root as `cpu_ms`, `offcpu_ms`, `gc_ms` and into `metrics`'
+    `snapshot()["host"]`. Where the thread clock advances in scheduler
+    ticks (10 ms steps on some sandboxed hosts), one iteration's split
+    is a sample that may fall outside [0, host part]; sums over many
+    iterations are what it estimates."""
 
-    def __init__(self, tr):
+    __slots__ = ("tr", "stack", "step", "held", "metrics", "t0_ns",
+                 "cpu0_ns", "gc0", "wait_ns", "wait_cpu_ns", "w_ns",
+                 "w_cpu_ns")
+
+    def __init__(self, tr, metrics=None):
         self.tr = tr
+        self.metrics = metrics
+        # the CPU clock's intervals lie inside the wall clock's, and a
+        # wait's enclose it, so a thread that never leaves the CPU
+        # reads no negative off-CPU time
+        self.gc0 = tr.gc_totals()
+        self.t0_ns = time.perf_counter_ns()
+        self.cpu0_ns = time.thread_time_ns()
         root = tr.begin("iteration", cat="engine", forward=True)
         # the offset between the profiler's clock and the tracer's:
         # this annotation's start is `t0_perf_ns` on the tracer's
@@ -379,12 +417,27 @@ class IterationTrace:
         self.stack = [root]
         self.step = None          # this iteration's decode.step span
         self.held = []            # closed spans, recorded at close()
+        self.wait_ns = self.wait_cpu_ns = 0
+        self.w_ns = self.w_cpu_ns = 0
 
     def begin(self, name, **attrs):
         sp = self.tr.begin(name, cat="engine", parent=self.stack[-1],
                            attrs=attrs, forward=True)
         self.stack.append(sp)
         return sp
+
+    def begin_wait(self, name, **attrs):
+        """`begin` for a span in which the host waits for the device:
+        its wall and CPU time are kept out of the host part."""
+        sp = self.begin(name, **attrs)
+        self.w_cpu_ns = time.thread_time_ns()
+        self.w_ns = time.perf_counter_ns()
+        return sp
+
+    def end_wait(self, sp, **attrs):
+        self.wait_ns += time.perf_counter_ns() - self.w_ns
+        self.wait_cpu_ns += time.thread_time_ns() - self.w_cpu_ns
+        self.end(sp, **attrs)
 
     def unwind(self, sp):
         """Close whatever an exception left open under `sp` (the engine
@@ -432,6 +485,18 @@ class IterationTrace:
         if not progress:
             self.tr.end(root, False)
             return
+        # not clamped to [0, host]: where the thread clock moves in
+        # scheduler ticks, a clamp would bias the sums
+        cpu = time.thread_time_ns() - self.cpu0_ns - self.wait_cpu_ns
+        host = time.perf_counter_ns() - self.t0_ns - self.wait_ns
+        gc = [b - a for a, b in zip(self.gc0, self.tr.gc_totals())]
+        gc_ns = gc[0] + gc[1] + gc[2]
+        attrs.update(cpu_ms=round(cpu * 1e-6, 3),
+                     offcpu_ms=round((host - cpu) * 1e-6, 3),
+                     gc_ms=round(gc_ns * 1e-6, 3))
+        if self.metrics is not None:
+            self.metrics.record_host(host, cpu, self.wait_ns, gc[:3],
+                                     gc[3:], self.tr.dropped)
         for k, v in (gauges or {}).items():
             attrs[k] = (round(float(v), 3) if isinstance(v, float)
                         else list(v) if isinstance(v, (list, tuple))

@@ -184,11 +184,11 @@ def test_costbook_capture_and_key_join_roundtrip():
     span_keys = {s.attrs["key"] for s in tr.spans()
                  if s.cat == "compile"}
     assert span_keys == {T._key_str(k) for k in traced}
-    # the armed soak populated the MFU gauges from the step's record
-    assert snap["mfu"]["cost_source"] == "xla"
-    assert snap["mfu"]["flops_per_step"] > 0
-    assert snap["mfu"]["model_flops_util"]["n"] > 0
-    assert snap["mfu"]["bandwidth_util"]["n"] > 0
+    # the decode step's own record is in the book, from XLA, and the
+    # serving snapshot carries no per-step utilization gauge
+    step = bk.get("ServingEngine", eng._step_cost_key())
+    assert step is not None and step.source == "xla" and step.flops > 0
+    assert "mfu" not in snap
     # compile temp high-water reached the memory section while armed
     assert snap["memory"]["compile_temp_peak_bytes"] == \
         bk.temp_high_water()
@@ -207,8 +207,8 @@ def test_capture_disabled_falls_back_to_analytic():
         snap = eng.metrics.snapshot()
     assert bk.keys()
     assert all(c.source == "analytic" for c in bk.costs())
-    assert snap["mfu"]["cost_source"] == "analytic"
-    assert snap["mfu"]["flops_per_step"] > 0
+    assert all(c.flops > 0 for c in bk.costs())
+    assert "mfu" not in snap
 
 
 # ----------------------------------------------------------------------

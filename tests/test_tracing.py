@@ -451,10 +451,8 @@ def test_record_event_type_capacity_and_tracer_surface():
 
 def _full_metrics():
     """A ServingMetrics with every section populated (paging +
-    sharding + memory ledger + MFU/goodput gauges recorded) — no
+    sharding + memory ledger + host time + goodput recorded) — no
     engine needed."""
-    from paddle_tpu.profiler.costs import CPU_SPEC
-
     m = ServingMetrics()
     m.record_submit()
     m.record_join()
@@ -500,7 +498,8 @@ def _full_metrics():
                  "adapter_bytes": 128, "in_use_bytes": 1200,
                  "compile_temp_peak_bytes": 64},
         budget_bytes=2000)
-    m.record_step_utilization(1e6, 2e6, 0.001, CPU_SPEC, "xla")
+    m.record_host(5_000_000, 4_000_000, 2_000_000, (10, 0, 300),
+                  (1, 0, 1), 0)
     m.record_cold_start({"time_to_ready_s": 1.5, "programs": 4,
                          "loaded_from_cache": 3, "compiled": 1,
                          "cache_errors": 0, "warm": 0})

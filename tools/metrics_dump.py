@@ -33,7 +33,7 @@ def _demo_snapshot():
     """Serve a few requests through a tiny PAGED pool (speculation
     enabled) under a tracer session AND an armed cost-accounting
     session, so the dump previews every snapshot section — memory
-    ledger, MFU/goodput gauges, speculation counters, radix
+    ledger, host time by cause, goodput, speculation counters, radix
     prefix-cache stats, cold-start report, traffic-shaping slo
     counters included — and return (snapshot, tracer). The workload
     shares an 8-token preamble so the prefix section shows a whole
