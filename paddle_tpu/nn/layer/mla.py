@@ -11,9 +11,9 @@ no gradient.
 What a token leaves behind is ONE row, `[c_kv | rotated k_pe]`
 (`row_width` values, no head axis), and the attention has two forms over
 such rows: `causal` up-projects keys and values from them (a join: every
-row is read by every later position of its own sequence), `absorbed`
-folds W_kvb into the query and the output and reads the rows as they are
-(a decode step: one query a slot against thousands of cached rows).
+row is read by every later position of its own sequence), `absorbed` and
+`absorbed_paged` (through the page table) fold W_kvb into the query and the
+output and read the rows as stored (a decode step: one query, many rows).
 """
 from __future__ import annotations
 
@@ -197,20 +197,35 @@ class LatentAttention(Layer):
         return jnp.moveaxis(jax.lax.map(heads, (split(q), split(k),
                                                 split(v))), 0, 2)
 
+    def _absorb_query(self, q):
+        """q [S, H, nope + rot] -> [S, H, rank + rot] = [q_nope . W_uk |
+        q_pe]: the query that scores a row as stored."""
+        import jax.numpy as jnp
+
+        w_uk, _ = self._w_kvb()
+        return jnp.concatenate([
+            jnp.einsum("shd,chd->shc", q[..., :self.nope], w_uk),
+            q[..., self.nope:]], -1)
+
+    def _absorbed_out(self, o):
+        """The heads' normalised sums of c_kv [S, H, rank] -> [S, hidden]:
+        W_uv, then the output projection."""
+        import jax.numpy as jnp
+
+        _, w_uv = self._w_kvb()
+        o = jnp.einsum("shc,chd->shd", o, w_uv)
+        return o.reshape(o.shape[0], -1) @ self.o._data
+
     def absorbed(self, q, rows, n_keys):
         """Absorbed, a decode step: q [S, H, nope + rot] against each
         slot's cached rows [S, L, row_width], of which the first
         `n_keys` [S] are written -> [S, hidden]. W_uk goes into the
         query and W_uv onto the output: the rows are read as stored."""
-        import jax
         import jax.numpy as jnp
 
         f32 = jnp.float32
-        w_uk, w_uv = self._w_kvb()
         width = self.rank + self.rot
-        qa = jnp.concatenate([
-            jnp.einsum("shd,chd->shc", q[..., :self.nope], w_uk),
-            q[..., self.nope:]], -1)                       # [S, H, width]
+        qa = self._absorb_query(q)                         # [S, H, width]
         sc = jnp.einsum("shc,slc->shl", qa, rows[..., :width],
                         preferred_element_type=f32) * f32(self.scale)
         live = jnp.arange(rows.shape[1], dtype=jnp.int32)[None, :] \
@@ -218,5 +233,21 @@ class LatentAttention(Layer):
         e, total = _exp_and_sum(jnp.where(live[:, None], sc, f32(-1e30)))
         o = jnp.einsum("shl,slc->shc", e.astype(rows.dtype),
                        rows[..., :self.rank], preferred_element_type=f32)
-        o = jnp.einsum("shc,chd->shd", (o / total).astype(rows.dtype), w_uv)
-        return o.reshape(o.shape[0], -1) @ self.o._data
+        return self._absorbed_out((o / total).astype(rows.dtype))
+
+    def absorbed_paged(self, q, pages, table, n_keys, interpret=False):
+        """`absorbed` over the rows as the pool holds them: pages [N + 1,
+        psz, row_width] read through `table` [S, max_pages]. The
+        `paged_latent_decode` kernel (`ops.latent_decode`) where its gates
+        open, or under the TPU interpreter where `interpret`: it reads
+        each written row once, for score and value. Elsewhere the slots'
+        whole tables are gathered and `absorbed` runs over them."""
+        from ...ops import latent_decode as LD
+
+        if not (interpret or LD.kernel_opens(pages, self.rank,
+                                             self.num_heads)):
+            rows = pages[table].reshape(table.shape[0], -1, pages.shape[-1])
+            return self.absorbed(q, rows, n_keys)
+        return self._absorbed_out(LD.paged_latent_decode(
+            self._absorb_query(q), pages, table, n_keys, self.rank,
+            self.scale, interpret))

@@ -366,9 +366,18 @@ class CausalLMDriver:
         return values * self.eng.page_size * jnp.dtype(storage).itemsize
 
     def pages_per_block(self, storage):
-        """1: the step gathers its pages, no page-table kernel reads
-        grouped heads or latent rows."""
-        return 1
+        """A latent stack: the pages a grid step of its decode kernel
+        takes (`ops.latent_decode.decode_block_pages`: from the pool's
+        shapes and page dtype alone; 1 where the step gathers). Any other
+        stack: 1, no page-table kernel reads grouped heads."""
+        if not self.latent:
+            return 1
+        from ..ops import latent_decode as LD
+
+        cfg, eng = self.cfg, self.eng
+        return LD.decode_block_pages(
+            eng.page_size, cfg.latent_row_width, cfg.kv_lora_rank,
+            cfg.num_attention_heads, eng.max_pages, storage)
 
     def prefill(self, params, buffers, prompt, length, memory, bias_row,
                 Pb, ad):

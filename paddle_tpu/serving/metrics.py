@@ -134,9 +134,9 @@ SNAPSHOT_DOCS = {
                    "pages that a paged decode call fetches and "
                    "computes on"),
     "paging.pages_per_block": (
-        "gauge", "consecutive logical pages one grid step of the "
-                 "paged decode call takes (from the pool's shapes and "
-                 "page dtype; 1 where the pool takes the gather)"),
+        "gauge", "consecutive logical pages one grid step of the paged "
+                 "decode call (K/V or latent kernel) takes, from the pool's "
+                 "shapes and page dtype; 1 where the pool takes the gather"),
     "paging.live_block_iterations": (
         "counter", "grid steps of a paged decode call that fetch and "
                    "compute, sum over the occupied slots of "

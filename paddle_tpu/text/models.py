@@ -939,7 +939,6 @@ class DeepseekV3ForCausalLM(nn.Layer):
 
         index = jnp.asarray(index, jnp.int32)
         x = self.embed_tokens._data[tok]
-        S = tok.shape[0]
         pages, counts = [], []
         for blk, pg in zip(self.layers, state["latent"]):
             with jax.named_scope("mla"):
@@ -947,8 +946,8 @@ class DeepseekV3ForCausalLM(nn.Layer):
                     blk.norm(blk.input_layernorm, x), index)
                 pg = PG.write_token(pg, None, table, index,
                                     row[:, None])[0]
-                rows = pg[table].reshape(S, -1, pg.shape[-1])
-                x = x + blk.self_attn.absorbed(q, rows, index + 1)
+                x = x + blk.self_attn.absorbed_paged(q, pg, table,
+                                                     index + 1)
             pages.append(pg)
             x, c = blk.feed_forward(x, active)
             counts.append(c)

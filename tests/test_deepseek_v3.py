@@ -322,10 +322,28 @@ def _specs(seed, n):
              int(rs.randint(4, 10))) for _ in range(n)]
 
 
-def test_prefill_then_decode_through_pages_matches_the_full_forward():
+@pytest.mark.parametrize("kernel", [False, True])
+def test_prefill_then_decode_through_pages_matches_the_full_forward(
+        monkeypatch, kernel):
     """Joins (unabsorbed) and steps (absorbed, through the page table,
     with slots reused) against the full forward's logits: tapped where
-    the programs compute them."""
+    the programs compute them. The steps read the gathered rows, or
+    (`kernel`) run the `paged_latent_decode` kernel under the TPU
+    interpreter, two pages a grid step: four blocks a slot."""
+    if kernel:
+        import functools
+
+        from paddle_tpu.ops import latent_decode as LD
+
+        monkeypatch.setattr(nn.LatentAttention, "absorbed_paged",
+                            functools.partialmethod(
+                                nn.LatentAttention.absorbed_paged,
+                                interpret=True))
+        monkeypatch.setattr(LD, "_LATENT_BUFFER_BYTES", 2 * 2 * 4 * 32 * 4)
+        traced = []
+        call = LD.paged_latent_decode
+        monkeypatch.setattr(LD, "paged_latent_decode",
+                            lambda *a: traced.append(a) or call(*a))
     model = _build(latent_row_pad=8)
     seen = []
     plain = model._logits
@@ -371,6 +389,10 @@ def test_prefill_then_decode_through_pages_matches_the_full_forward():
     assert all(v == 1 for v in eng.trace_counts.values())
     assert snap["pipeline"]["steps_ahead"] >= \
         snap["pipeline"]["decode_steps"] - 2
+    if kernel:
+        # the one step program's trace called the kernel in each layer
+        assert len(traced) == 3
+        assert all(a[1].shape == st["latent"][0].shape for a in traced)
 
 
 def test_expert_counters_leave_with_the_tokens():

@@ -749,6 +749,34 @@ def test_latent_decode_read_compiles_at_the_serving_cell_shapes(one_chip):
         < rows + 2 * scores
 
 
+def test_latent_decode_kernel_compiles_at_the_serving_cell_shapes(one_chip):
+    """The same read on the chip's path: `absorbed_paged` takes the
+    `paged_latent_decode` kernel (32 pages a grid step), which reads the
+    pages through the table itself, so the program gathers no rows and
+    holds no [slots, heads, keys] scores: its scratch is the query and
+    the output, not the 0.5 GB of rows or the 0.2 GB of scores."""
+    from paddle_tpu.ops import latent_decode as LD
+
+    attn = _latent_attention()
+    S, L, psz = (DSV3[k] for k in ("S", "L", "psz"))
+    mp = L // psz
+    assert LD.block_pages(psz, attn.row_width, mp, "bfloat16") == 32
+    orig = A._on_tpu
+    A._on_tpu = lambda: True
+    try:
+        compiled = _compile(
+            lambda q, pages, table, n_keys: attn.absorbed_paged(
+                q, pages, table, n_keys), one_chip,
+            ((S, DSV3["heads"], 192), jnp.bfloat16),
+            ((S * mp + 1, psz, attn.row_width), jnp.bfloat16),
+            ((S, mp), jnp.int32), ((S,), jnp.int32))
+    finally:
+        A._on_tpu = orig
+    text = compiled.as_text()
+    assert "paged_latent_decode" in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 64 * 2**20
+
+
 @pytest.mark.parametrize("path", ["flash", "composed"])
 def test_latent_join_attention_compiles_at_the_serving_cell_shapes(
         one_chip, path):
