@@ -171,10 +171,28 @@ def dense(q, k_rows, v_rows, kv_heads, n_keys):
 def paged_reader(k_pages, v_pages, table, kv_heads):
     """How the layers of ONE decode step read the same pages ([N + 1,
     psz, Hkv d], `table` [S, max_pages]): a function (q [S, Hq, d],
-    n_keys [S]) -> [S, groups, G, 2d]. The dense view is gathered ONCE
-    here and every layer attends to it (`dense`): the gather is the
-    pool's size in traffic, and eight layers share it."""
-    S, hd = table.shape[0], k_pages.shape[-1]
+    n_keys [S]) -> [S, groups, G, 2d]. On the chip, in a trace where the
+    attention dispatchers may take their kernels
+    (`ops.attention._flash_usable`: not under `PT_FLASH_ATTENTION=0`, not
+    in a program the partitioner splits) and over a pool the kernel fits,
+    every layer reads its slots' written pages through the table
+    (`ops.grouped_decode`) and no view is gathered. Elsewhere the dense
+    view is gathered ONCE here and every layer attends to it (`dense`):
+    the gather is the pool's size in traffic, and eight layers share
+    it."""
+    from . import attention as A
+
+    S, psz, hd = table.shape[0], k_pages.shape[1], k_pages.shape[-1]
+    if A._on_tpu() and A._flash_usable():
+        from . import grouped_decode as GD
+
+        if GD.kernel_fits(psz, hd, 2 * hd // kv_heads, k_pages.dtype):
+            def read(q, n_keys):
+                return GD.paged_grouped_decode(
+                    widen_queries(q, kv_heads), k_pages, v_pages, table,
+                    n_keys, 1.0 / math.sqrt(q.shape[-1]))
+
+            return read
     k_rows = k_pages[table].reshape(S, -1, hd)
     v_rows = v_pages[table].reshape(S, -1, hd)
     return lambda q, n_keys: dense(q, k_rows, v_rows, kv_heads, n_keys)

@@ -366,16 +366,16 @@ class CausalLMDriver:
         return values * self.eng.page_size * jnp.dtype(storage).itemsize
 
     def pages_per_block(self, storage):
-        """A latent stack: the pages a grid step of its decode kernel
-        takes (`ops.latent_decode.decode_block_pages`: from the pool's
-        shapes and page dtype alone; 1 where the step gathers). Any other
-        stack: 1, no page-table kernel reads grouped heads."""
-        if not self.latent:
-            return 1
-        from ..ops import latent_decode as LD
-
+        """Pages a grid step of the stack's decode kernel takes, from the
+        pool's shapes and page dtype alone; 1 where the step gathers."""
         cfg, eng = self.cfg, self.eng
-        return LD.decode_block_pages(
+        if "paged" in self.cache_kinds():       # K/V pages, grouped heads
+            from ..ops import grouped_decode as GD
+            return GD.decode_block_pages(
+                eng.page_size, cfg.num_key_value_heads * cfg.head_dim,
+                2 * cfg.head_dim, eng.max_pages, storage)
+        from ..ops import latent_decode as LD
+        return 1 if not self.latent else LD.decode_block_pages(
             eng.page_size, cfg.latent_row_width, cfg.kv_lora_rank,
             cfg.num_attention_heads, eng.max_pages, storage)
 

@@ -670,6 +670,34 @@ def test_gathered_page_read_compiles_at_the_serving_cell_widths(one_chip):
     assert compiled.memory_analysis().temp_size_in_bytes < 2 * rows
 
 
+def test_grouped_decode_kernel_compiles_at_the_serving_cell_widths(
+        one_chip):
+    """The same read on the chip's path: `paged_reader` takes the
+    `paged_grouped_decode` kernel (32 pages a grid step), which reads
+    the written pages through the table itself, so the program gathers
+    no rows: its scratch is the queries and the output, not the 1.3 GB
+    of gathered K and V."""
+    from paddle_tpu.ops import diff_attention as DA
+    from paddle_tpu.ops import grouped_decode as GD
+
+    S, h, d, psz = (PHI[k] for k in ("S", "h", "d", "psz"))
+    mp = 256
+    pages = ((S * mp + 1, psz, h * d), jnp.bfloat16)
+    assert GD.block_pages(psz, h * d, mp, "bfloat16") == 32
+    orig = A._on_tpu
+    A._on_tpu = lambda: True
+    try:
+        compiled = _compile(
+            lambda q, kp, vp, table, length: DA.paged_reader(
+                kp, vp, table, 2 * h)(q, length), one_chip,
+            ((S, 4 * h, d // 2), jnp.bfloat16), pages, pages,
+            ((S, mp), jnp.int32), ((S,), jnp.int32))
+    finally:
+        A._on_tpu = orig
+    assert "paged_grouped_decode" in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < 64 * 2**20
+
+
 def test_selective_scan_compiles_at_the_serving_cell_shapes(one_chip):
     """The Pallas scan a join runs (one row, 2048 positions, 5120
     channels, 16 states) and the composition it replaces on the chip."""

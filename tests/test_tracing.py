@@ -928,7 +928,8 @@ def test_every_pallas_kernel_has_a_fixed_name():
         "flash_fwd", "flash_bwd_dq", "flash_bwd_dkv", "flash_decode",
         "flash_verify", "paged_flash_decode", "paged_flash_verify",
         "int8_matmul", "lora_gather", "fused_conv_fwd",
-        "fused_conv_bwd", "selective_scan", "paged_latent_decode"])
+        "fused_conv_bwd", "selective_scan", "paged_latent_decode",
+        "paged_grouped_decode"])
     assert all(not any(c.isdigit() for c in n.replace("int8", ""))
                for n in flat)                     # no shape in a name
 
